@@ -319,7 +319,7 @@ class ClusterNode:
         # .java:64 semantics distributed)
         self._reader_contexts: dict[str, dict] = {}
         # heavy query phases run OFF the transport loop so a slow search
-        # cannot stall heartbeats/elections (VERDICT r2 weak #9); one worker
+        # cannot stall heartbeats/elections; one worker
         # keeps the engine's single-writer discipline for WRITE/engine work
         self._data_executor = None
         # read-only searches get a PARALLEL pool (the reference's `search`

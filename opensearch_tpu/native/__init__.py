@@ -3,32 +3,43 @@
 The reference leans on JVM intrinsics + Lucene's native-speed codecs for
 its WAL and postings paths (SURVEY.md §2 "TPU-build note" rows); here the
 same two hot loops are C++ (native/tlog_codec.cpp) behind a C ABI — ctypes,
-not pybind11 (not in this image). The library is built on first import with
-g++ (cached next to the source); every entry point has a pure-Python
-fallback so the engine still runs where no toolchain exists.
+not pybind11 (not in this image). The library is built on first use with
+g++ and kept next to the source under a name that carries the source's
+content hash, so a binary left in a copied tree is only ever loaded for
+the source it was built from. Every entry point has a pure-Python fallback
+so the engine still runs where no toolchain exists; a failed build or load
+is logged once and reported by :func:`native_available`.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import sys
 import threading
 from pathlib import Path
 
+logger = logging.getLogger(__name__)
+
 _DIR = Path(__file__).parent
 _SRC = _DIR / "tlog_codec.cpp"
-_LIB = _DIR / f"libosnative-{sys.implementation.cache_tag}.so"
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _load_attempted = False
 
 
-def _build() -> bool:
+def _lib_path() -> Path:
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _DIR / f"libosnative-{sys.implementation.cache_tag}-{digest}.so"
+
+
+def _build(lib_path: Path) -> bool:
     # compile to a temp path + atomic rename: a concurrent process must
-    # never CDLL a half-written .so (it would silently fall back to Python)
-    tmp = _LIB.with_suffix(f".tmp{os.getpid()}.so")
+    # never CDLL a half-written .so
+    tmp = lib_path.with_suffix(f".tmp{os.getpid()}.so")
     try:
         result = subprocess.run(
             ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
@@ -36,10 +47,16 @@ def _build() -> bool:
             capture_output=True, timeout=120,
         )
         if result.returncode != 0 or not tmp.exists():
+            logger.warning(
+                "native library build failed (g++ exit %s): %s — using "
+                "the Python fallbacks", result.returncode,
+                result.stderr.decode(errors="replace")[-500:])
             return False
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib_path)
         return True
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
+        logger.warning("native library build failed (%s) — using the "
+                       "Python fallbacks", e)
         return False
     finally:
         tmp.unlink(missing_ok=True)
@@ -53,15 +70,14 @@ def _load() -> ctypes.CDLL | None:
         _load_attempted = True
         if os.environ.get("OPENSEARCH_TPU_NO_NATIVE"):
             return None
-        stale = (
-            not _LIB.exists()
-            or _LIB.stat().st_mtime < _SRC.stat().st_mtime
-        )
-        if stale and not _build():
+        lib_path = _lib_path()
+        if not lib_path.exists() and not _build(lib_path):
             return None
         try:
-            lib = ctypes.CDLL(str(_LIB))
-        except OSError:
+            lib = ctypes.CDLL(str(lib_path))
+        except OSError as e:
+            logger.warning("native library [%s] failed to load (%s) — "
+                           "using the Python fallbacks", lib_path.name, e)
             return None
         lib.osn_crc32.restype = ctypes.c_uint32
         lib.osn_crc32.argtypes = [ctypes.c_char_p, ctypes.c_uint64]

@@ -1709,8 +1709,8 @@ class TpuNode:
             release.close()
             # request-level translog durability: ONE fsync per outer write
             # request covering every shard it touched (Translog.java:606 —
-            # the reference fsyncs per request, not per op; VERDICT r1 #10
-            # flagged the per-op sync as fsync-bound). Runs even on partial
+            # the reference fsyncs per request, not per op; a per-op sync is
+            # fsync-bound). Runs even on partial
             # bulk failure: applied items must be durable before their acks
             dirty, self._dirty_translog_shards = (
                 self._dirty_translog_shards, set()

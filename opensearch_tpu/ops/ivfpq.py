@@ -175,13 +175,18 @@ def train(
 
 @functools.partial(jax.jit, static_argnames=("m",))
 def _encode_chunk(chunk: jnp.ndarray, coarse: jnp.ndarray, codebooks: jnp.ndarray, *, m: int):
-    """(list_ids [c], codes [c, m] uint8) for one chunk of vectors."""
+    """(list_ids [c], codes [c, m] int32 in [0, ks)) for one chunk of
+    vectors. The codes leave the device as int32 and `encode` narrows them
+    to uint8 on the host: with the int32 -> uint8 convert inside this
+    program, XLA:TPU (libtpu 0.0.34, v5e) returned all-zero codes for half
+    the subspaces — the convert fused behind the vmapped argmin is
+    miscompiled (PR 21; the CPU backend is not affected)."""
     lists = _assign(chunk, coarse)
     residuals = chunk - coarse[lists]
     dsub = chunk.shape[1] // m
     res_sub = jnp.transpose(residuals.reshape(-1, m, dsub), (1, 0, 2))
     codes = jax.vmap(_assign)(res_sub, codebooks)        # [m, c]
-    return lists, jnp.transpose(codes).astype(jnp.uint8)  # [c, m]
+    return lists, jnp.transpose(codes)                    # [c, m]
 
 
 def encode(vectors: np.ndarray, params: IVFPQParams, *, chunk: int = 65_536):
@@ -556,7 +561,7 @@ def search_index(
     :func:`search` lowering; "pallas" runs the cooperative split — coarse
     quantization + probe selection host-side (:func:`host_probe_select`),
     then ONE batched fused Pallas scan + exact rescore on device
-    (ops/pallas_adc.adc_topr_auto, interpret-mode off-TPU)."""
+    (ops/pallas_adc.adc_topr_auto; interpret-mode only on the CPU backend)."""
     nprobe = nprobe or DEFAULT_NPROBE
     if rerank is None:
         rerank = default_rerank(k, rescore_multiplier)

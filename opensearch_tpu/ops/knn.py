@@ -48,7 +48,7 @@ def _raw_similarity(
     HIGHEST matmul precision: exact-path scores must match an fp32 host
     reference bit-for-bit (and the distributed serving program, which also
     runs HIGHEST) — the default TPU bf16 lowering flips near-tie
-    neighbors (VERDICT r2 weak #2)."""
+    neighbors."""
     sim = canonical_similarity(similarity)
     import jax as _jax
 

@@ -7,11 +7,10 @@ int8 achieves FEWER QPS than fp32 (204 vs 296, BENCH_ANN.json) against a
 SMALLER modeled byte floor, because XLA widens the quantized LUT through
 the ``take_along_axis`` gather — the byte saving never reaches HBM. A
 hand-scheduled kernel controls residency directly: the per-(query, probe)
-LUT stays in VMEM at its NATIVE width (fp32 / bf16 half-width / uint8 with
-int32 accumulate), each probe's PQ code block streams through VMEM exactly
-once, and only the ``[B, R]`` winners ever land in HBM — the
-``[B, nprobe, L_pad]`` ADC-distance intermediate of the XLA lowering never
-exists.
+LUT stays in VMEM (fp32, or bf16 half-width for both reduced precisions),
+each probe's PQ code block streams through VMEM exactly once, and only the
+``[B, R]`` winners ever land in HBM — the ``[B, nprobe, L_pad]``
+ADC-distance intermediate of the XLA lowering never exists.
 
 SPLIT (FusionANNS-style host/device cooperative routing, PAPERS.md):
 coarse quantization, probe selection and candidate-list assembly run
@@ -28,10 +27,11 @@ block from the device-resident ``[nlist, L_pad, m]`` code slab — no
 KERNEL. Grid ``(B, nprobe, L_pad // l_blk)`` (sequential on a TensorCore,
 so VMEM scratch persists across iterations — the ``pallas_knn.py``
 accumulation pattern). Per step: decode the ``[l_blk, m]`` code tile
-against the resident ``[m, ks]`` LUT as ONE one-hot matmul on the MXU
-(``[l_blk, m·ks] × [m·ks, 1]``; the one-hot operand is m lane-compares
-concatenated lane-wise — no gather), mask ragged list tails, and fold the
-block's candidates into a running ``[1, R]`` top-R pool in VMEM scratch via
+against the resident m-major ``[1, m·ks]`` LUT row as ONE one-hot matmul
+on the MXU (``[1, m·ks] × [l_blk, m·ks]ᵀ``, both contracted on lanes; the
+one-hot operand is m lane-compares concatenated lane-wise — no gather), so
+the block's candidates land on the result's lanes, mask ragged list tails,
+and fold them into a running ``[1, R]`` top-R pool in VMEM scratch via
 R extract-max rounds, guarded by the kth-best threshold early-exit so
 steady-state tiles cost one decode + one row-max. Carried entries merge
 FIRST, so score ties resolve to the earliest (probe-major) position —
@@ -39,23 +39,31 @@ exactly ``lax.top_k``'s tie-break over the XLA path's flattened
 ``[nprobe * L_pad]`` axis, which is what makes the interpret-mode parity
 tests exact.
 
+LAYOUT (what Mosaic accepts — checked without a chip by
+tests/test_tpu_lowering.py): every row-shaped operand (LUT, ids, mask,
+both outputs) carries a unit second-minor axis so its block's last two
+dims span the array's or the (8, 128) tile; the probe table is flat in
+SMEM; nothing is reshaped between lanes and sublanes inside the kernel.
+
 PRECISION (ANNS-AMP): "fp32" accumulates f32; "bf16" keeps the LUT
 resident in VMEM at half width and accumulates f32; "int8" quantizes each
 QUERY's LUT affinely to uint8 (one shared affine across its probes, so
 integer sums stay comparable ACROSS probes without a dequantize in the
-scan) and rides the one-hot matmul at bf16 (0..255 is exact in bf16) with
-an f32 accumulator — sums are ≤ m·255 < 2^24, exactly representable in any
+scan), widens it to bf16 on the way in (0..255 is exact in bf16; Mosaic
+has no uint8 -> bf16 convert) and rides the one-hot matmul with an f32
+accumulator — sums are ≤ m·255 < 2^24, exactly representable in any
 summation order, so the pool still ranks on integers and the exact fp32
-rescore restores score fidelity. No gather ever widens the LUT: that is the whole point.
+rescore restores score fidelity. No gather ever widens the LUT per
+candidate: that is the whole point.
 
 SELECTION. Serving reaches this kernel only through
 :func:`adc_topr_auto` / the ``search.knn.ann.kernel`` policy
-(search/ann.py): ``pallas`` on TPU, ``interpret=True`` parity path on the
-CPU sim (mirroring ``knn_*_auto``), with :func:`adc_scan_xla` as the
-bit-compatible XLA fallback the parity tests diff against. tpulint TPU016
-enforces the shape statically: ``pl.pallas_call`` lives only under
-``ops/``, reachable only through ``*_auto`` wrappers carrying the
-platform/interpret guard.
+(search/ann.py): ``pallas`` on TPU, the ``interpret=True`` parity path
+only when the backend is the CPU (mirroring ``knn_*_auto``), with
+:func:`adc_scan_xla` as the bit-compatible XLA fallback the parity tests
+diff against. tpulint TPU016 enforces the shape statically:
+``pl.pallas_call`` lives only under ``ops/``, reachable only through
+``*_auto`` wrappers carrying the platform/interpret guard.
 """
 
 from __future__ import annotations
@@ -76,9 +84,9 @@ _NEG_INF = float("-inf")
 
 
 def _adc_scan_kernel(
-    probes_ref,   # scalar prefetch [B, P] int32 (host-selected probe table)
-    lut_ref,      # [1, 1, m, ks] native width (f32 / bf16 / uint8)
-    codes_ref,    # [1, l_blk, m] uint8 — the probed inverted-list block
+    probes_ref,   # scalar prefetch [B * P] int32 (host-selected probe table)
+    lut_ref,      # [1, m * ks] f32 / bf16 — this (query, probe)'s LUT, m-major
+    codes_ref,    # [l_blk, m] uint8 — the probed inverted-list block
     ids_ref,      # [1, l_blk] int32 doc ids (-1 = padding)
     mask_ref,     # [1, l_blk] f32 (1.0 live slot; bool tiles are awkward)
     vals_out,     # [1, R] f32 candidate scores (-adc, higher is better)
@@ -90,8 +98,8 @@ def _adc_scan_kernel(
     ks: int,
     n_lb: int,
     nprobe: int,
-    precision: str,
 ):
+    del probes_ref  # consumed by the BlockSpec index_maps only
     p = pl.program_id(1)
     lb = pl.program_id(2)
 
@@ -100,46 +108,39 @@ def _adc_scan_kernel(
         vals_scr[:] = jnp.full((1, r), _NEG_INF)
         ids_scr[:] = jnp.full((1, r), -1, jnp.int32)
 
-    codes = codes_ref[0].astype(jnp.int32)               # [l_blk, m]
-    m = codes.shape[1]
-    lut = lut_ref[0, 0]                                   # [m, ks] native
-    iota_ks = jax.lax.broadcasted_iota(
-        jnp.int32, (codes.shape[0], ks), 1)
-    # MXU one-hot decode (ROADMAP 2b): sum_m lut[m, code[l, m]] as ONE
-    # [l_blk, m*ks] x [m*ks, 1] matmul. The one-hot operand is m 2D
+    codes = codes_ref[:].astype(jnp.int32)                # [l_blk, m]
+    l_blk, m = codes.shape
+    lut = lut_ref[:]                                      # [1, m * ks]
+    iota_ks = jax.lax.broadcasted_iota(jnp.int32, (l_blk, ks), 1)
+    # MXU one-hot decode: adc[l] = sum_m lut[m, code[l, m]] as ONE
+    # [1, m*ks] x [l_blk, m*ks]^T matmul. The one-hot operand is m 2D
     # lane-compares concatenated lane-wise (no gather, LUT never leaves
-    # VMEM); the [m, ks] LUT flattens m-major so lanes line up. The old
-    # VPU select-and-sum ran m [l_blk, ks] reduces per block — this is
-    # one systolic pass over the same m*ks contraction.
+    # VMEM) and the LUT arrives flattened m-major so lanes line up.
+    # Contracting both operands on their lane axis puts the l_blk
+    # candidates on the RESULT's lanes — the layout the [1, l_blk]
+    # ids/mask rows and the pool merge below already use.
     onehot = jnp.concatenate(
-        [iota_ks == codes[:, mi][:, None] for mi in range(m)], axis=1)
-    lut_col = lut.reshape(m * ks, 1)
-    dn = (((1,), (0,)), ((), ()))
-    if precision == "fp32":
-        # f32 x f32 at HIGHEST: the MXU's six-pass fp32-faithful mode —
-        # products are exact (one-hot), so only summation order can move
-        acc = jax.lax.dot_general(
-            onehot.astype(jnp.float32), lut_col,
-            dn, preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
-    else:
-        # bf16 LUT entries are native; uint8 0..255 is EXACT in bf16
-        # (8 mantissa bits), products are exact one-hot selects, and the
-        # f32 accumulator holds integer sums <= m * 255 < 2^24 exactly in
-        # ANY order — so the int8 pool stays bit-identical to the old
-        # integer accumulation, now at one MXU pass per block
-        acc = jax.lax.dot_general(
-            onehot.astype(jnp.bfloat16), lut_col.astype(jnp.bfloat16),
-            dn, preferred_element_type=jnp.float32)
-    adc = acc[:, 0]
+        [(iota_ks == codes[:, mi:mi + 1]).astype(lut.dtype)
+         for mi in range(m)], axis=1)                     # [l_blk, m * ks]
+    # f32 LUT: HIGHEST is the MXU's fp32-faithful mode — products are
+    # exact (one-hot), so only summation order can move. bf16 LUT entries
+    # (native bf16, or uint8 0..255 widened exactly) ride one MXU pass
+    # with an f32 accumulator; int8 sums are integers <= m * 255 < 2^24,
+    # exact in ANY order, so the int8 pool stays bit-identical to integer
+    # accumulation.
+    adc = jax.lax.dot_general(
+        lut, onehot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if lut.dtype == jnp.float32 else None))  # [1, l_blk]
     # smaller ADC distance = better candidate; ragged tails -> -inf
-    scores = jnp.where(mask_ref[0] > 0.5, -adc, _NEG_INF)[None, :]
+    scores = jnp.where(mask_ref[:] > 0.5, -adc, _NEG_INF)
     cand_ids = ids_ref[:]                                 # [1, l_blk]
 
     # threshold early-exit (the pallas_knn pattern): the R-round merge
     # only runs when this block beats the pool's current Rth-best
-    kth_best = vals_scr[0, r - 1]
-    improves = jnp.max(scores) > kth_best
+    kth_best = vals_scr[:, r - 1]
+    improves = jnp.any(jnp.max(scores, axis=1) > kth_best)
 
     @pl.when(improves)
     def _merge():
@@ -201,53 +202,65 @@ def pallas_adc_topr(
             f"l_blk [{l_blk}] must divide l_pad [{l_pad}] — both are "
             f"powers of two on the serving path")
     n_lb = l_pad // l_blk
-    precision = "fp32"
-    if lut.dtype == jnp.bfloat16:
-        precision = "bf16"
-    elif lut.dtype == jnp.uint8:
-        precision = "int8"
+    if lut.dtype == jnp.uint8:
+        # Mosaic has no uint8 -> bf16 convert; 0..255 is exact in bf16
+        # (8 mantissa bits), so widen on the way in. The LUT is
+        # B*P*m*ks entries — noise next to the streamed code blocks.
+        lut = lut.astype(jnp.bfloat16)
     kernel = functools.partial(
-        _adc_scan_kernel, r=r, ks=ks, n_lb=n_lb, nprobe=P,
-        precision=precision)
+        _adc_scan_kernel, r=r, ks=ks, n_lb=n_lb, nprobe=P)
+    # Mosaic block rule: a block's last two dims are multiples of the
+    # (8, 128) tile or span the array's. Row-shaped operands therefore
+    # carry a unit second-minor axis ([.., 1, width]) and the leading
+    # axes are squeezed (None) so the kernel sees plain [1, width] rows.
+    # The probe table is flat: 2D SMEM pads every row to a full lane tile.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, P, n_lb),
         in_specs=[
-            pl.BlockSpec((1, 1, m, ks), lambda b, p, l, pr: (b, p, 0, 0)),
+            pl.BlockSpec((None, None, 1, m * ks),
+                         lambda b, p, l, pr: (b, p, 0, 0)),
             # the probed list block: the index_map reads the scalar-
             # prefetched probe table, so the DMA streams exactly the
             # blocks the host routed this query to
-            pl.BlockSpec((1, l_blk, m),
-                         lambda b, p, l, pr: (pr[b, p], l, 0)),
-            pl.BlockSpec((1, l_blk), lambda b, p, l, pr: (pr[b, p], l)),
-            pl.BlockSpec((1, l_blk), lambda b, p, l, pr: (pr[b, p], l)),
+            pl.BlockSpec((None, l_blk, m),
+                         lambda b, p, l, pr: (pr[b * P + p], l, 0)),
+            pl.BlockSpec((None, 1, l_blk),
+                         lambda b, p, l, pr: (pr[b * P + p], 0, l)),
+            pl.BlockSpec((None, 1, l_blk),
+                         lambda b, p, l, pr: (pr[b * P + p], 0, l)),
         ],
         out_specs=[
-            pl.BlockSpec((1, r), lambda b, p, l, pr: (b, 0)),
-            pl.BlockSpec((1, r), lambda b, p, l, pr: (b, 0)),
+            pl.BlockSpec((None, 1, r), lambda b, p, l, pr: (b, 0, 0)),
+            pl.BlockSpec((None, 1, r), lambda b, p, l, pr: (b, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, r), jnp.float32),
             pltpu.VMEM((1, r), jnp.int32),
         ],
     )
-    return pl.pallas_call(
+    vals, out_ids = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, r), jnp.float32),
-            jax.ShapeDtypeStruct((B, r), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, r), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, r), jnp.int32),
         ],
         interpret=interpret,
-    )(probes, lut, codes, ids, maskf)
+    )(probes.reshape(B * P), lut.reshape(B, P, 1, m * ks), codes,
+      ids.reshape(nlist, 1, l_pad), maskf.reshape(nlist, 1, l_pad))
+    return vals.reshape(B, r), out_ids.reshape(B, r)
 
 
 def adc_scan_xla(lut, codes, ids, maskf, probes, *, r: int):
     """The fused pipeline's XLA fallback scan: same inputs, same candidate
     ordering (``lax.top_k`` over the probe-major flattened axis matches the
     pool's carried-first tie-break), via the gather lowering the kernel
-    replaces. int8 pools are bit-identical to the kernel's (integer
-    accumulation); fp32/bf16 agree to summation order."""
+    replaces. int8 pool values are bit-identical to the kernel's (integer
+    accumulation); fp32/bf16 agree to summation order. On a TPU
+    ``lax.top_k`` does not order EQUAL values by position, so among ties
+    the two pools may list different ids (seen on a v5e); the kernel keeps
+    the earliest, as the CPU's ``top_k`` does."""
     pcodes = codes[probes].astype(jnp.int32)       # [B, P, L, m]
     pids = ids[probes]                              # [B, P, L]
     pmask = maskf[probes] > 0.5
@@ -370,13 +383,13 @@ def adc_topr_auto(
     contract: Pallas kernels are reachable only through here). ``impl``:
     None (auto) runs the Pallas kernel natively on TPU and the XLA
     fallback scan elsewhere; "pallas" forces the kernel — interpret-mode
-    on a non-TPU backend, the CPU-sim parity path; "xla" forces the
-    fallback scan. ``profiled_kernel`` covers it like the exact entries,
-    so the profiler's ``retraced`` oracle and the roofline fold see
-    direct launches of the fused ADC program too."""
+    only when the backend is the CPU, the tests' parity path; "xla" forces
+    the fallback scan. ``profiled_kernel`` covers it like the exact
+    entries, so the profiler's ``retraced`` oracle and the roofline fold
+    see direct launches of the fused ADC program too."""
     platform = jax.devices()[0].platform
     if impl == "pallas":
-        use_pallas, interpret = True, platform != "tpu"
+        use_pallas, interpret = True, platform == "cpu"
     elif impl == "xla":
         use_pallas, interpret = False, False
     else:

@@ -29,19 +29,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 ships it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.5
-import inspect as _inspect
-
-_SHARD_MAP_NO_CHECK = {
-    ("check_vma" if "check_vma" in _inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
 
 from opensearch_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from opensearch_tpu.ops import knn as knn_ops
@@ -221,7 +210,7 @@ def build_distributed_search(
         mesh=mesh,
         in_specs=(seg_specs, q_specs),
         out_specs=(P(), P()),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -254,7 +243,7 @@ def build_knn_serving_step(
     per-shard winners (the shard's matched-doc count, ≤ k_shard). At the
     default (kernel="xla", score_precision="fp32") scoring runs in fp32
     with HIGHEST matmul precision so results are exact and identical to
-    the host path (VERDICT r2 weak #2). Any other combination routes each
+    the host path. Any other combination routes each
     local shard's scan through ops/pallas_knn.knn_fused_shard — the fused
     blockwise kernel (kernel="pallas"; `interpret` threads the caller's
     platform resolution, ONE read per program build) or its bit-compatible
@@ -338,7 +327,7 @@ def build_knn_serving_step(
         in_specs=(P(DATA_AXIS, None, None), P(DATA_AXIS, None),
                   P(DATA_AXIS, None), P(None, None)),
         out_specs=(P(), P(), P()),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
     return jax.jit(mapped)
 

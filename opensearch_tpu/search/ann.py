@@ -30,8 +30,8 @@ an in-flight one.
 "xla" is the monolithic ops/ivfpq.search lowering; "pallas" is the fused
 blockwise scan (ops/pallas_adc) behind the FusionANNS-style host/device
 cooperative split — host coarse quantization + probe selection, one
-batched device scan — running interpret-mode off-TPU (the parity path,
-mirroring ``knn_*_auto``; NOT a speed path on the CPU sim). "auto"
+batched device scan — interpreted only when the backend is the CPU (the
+tests' parity path, mirroring ``knn_*_auto``; NOT a speed path there). "auto"
 resolves to "pallas" on a TPU backend and "xla" elsewhere, so the CPU sim
 keeps the fast lowering unless a test/soak forces the kernel.
 
@@ -222,6 +222,12 @@ class AnnServingConfig:
             "kernel": self.kernel,
             "exact_kernel": self.exact_kernel,
             "score_precision": self.score_precision,
+            # what the policies mean on THIS backend: the kernels the next
+            # dispatch launches (and keys its batch by)
+            "resolved": {
+                "kernel": resolve_kernel(self.kernel),
+                "exact_kernel": resolve_kernel(self.exact_kernel),
+            },
         }
         # index-build accounting (index/device.py): how many IVF-PQ
         # structures this process built at publish time, and their cost

@@ -177,8 +177,7 @@ class ShardContext:
         for a KnnQuery, with the top-k cut applied across the whole shard.
 
         Large exact segments score through ops/fused.knn_topk_streaming
-        (the corpus-chunked scan that never materializes [B, n] — VERDICT
-        r4 weak #2 wired into the serving path): only the [1, k] winners
+        (the corpus-chunked scan that never materializes [B, n]): only the [1, k] winners
         come back to host, as a sparse -inf-based score array (the same
         representation the ANN path uses). Small segments keep the eager
         materializing scan — a [1, n] row below the streaming threshold

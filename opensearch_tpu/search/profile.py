@@ -361,21 +361,15 @@ def _host_bytes(value: Any) -> int:
 
 
 def _is_jax_array(value: Any) -> bool:
-    try:
-        import jax
+    import jax
 
-        return isinstance(value, jax.Array)
-    except (ImportError, AttributeError):  # no jax Array API: not a jax array
-        return False
+    return isinstance(value, jax.Array)
 
 
 def _under_trace(args: tuple) -> bool:
-    try:
-        from jax.core import Tracer
+    import jax
 
-        return any(isinstance(a, Tracer) for a in args)
-    except (ImportError, AttributeError):  # jax internals moved; assume eager
-        return False
+    return any(isinstance(a, jax.core.Tracer) for a in args)
 
 
 def _signature(name: str, args: tuple, kwargs: dict) -> tuple:

@@ -2,7 +2,7 @@
 
 The Node.java:494 analog. One ClusterServer = a TcpTransport (L2), a
 ClusterNode (coordinator + shards + action handlers), a LoopScheduler
-(timers), and — the round-3 unification (VERDICT r2 missing #4) — the SAME
+(timers), and the SAME
 128-route trie router the single-node server uses (rest/handlers.py),
 served over a ClusterFacade that gives every handler the TpuNode API with
 cluster semantics (one RestController + NodeClient in front of one action
@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import json
 from pathlib import Path
 
 from opensearch_tpu.cluster.cluster_node import ClusterNode
@@ -103,6 +104,12 @@ class ClusterServer:
 
 
 async def amain(args: argparse.Namespace) -> None:
+    from opensearch_tpu.bootstrap import (
+        configure_compile_cache,
+        startup_report,
+    )
+
+    report = startup_report(configure_compile_cache())
     seeds = parse_seeds(args.seeds)
     server = ClusterServer(
         args.node_id, args.data, args.host,
@@ -112,7 +119,8 @@ async def amain(args: argparse.Namespace) -> None:
     bootstrap = args.bootstrap.split(",") if args.bootstrap else None
     await server.start(bootstrap=bootstrap)
     print(f"[{args.node_id}] transport {seeds[args.node_id]} "
-          f"http 127.0.0.1:{args.http_port}", flush=True)
+          f"http 127.0.0.1:{args.http_port} "
+          f"started={json.dumps(report)}", flush=True)
     await asyncio.Event().wait()  # run forever
 
 

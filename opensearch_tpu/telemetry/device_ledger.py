@@ -198,12 +198,9 @@ def active_scope() -> dict:
 
 
 def _default_device() -> str:
-    try:
-        import jax
+    import jax
 
-        return str(jax.devices()[0])
-    except (ImportError, RuntimeError):  # no backend: still account bytes
-        return "device:0"
+    return str(jax.devices()[0])
 
 
 class Allocation:
@@ -761,7 +758,25 @@ def stats_section() -> dict:
 
     out = default_ledger.snapshot_stats()
     out["shard_mesh"] = default_registry.snapshot_stats()
+    out["backend_memory"] = backend_memory()
     return out
+
+
+def backend_memory() -> list[dict]:
+    """What the backend itself reports for each device
+    (``Device.memory_stats()``; the CPU backend reports nothing): the
+    outside check on the ledger's own bookkeeping, and the only per-device
+    split of a structure the ledger books to a whole ``mesh[N]``."""
+    import jax
+
+    rows = []
+    for dev in jax.devices():
+        stats = dev.memory_stats() or {}
+        rows.append({"device": str(dev), **{
+            key: int(stats[key])
+            for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+            if key in stats}})
+    return rows
 
 
 def heat_section() -> dict:

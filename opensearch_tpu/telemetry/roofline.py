@@ -393,9 +393,8 @@ _KERNEL_PARAM_ADAPTERS: dict[str, Callable[[tuple, dict], dict]] = {
 class PlatformPeaks:
     """What this backend actually sustains: peak FLOP/s from a large
     fenced matmul, peak HBM bytes/s from an on-device copy. `source` is
-    "measured" (the microbenchmark ran), "stub" (injected — sims, soak),
-    or "fallback" (no backend; fixed conservative numbers so fraction
-    math never divides by zero)."""
+    "measured" (the microbenchmark ran on `platform`) or "stub"
+    (injected — sims, soak)."""
 
     __slots__ = ("platform", "flops_per_s", "bytes_per_s", "source",
                  "calibrated_at_ms")
@@ -499,20 +498,13 @@ def _measure_peaks() -> PlatformPeaks:
 def calibrate(force: bool = False) -> PlatformPeaks:
     """Run (or reuse) the platform calibration. Cached per platform;
     `force=True` re-measures (the `POST /_roofline/calibrate` button).
-    Without a usable backend a fixed fallback keeps the math defined."""
+    A process that cannot see its backend raises here — node boot calls
+    this, so such a node does not come up (sims install `stub_peaks`
+    first and never reach the backend)."""
     global _active_peaks
-    try:
-        import jax
+    import jax
 
-        platform = jax.devices()[0].platform
-    except Exception as e:  # noqa: BLE001 - no backend: fixed fallback peaks
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "roofline calibration has no usable backend (%s): using "
-            "fallback peaks", e)
-        return set_peaks(PlatformPeaks("none", 1.0e11, 2.5e10,
-                                       source="fallback"))
+    platform = jax.devices()[0].platform
     if not force:
         with _peaks_lock:
             cached = _peaks_by_platform.get(platform)

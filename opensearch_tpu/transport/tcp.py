@@ -64,7 +64,7 @@ _KIND_JSON = 0x00    # [len][0x00][json]
 _KIND_BINARY = 0x01  # [len][0x01][u32 json_len][json][raw bytes]
 # a JSON payload/result dict may carry raw bytes under this key; the codec
 # ships them out-of-band (no base64) — the data-plane path segment
-# replication needs (VERDICT r2 weak #9 / missing #2)
+# replication needs
 BINARY_KEY = "_binary"
 
 

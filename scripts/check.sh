@@ -23,8 +23,9 @@
 #                               # traffic, invariants-only)
 #   scripts/check.sh --bench    # + the bench-regression gates: a quick
 #                               # bench.py --gate run must stay within a
-#                               # CPU/TPU-aware tolerance of the same
-#                               # platform's BENCH_CACHE.json entry, and
+#                               # CPU/TPU-aware tolerance of the last full
+#                               # bench.py result on the same platform
+#                               # (none on record: passes with a note), and
 #                               # bench.py --mesh-gate holds the shard-mesh
 #                               # cluster bench to BENCH_MESH.json the same
 #                               # way, and bench.py --ann-gate holds the
@@ -114,7 +115,7 @@ if [[ "${1:-}" == "--soak-tcp" ]]; then
 fi
 
 if [[ "${1:-}" == "--bench" ]]; then
-  echo "== bench-regression gate (quick run vs BENCH_CACHE.json) =="
+  echo "== bench-regression gate (quick run vs the last bench.py result on this platform) =="
   python bench.py --gate
   echo "== shard-mesh gate (quick cluster run vs BENCH_MESH.json) =="
   python bench.py --mesh-gate

@@ -401,7 +401,7 @@ def test_geo_bounds_and_centroid(geo_node):
 
 
 def test_range_field_ipv6_and_open_bounds(geo_node):
-    """VERDICT review: IPv6 ordinals exceed 2^62 — open bounds must sit at
+    """IPv6 ordinals exceed 2^62 — open bounds must sit at
     the int64 edges, and single-address string values are one-point
     ranges."""
     node = geo_node

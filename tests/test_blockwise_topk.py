@@ -1,6 +1,6 @@
 """Exactness of the blockwise top-k (block-max pruning) vs lexsort.
 
-VERDICT r1 #3: the monolithic lax.top_k over [B, 1M] was the perf hot spot;
+The monolithic lax.top_k over [B, 1M] was the perf hot spot;
 blockwise_topk must be bit-exact under the (score desc, doc id asc) order.
 """
 

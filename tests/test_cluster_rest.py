@@ -1,6 +1,6 @@
 """The FULL REST surface against a real 3-node TCP cluster.
 
-VERDICT r2 missing #4's bar: cluster mode serves search with aggregations,
+The bar: cluster mode serves search with aggregations,
 scroll, PIT, doc CRUD (incl. update/mget/count/msearch) and the stats/cat
 surface through ANY node, via the same 128-route trie router the
 single-node server uses (one RestController + one action registry,

@@ -1,4 +1,4 @@
-"""The on-device cross-shard merge in the REAL serving path (VERDICT r2 #2).
+"""The on-device cross-shard merge in the REAL serving path.
 
 A multi-shard knn _search must execute the shard_map program
 (parallel/distributed.build_knn_serving_step: per-shard scoring + top-k on

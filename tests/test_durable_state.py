@@ -1,6 +1,6 @@
 """Durable cluster state: full-cluster stop/start retains metadata + data.
 
-VERDICT r2 missing #3 / task #5: PersistedState (term + accepted state) is
+PersistedState (term + accepted state) is
 write-ahead persisted per node (gateway.GatewayStore — the
 PersistedClusterStateService:137 analog); on reboot the node recovers the
 state BEFORE elections (no double vote in an old term) and recreates its

@@ -2,7 +2,7 @@
 
 Reference surface: InternalEngine.java:152 (OpenSearchConcurrentMergeScheduler,
 TieredMergePolicy, CombinedDeletionPolicy), TransportForceMergeAction.
-VERDICT r1 #6 done-criteria: many refreshes end in a bounded segment count,
+Done-criteria: many refreshes end in a bounded segment count,
 deleted docs are reclaimed, search results unchanged.
 """
 

@@ -47,6 +47,16 @@ class TestVarintCodec:
         assert np.array_equal(out_py, docs)
 
 
+def test_library_is_named_by_its_source_content(tmp_path, monkeypatch):
+    """A binary carried over in a copied tree (mtimes and all) must never
+    be loaded for a different source: the file name follows the content."""
+    built_for = native._lib_path().name
+    edited = tmp_path / "tlog_codec.cpp"
+    edited.write_bytes(native._SRC.read_bytes() + b"\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", edited)
+    assert native._lib_path().name != built_for
+
+
 class TestNativeTlog:
     @pytest.mark.skipif(not native.native_available(),
                         reason="no C++ toolchain")

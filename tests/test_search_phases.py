@@ -1,5 +1,5 @@
-"""can_match shard skipping, rescore, collapse, sliced scroll (VERDICT r2
-missing #7/#8 — CanMatchPreFilterSearchPhase.java, search/rescore/
+"""can_match shard skipping, rescore, collapse, sliced scroll
+(CanMatchPreFilterSearchPhase.java, search/rescore/
 RescorePhase.java, search/collapse/CollapseContext.java,
 search/slice/SliceBuilder.java)."""
 

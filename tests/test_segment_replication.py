@@ -1,4 +1,4 @@
-"""Segment replication over binary transport frames (VERDICT r2 missing #2).
+"""Segment replication over binary transport frames.
 
 index.replication.type=SEGMENT: replicas never index documents — writes
 append only to their translog (durability + promotion source); searchable

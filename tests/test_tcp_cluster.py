@@ -2,7 +2,7 @@
 
 The InternalTestCluster analog (SURVEY.md §4 answer #1: whole nodes in one
 process with real transports on loopback) applied to the TCP transport —
-VERDICT r1 #1 done-criteria: a 3-process-shaped cluster elects a leader,
+done-criteria: a 3-process-shaped cluster elects a leader,
 serves _bulk/_search/_cluster/health through ANY node's REST port, and
 survives kill-the-leader with no acknowledged-write loss.
 """
@@ -349,7 +349,7 @@ def test_leader_kill_mid_bulk(tcp_cluster):
 
         # every acked doc must be readable after failover; promotion and
         # replica repair may still be settling, so retry to a deadline
-        # (condition-based, r3 VERDICT item #10)
+        # (condition-based, not a fixed sleep)
         deadline = loop.time() + 60.0
         missing = sorted(acked)
         while missing and loop.time() < deadline:
