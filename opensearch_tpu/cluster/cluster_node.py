@@ -3000,6 +3000,7 @@ class ClusterNode:
                 exporter = self.telemetry.tracer.exporter
                 if exporter is not None:
                     telemetry["exporter"] = exporter.snapshot_stats()
+                telemetry["capture"] = self.telemetry.tracer.capture_stats()
             resp["name"] = self.node_id
             resp["telemetry"] = telemetry
             if want("knn_batch"):

@@ -405,6 +405,16 @@ class TpuNode:
 
         self.knn_batcher = _batcher_mod.default_batcher
         self.knn_batcher.metrics = self.telemetry.metrics
+        # request-detail captures (telemetry/tracing.py): written under the
+        # data path when a profiler session ends, with these counters
+        # snapshotted at its open and close
+        from opensearch_tpu.telemetry.device_ledger import default_ledger
+
+        self.telemetry.tracer.capture_dir = self.data_path / "telemetry"
+        self.telemetry.tracer.capture_counters = lambda: {
+            "knn_batch": dict(self.knn_batcher.stats),
+            "device_resident_bytes": default_ledger.resident_bytes(),
+        }
         # roofline recorder (telemetry/roofline.py): process-wide like the
         # batcher; this node is its fallback metrics sink (active_metrics()
         # still attributes per executing request scope). Peaks calibrate
@@ -3085,6 +3095,7 @@ class TpuNode:
             body = self.search_pipelines.transform_request(pl, body)
             if "_original_size" in body:
                 pl_ctx["_original_size"] = body.pop("_original_size")
+        from opensearch_tpu.telemetry import spans as span_names
         from opensearch_tpu.telemetry import tracing
 
         # activate() scopes phase spans (can_match/rescore/collapse) to
@@ -3092,7 +3103,7 @@ class TpuNode:
         # entry can carry the trace_id
         with tracing.activate(self.telemetry.tracer), \
                 self.telemetry.tracer.start_span(
-                    "search", {"indices": expr}
+                    span_names.SEARCH, {"indices": expr}
                 ) as span:
             resp = search_service.search(
                 shards, body, acquired=acquired,

@@ -540,12 +540,31 @@ def host_probe_select(index: IVFPQIndex, queries: np.ndarray,
     return np.take_along_axis(part, order, axis=1).astype(np.int32)
 
 
-def search_index(
+def select_probes(index: IVFPQIndex, queries, nprobe: int | None,
+                  kernel: str):
+    """The host's half of a launch under the RESOLVED serving policy
+    ``kernel`` (search/ann.py resolve_kernel), as (queries, probes) for
+    :func:`search_probed`. "pallas" is a cooperative split: the queries
+    are normalized and coarse-quantized and the probes selected here, on
+    the host (:func:`host_probe_select`). "xla" selects its probes on the
+    device, so the queries pass through and probes is None."""
+    if kernel != "pallas":
+        return queries, None
+    qh = np.asarray(queries, dtype=np.float32)
+    if index.normalized:
+        q_norm = np.linalg.norm(qh, axis=-1, keepdims=True)
+        qh = qh / np.maximum(q_norm, 1e-12)
+    nprobe = min(nprobe or DEFAULT_NPROBE, index.params.nlist)
+    return qh, host_probe_select(index, qh, nprobe)
+
+
+def search_probed(
     index: IVFPQIndex,
     vectors: jnp.ndarray,
     norms_sq: jnp.ndarray,
     valid: jnp.ndarray,
-    queries: jnp.ndarray,
+    queries,
+    probes,
     *,
     k: int,
     nprobe: int | None = None,
@@ -553,33 +572,23 @@ def search_index(
     similarity: str = "l2_norm",
     adc_precision: str = "fp32",
     rescore_multiplier: int | None = None,
-    kernel: str = "xla",
 ):
-    """Convenience wrapper binding an IVFPQIndex's arrays to the selected
-    ADC scan. ``kernel`` is the RESOLVED serving policy
-    (search/ann.py resolve_kernel): "xla" runs the monolithic
-    :func:`search` lowering; "pallas" runs the cooperative split — coarse
-    quantization + probe selection host-side (:func:`host_probe_select`),
-    then ONE batched fused Pallas scan + exact rescore on device
-    (ops/pallas_adc.adc_topr_auto; interpret-mode only on the CPU backend)."""
-    nprobe = nprobe or DEFAULT_NPROBE
+    """The device's half, on what :func:`select_probes` returned: with
+    probes, ONE batched fused Pallas scan + exact rescore
+    (ops/pallas_adc.adc_topr_auto; interpret-mode only on the CPU
+    backend); without, the monolithic :func:`search` lowering. Returns
+    device arrays; the caller's host copy is the fence."""
     if rerank is None:
         rerank = default_rerank(k, rescore_multiplier)
     similarity = knn_ops.canonical_similarity(similarity)
-    if kernel == "pallas":
+    if probes is not None:
         from opensearch_tpu.ops import pallas_adc
 
-        qh = np.asarray(queries, dtype=np.float32)
-        if index.normalized:
-            q_norm = np.linalg.norm(qh, axis=-1, keepdims=True)
-            qh = qh / np.maximum(q_norm, 1e-12)
-        probes = host_probe_select(
-            index, qh, min(nprobe, index.params.nlist))
         return pallas_adc.adc_topr_auto(
             index.params.coarse, index.params.codebooks,
             index.codes, index.ids, index.mask,
             vectors, norms_sq, valid,
-            jnp.asarray(qh), jnp.asarray(probes),
+            jnp.asarray(queries), jnp.asarray(probes),
             k=k, rerank=rerank,
             similarity=similarity, adc_precision=adc_precision,
             impl="pallas")
@@ -597,8 +606,33 @@ def search_index(
         valid,
         queries,
         k=k,
-        nprobe=min(nprobe, index.params.nlist),
+        nprobe=min(nprobe or DEFAULT_NPROBE, index.params.nlist),
         rerank=rerank,
         similarity=similarity,
         adc_precision=adc_precision,
     )
+
+
+def search_index(
+    index: IVFPQIndex,
+    vectors: jnp.ndarray,
+    norms_sq: jnp.ndarray,
+    valid: jnp.ndarray,
+    queries: jnp.ndarray,
+    *,
+    k: int,
+    nprobe: int | None = None,
+    rerank: int | None = None,
+    similarity: str = "l2_norm",
+    adc_precision: str = "fp32",
+    rescore_multiplier: int | None = None,
+    kernel: str = "xla",
+):
+    """Convenience wrapper binding an IVFPQIndex's arrays to the selected
+    ADC scan: :func:`select_probes`, then :func:`search_probed`. The
+    serving closure calls the two halves itself, each in its own span."""
+    queries, probes = select_probes(index, queries, nprobe, kernel)
+    return search_probed(
+        index, vectors, norms_sq, valid, queries, probes,
+        k=k, nprobe=nprobe, rerank=rerank, similarity=similarity,
+        adc_precision=adc_precision, rescore_multiplier=rescore_multiplier)

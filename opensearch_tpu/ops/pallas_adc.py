@@ -247,6 +247,7 @@ def pallas_adc_topr(
             jax.ShapeDtypeStruct((B, 1, r), jnp.int32),
         ],
         interpret=interpret,
+        name="fused_adc_search",
     )(probes.reshape(B * P), lut.reshape(B, P, 1, m * ks), codes,
       ids.reshape(nlist, 1, l_pad), maskf.reshape(nlist, 1, l_pad))
     return vals.reshape(B, r), out_ids.reshape(B, r)

@@ -808,6 +808,7 @@ def pallas_knn_fused(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="pallas_knn_fused",
     )(
         q_x,
         qsq,

@@ -255,6 +255,9 @@ def build_knn_serving_step(
     caller's (distributed_serving picks D as a divisor of S)."""
     fused = (kernel, score_precision) != ("xla", "fp32")
 
+    # a stable scope name: a trace reduction can match the step's device ops
+    # to the host's `launch.device` span by name after a refactor
+    @jax.named_scope("mesh_knn_step")
     def step(vectors, norms_sq, valid, queries):
         # block shapes: [S_local, n, d], [S_local, n], [S_local, n], [B, d]
         s_local, n_flat, _d = vectors.shape

@@ -3334,6 +3334,9 @@ def nodes_stats(node: TpuNode, params, query, body):
             # accounting) — same surface the cluster fan-out merges
             **({"exporter": node.telemetry.tracer.exporter.snapshot_stats()}
                if node.telemetry.tracer.exporter is not None else {}),
+            # request-detail capture (spans written while a jax.profiler
+            # session runs): open, records, dropped, last file
+            "capture": node.telemetry.tracer.capture_stats(),
         },
         "slowlog": {
             "search": node.search_slowlog.entries()[-10:],

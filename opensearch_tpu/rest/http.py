@@ -15,6 +15,7 @@ import argparse
 import asyncio
 import json
 import logging
+import time
 import traceback
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any
@@ -23,6 +24,8 @@ from urllib.parse import parse_qsl, unquote, urlsplit
 from opensearch_tpu.common.errors import OpenSearchTpuException
 from opensearch_tpu.node import TpuNode
 from opensearch_tpu.rest.handlers import build_router
+from opensearch_tpu.telemetry import spans as span_names
+from opensearch_tpu.telemetry import tracing
 
 MAX_BODY = 100 * 1024 * 1024  # the reference's http.max_content_length default
 
@@ -132,13 +135,14 @@ class HttpServer:
                 if request is None:
                     break
                 method, path, query, headers, body = request
-                status, payload, content_type = await self._dispatch(
+                status, payload, content_type, root = await self._dispatch(
                     method, path, query, body
                 )
                 keep_alive = headers.get("connection", "keep-alive") != "close"
                 await self._write_response(
                     writer, status, payload, content_type,
                     keep_alive=keep_alive, head=(method == "HEAD"),
+                    root=root,
                 )
                 if not keep_alive:
                     break
@@ -196,71 +200,81 @@ class HttpServer:
 
     async def _dispatch(
         self, method: str, path: str, query: dict, raw_body: bytes
-    ) -> tuple[int, Any, str]:
-        try:
-            handler, params = self.router.resolve(method, path)
-            body = _parse_body(path, raw_body)
-            # transport knows the payload size; hand it to bulk so the
-            # pressure estimate doesn't re-serialize every document
-            if path.endswith("/_bulk") or path == "/_bulk":
-                query["_payload_bytes"] = len(raw_body)
-            # in-flight request bytes against the breaker (the reference's
-            # in_flight_requests child tracks transport payload bytes)
-            breakers = getattr(self.node, "breakers", None)
-            if breakers is not None and raw_body:
-                breakers.in_flight_requests.add_estimate_and_maybe_break(
-                    len(raw_body), "<http_request>"
-                )
-            # only the lock-protected TaskManager endpoints may run
-            # concurrently with the data worker; stats/cat iterate engine
-            # structures that are single-writer. Read-only searches run on
-            # the parallel search pool — split by PRIORITY LANE (see
-            # __init__) so background msearch floods can't occupy the
-            # interactive workers.
-            from opensearch_tpu.search import lanes as lanes_mod
-            from opensearch_tpu.telemetry import default_telemetry
+    ) -> tuple[int, Any, str, Any]:
+        """(status, payload, content type, the request's root span — which
+        the response write hangs `http.respond` under)."""
+        from opensearch_tpu.search import lanes as lanes_mod
+        from opensearch_tpu.telemetry import default_telemetry
 
-            telemetry = getattr(self.node, "telemetry", default_telemetry)
-            lane_cfg = lanes_mod.default_config
-            lane = (lanes_mod.classify_rest(path, query)
-                    if lane_cfg.enabled else lanes_mod.INTERACTIVE)
-            # the lane reaches handlers through the lane_scope contextvar
-            # below — never the query dict (strict handlers reject
-            # unrecognized parameters)
-            tracked = False
-            if path.startswith("/_tasks"):
-                executor = self._mgmt_executor
-            elif self._is_parallel_search(path, query):
-                tracked = True
-                if lane_cfg.enabled and lane == lanes_mod.BACKGROUND:
-                    executor = self._background_executor
-                    if not self.lane_tracker.try_submit(
-                            lane, lane_cfg.background_max_queue):
-                        # bounded background lane: shed, never queue
-                        # without bound (the QueuePressure contract)
-                        lanes_mod.record_lane_shed(telemetry.metrics, lane)
-                        if breakers is not None and raw_body:
-                            breakers.in_flight_requests.release(len(raw_body))
-                        return 429, {
-                            "error": {
-                                "type": "rejected_execution_exception",
-                                "reason": "background lane queue is full",
-                            },
-                            "status": 429,
-                        }, "application/json"
+        telemetry = getattr(self.node, "telemetry", default_telemetry)
+        root = None
+        try:
+            with telemetry.tracer.start_span(
+                span_names.HTTP_REQUEST, {"method": method, "path": path}
+            ) as span:
+                root = span
+                with tracing.detail(span_names.HTTP_PARSE):
+                    handler, params = self.router.resolve(method, path)
+                    body = _parse_body(path, raw_body)
+                    # transport knows the payload size; hand it to bulk so
+                    # the pressure estimate doesn't re-serialize every
+                    # document
+                    if path.endswith("/_bulk") or path == "/_bulk":
+                        query["_payload_bytes"] = len(raw_body)
+                    lane_cfg = lanes_mod.default_config
+                    lane = (lanes_mod.classify_rest(path, query)
+                            if lane_cfg.enabled else lanes_mod.INTERACTIVE)
+                span.set_attribute("lane", lane)
+                # in-flight request bytes against the breaker (the
+                # reference's in_flight_requests child tracks transport
+                # payload bytes)
+                breakers = getattr(self.node, "breakers", None)
+                if breakers is not None and raw_body:
+                    breakers.in_flight_requests.add_estimate_and_maybe_break(
+                        len(raw_body), "<http_request>"
+                    )
+                # only the lock-protected TaskManager endpoints may run
+                # concurrently with the data worker; stats/cat iterate
+                # engine structures that are single-writer. Read-only
+                # searches run on the parallel search pool — split by
+                # PRIORITY LANE (see __init__) so background msearch floods
+                # can't occupy the interactive workers. The lane reaches
+                # handlers through the lane_scope contextvar below — never
+                # the query dict (strict handlers reject unrecognized
+                # parameters)
+                tracked = False
+                if path.startswith("/_tasks"):
+                    executor = self._mgmt_executor
+                elif self._is_parallel_search(path, query):
+                    tracked = True
+                    if lane_cfg.enabled and lane == lanes_mod.BACKGROUND:
+                        executor = self._background_executor
+                        if not self.lane_tracker.try_submit(
+                                lane, lane_cfg.background_max_queue):
+                            # bounded background lane: shed, never queue
+                            # without bound (the QueuePressure contract)
+                            lanes_mod.record_lane_shed(
+                                telemetry.metrics, lane)
+                            if breakers is not None and raw_body:
+                                breakers.in_flight_requests.release(
+                                    len(raw_body))
+                            span.set_attribute("status", 429)
+                            return 429, {
+                                "error": {
+                                    "type": "rejected_execution_exception",
+                                    "reason": "background lane queue is full",
+                                },
+                                "status": 429,
+                            }, "application/json", root
+                    else:
+                        executor = self._search_executor
+                        self.lane_tracker.try_submit(lane)
+                    lanes_mod.record_lane_metrics(
+                        telemetry.metrics, lane,
+                        self.lane_tracker.depth(lane))
                 else:
-                    executor = self._search_executor
-                    self.lane_tracker.try_submit(lane)
-                lanes_mod.record_lane_metrics(
-                    telemetry.metrics, lane, self.lane_tracker.depth(lane))
-            else:
-                executor = self._executor
-            span_cm = telemetry.tracer.start_span(
-                "http_request", {"method": method, "path": path,
-                                 "lane": lane}
-            )
-            try:
-                with span_cm as span:
+                    executor = self._executor
+                try:
                     # handlers are synchronous work; run them off the event
                     # loop so slow searches don't stall socket IO. The
                     # contextvars context is copied into the worker thread so
@@ -268,7 +282,19 @@ class HttpServer:
                     # the lane scope rides it into the dispatch batcher).
                     import contextvars as _cv
 
+                    # the wait for a pool worker: stamped here, on the loop
+                    # thread, and recorded by the worker that ends it
+                    submitted_ns = (time.perf_counter_ns()
+                                    if span.detail is not None else 0)
+
                     def run_handler():
+                        if submitted_ns:
+                            with tracing.detail(
+                                    span_names.HTTP_POOL_WAIT) as waited:
+                                waited.attributes.update(
+                                    wait_ns=waited.start_ns - submitted_ns,
+                                    submitted_ns=submitted_ns,
+                                    workers=executor._max_workers)
                         with lanes_mod.lane_scope(lane):
                             return handler(self.node, params, query, body)
 
@@ -277,11 +303,11 @@ class HttpServer:
                         executor, ctx.run, run_handler,
                     )
                     span.set_attribute("status", status)
-            finally:
-                if tracked:
-                    self.lane_tracker.complete(lane)
-                if breakers is not None and raw_body:
-                    breakers.in_flight_requests.release(len(raw_body))
+                finally:
+                    if tracked:
+                        self.lane_tracker.complete(lane)
+                    if breakers is not None and raw_body:
+                        breakers.in_flight_requests.release(len(raw_body))
             if "filter_path" in query and status < 400:
                 from opensearch_tpu.rest.handlers import apply_filter_path
 
@@ -289,42 +315,46 @@ class HttpServer:
             content_type = (
                 "text/plain" if isinstance(payload, str) else "application/json"
             )
-            return status, payload, content_type
+            return status, payload, content_type, root
         except OpenSearchTpuException as e:
-            return e.status, _error_envelope(e), "application/json"
+            return e.status, _error_envelope(e), "application/json", root
         except json.JSONDecodeError as e:
             return 400, {
                 "error": {"type": "parse_exception", "reason": str(e)},
                 "status": 400,
-            }, "application/json"
+            }, "application/json", root
         except Exception as e:  # noqa: BLE001 - top-level 500 guard
             traceback.print_exc()
             return 500, {
                 "error": {"type": "exception", "reason": str(e)},
                 "status": 500,
-            }, "application/json"
+            }, "application/json", root
 
     async def _write_response(
         self, writer, status: int, payload: Any, content_type: str,
-        keep_alive: bool, head: bool,
+        keep_alive: bool, head: bool, root=None,
     ) -> None:
-        if isinstance(payload, str):
-            data = payload.encode()
-        else:
-            data = json.dumps(payload).encode()
-        reason = {200: "OK", 201: "Created", 400: "Bad Request", 404: "Not Found",
-                  405: "Method Not Allowed", 409: "Conflict",
-                  413: "Content Too Large", 429: "Too Many Requests",
-                  500: "Internal Server Error",
-                  503: "Service Unavailable"}.get(status, "OK")
-        head_lines = (
-            f"HTTP/1.1 {status} {reason}\r\n"
-            f"content-type: {content_type}; charset=UTF-8\r\n"
-            f"content-length: {len(data)}\r\n"
-            f"connection: {'keep-alive' if keep_alive else 'close'}\r\n\r\n"
-        )
-        writer.write(head_lines.encode() + (b"" if head else data))
-        await writer.drain()
+        # the write follows `http_request`'s close: its span hangs under
+        # the root the dispatch handed back
+        with tracing.detail(span_names.HTTP_RESPOND, parent=root) as span:
+            if isinstance(payload, str):
+                data = payload.encode()
+            else:
+                data = json.dumps(payload).encode()
+            span.set_attribute("bytes", len(data))
+            reason = {200: "OK", 201: "Created", 400: "Bad Request", 404: "Not Found",
+                      405: "Method Not Allowed", 409: "Conflict",
+                      413: "Content Too Large", 429: "Too Many Requests",
+                      500: "Internal Server Error",
+                      503: "Service Unavailable"}.get(status, "OK")
+            head_lines = (
+                f"HTTP/1.1 {status} {reason}\r\n"
+                f"content-type: {content_type}; charset=UTF-8\r\n"
+                f"content-length: {len(data)}\r\n"
+                f"connection: {'keep-alive' if keep_alive else 'close'}\r\n\r\n"
+            )
+            writer.write(head_lines.encode() + (b"" if head else data))
+            await writer.drain()
 
 
 def _parse_body(path: str, raw: bytes) -> Any:

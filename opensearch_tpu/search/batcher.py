@@ -98,6 +98,8 @@ from typing import Any, Callable, Sequence
 from opensearch_tpu.common import timeutil
 from opensearch_tpu.common.settings import Property, Setting
 from opensearch_tpu.index.pressure import QueuePressure
+from opensearch_tpu.telemetry import spans as span_names
+from opensearch_tpu.telemetry import tracing
 
 # -- settings (registered dynamic in cluster/cluster_settings.py) -----------
 
@@ -168,7 +170,7 @@ class _KeyTuner:
                 else _EWMA_DECAY * self.ewma_gap_ms + (1 - _EWMA_DECAY) * gap)
         self.last_arrival_ms = now_ms
 
-    def note_flush(self, merged: int, max_wait_ms: int) -> None:
+    def note_flush(self, merged: int, max_wait_ms: float) -> None:
         self.ewma_merged = (_EWMA_DECAY * self.ewma_merged
                             + (1 - _EWMA_DECAY) * merged)
         self.ewma_wait_ms = (_EWMA_DECAY * self.ewma_wait_ms
@@ -209,14 +211,20 @@ class _KeyTuner:
 
 
 class _Entry:
-    __slots__ = ("payload", "enq_ms", "taken", "done", "result", "error",
-                 "batch_size", "wall_ns", "retraced", "wait_ms", "launch",
-                 "rank", "tune_key")
+    __slots__ = ("payload", "enq_ns", "taken_ns", "taken", "done", "result",
+                 "error", "batch_size", "wall_ns", "retraced", "launch",
+                 "rank", "tune_key", "leader_span")
 
-    def __init__(self, payload: Any, enq_ms: int, launch=None, rank: int = 0,
+    def __init__(self, payload: Any, enq_ns: int, launch=None, rank: int = 0,
                  tune_key: Any = None):
         self.payload = payload
-        self.enq_ms = enq_ms
+        # the queue wait is measured by ONE pair of stamps
+        # (`time.perf_counter_ns`): enqueue, here, and take, by the leader
+        # in `_take_locked`. `wait_ms`, the `knn.batch.queue_wait_ms`
+        # histogram, the tuner's measured waits and the `batch.wait` span's
+        # `queue_wait_ns` all derive from it
+        self.enq_ns = enq_ns
+        self.taken_ns = enq_ns
         self.taken = False
         self.done = False
         self.result: Any = None
@@ -224,7 +232,6 @@ class _Entry:
         self.batch_size = 1
         self.wall_ns = 0
         self.retraced = False
-        self.wait_ms = 0
         # the entry's own launch closure + its k-bucket rank: a batch is
         # always launched by the closure of its LARGEST-rank member, so a
         # smaller-k joiner (cross-k coalescing) can ride a bigger-k launch
@@ -233,6 +240,14 @@ class _Entry:
         self.rank = rank
         # generation-free key family feeding the per-key wait auto-tuner
         self.tune_key = tune_key
+        # the `launch` span of the leader that took the entry, which a
+        # follower's `batch.wait` span names as its cause
+        self.leader_span: str | None = None
+
+    @property
+    def wait_ms(self) -> float:
+        """Milliseconds queued, enqueue to take, in ns resolution."""
+        return (self.taken_ns - self.enq_ns) / 1e6
 
 
 class _Bucket:
@@ -251,7 +266,7 @@ class DispatchOutcome:
     __slots__ = ("value", "merged", "wall_ns", "retraced", "wait_ms")
 
     def __init__(self, value: Any, merged: int, wall_ns: int,
-                 retraced: bool, wait_ms: int):
+                 retraced: bool, wait_ms: float):
         self.value = value
         self.merged = merged          # live queries in the batch
         self.wall_ns = wall_ns        # fenced wall of the whole launch
@@ -464,64 +479,62 @@ class KnnDispatchBatcher:
                       and lanes_mod.active_lane() == lanes_mod.BACKGROUND)
         if tune_key is None:
             tune_key = key
-        with self._cond:
-            self.pressure.acquire()
-            entry = _Entry(payload, timeutil.monotonic_millis(),
-                           launch=launch, rank=rank, tune_key=tune_key)
-            tuner = None
-            if self.auto_tune:
-                tuner = self._tuner_locked(tune_key)
-                tuner.note_arrival(entry.enq_ms)
-                eff_wait = tuner.effective_wait(self.max_wait_ms)
-            else:
-                eff_wait = self.max_wait_ms
-            if background:
-                # background traffic accepts a longer window (it earns
-                # bigger merges); never BELOW the configured ceiling so a
-                # tuned-down interactive window doesn't shrink it
-                eff_wait = max(self.max_wait_ms, eff_wait) \
-                    * _BACKGROUND_WAIT_FACTOR
-            deadline = entry.enq_ms + max(eff_wait, 0)
-            for alt in alt_keys:
-                alt_bucket = self._buckets.get(alt)
-                if (alt_bucket is not None and alt_bucket.entries
-                        and len(alt_bucket.entries) < self.max_batch_size):
-                    # ride the bigger-k batch already forming; never CREATE
-                    # a bigger-k bucket just for a smaller-k request
-                    key = alt
-                    self.stats["cross_k_served"] += 1
-                    break
-            bucket = self._buckets.get(key)
-            if bucket is None:
-                bucket = self._buckets[key] = _Bucket()
-            bucket.entries.append(entry)
-            # the per-key controller's solo verdict wins when auto-tuning;
-            # the global EWMA stays the fallback signal
-            solo_now = (tuner.solo if tuner is not None
-                        else self._ewma <= _SOLO_EWMA_THRESHOLD)
-            if len(bucket.entries) >= self.max_batch_size:
-                batch, reason = self._take_locked(key), "size"
-            elif self.max_wait_ms <= 0 or (
-                self._in_flight.get(key, 0) == 0 and solo_now
-            ):
-                if len(bucket.entries) == 1:
-                    self.stats["solo_fast_path"] += 1
-                batch, reason = self._take_locked(key), "solo"
-            else:
-                batch, reason = None, ""
+        with tracing.detail(span_names.BATCH_WAIT) as waited:
+            with self._cond:
+                self.pressure.acquire()
+                entry = _Entry(payload, time.perf_counter_ns(),
+                               launch=launch, rank=rank, tune_key=tune_key)
+                # arrivals and the flush deadline stay on the timeutil
+                # clock, which a sim may have made virtual
+                now_ms = timeutil.monotonic_millis()
+                tuner = None
+                if self.auto_tune:
+                    tuner = self._tuner_locked(tune_key)
+                    tuner.note_arrival(now_ms)
+                    eff_wait = tuner.effective_wait(self.max_wait_ms)
+                else:
+                    eff_wait = self.max_wait_ms
+                if background:
+                    # background traffic accepts a longer window (it earns
+                    # bigger merges); never BELOW the configured ceiling so a
+                    # tuned-down interactive window doesn't shrink it
+                    eff_wait = max(self.max_wait_ms, eff_wait) \
+                        * _BACKGROUND_WAIT_FACTOR
+                deadline = now_ms + max(eff_wait, 0)
+                for alt in alt_keys:
+                    alt_bucket = self._buckets.get(alt)
+                    if (alt_bucket is not None and alt_bucket.entries
+                            and len(alt_bucket.entries) < self.max_batch_size):
+                        # ride the bigger-k batch already forming; never CREATE
+                        # a bigger-k bucket just for a smaller-k request
+                        key = alt
+                        self.stats["cross_k_served"] += 1
+                        break
+                bucket = self._buckets.get(key)
+                if bucket is None:
+                    bucket = self._buckets[key] = _Bucket()
+                bucket.entries.append(entry)
+                # the per-key controller's solo verdict wins when auto-tuning;
+                # the global EWMA stays the fallback signal
+                solo_now = (tuner.solo if tuner is not None
+                            else self._ewma <= _SOLO_EWMA_THRESHOLD)
+                if len(bucket.entries) >= self.max_batch_size:
+                    batch, reason = self._take_locked(key), "size"
+                elif self.max_wait_ms <= 0 or (
+                    self._in_flight.get(key, 0) == 0 and solo_now
+                ):
+                    if len(bucket.entries) == 1:
+                        self.stats["solo_fast_path"] += 1
+                    batch, reason = self._take_locked(key), "solo"
+                else:
+                    batch, reason = None, ""
+            if batch is None:
+                led = self._await_or_lead(key, entry, deadline)
+                if led is not None:
+                    batch, reason = led
+            _describe_wait(waited, entry, reason)
         while True:
-            if batch is not None:
-                out = self._run_batch(key, batch, own=entry,
-                                      shards=shards, kind=kind,
-                                      family=family, reason=reason)
-                if out is not None:
-                    return out
-                # we led a batch that did not include our own entry (the
-                # size bound shrank under us): keep waiting for ours
-                batch = None
-                continue
-            led = self._await_or_lead(key, entry, deadline)
-            if led is None:
+            if batch is None:
                 # another leader served us
                 if entry.error is not None:
                     raise entry.error
@@ -529,7 +542,17 @@ class KnnDispatchBatcher:
                     entry.result, entry.batch_size, entry.wall_ns,
                     entry.retraced, entry.wait_ms,
                 )
-            batch, reason = led
+            out = self._run_batch(key, batch, own=entry,
+                                  shards=shards, kind=kind,
+                                  family=family, reason=reason)
+            if out is not None:
+                return out
+            # we led a batch that did not include our own entry (the
+            # size bound shrank under us): keep waiting for ours
+            with tracing.detail(span_names.BATCH_WAIT) as waited:
+                led = self._await_or_lead(key, entry, deadline)
+                batch, reason = led if led is not None else (None, "")
+                _describe_wait(waited, entry, reason)
 
     # -- internals ---------------------------------------------------------
 
@@ -537,7 +560,10 @@ class KnnDispatchBatcher:
               kind: str = "exact",
               family: str | None = None) -> DispatchOutcome:
         t0 = time.perf_counter_ns()
-        results, retraced = launch([payload])
+        with tracing.detail(span_names.LAUNCH) as span:
+            span.set_attribute("merged", 1)
+            span.set_attribute("reason", "unbatched")
+            results, retraced = launch([payload])
         wall = time.perf_counter_ns() - t0
         self._record_launch(1, wall, (0,), shards, kind)
         self._after_launch(kind, family, retraced, wall, merged=1,
@@ -589,10 +615,10 @@ class KnnDispatchBatcher:
             bucket.entries = rest
         else:
             del self._buckets[key]
-        now = timeutil.monotonic_millis()
+        now_ns = time.perf_counter_ns()
         for e in batch:
             e.taken = True
-            e.wait_ms = max(0, now - e.enq_ms)
+            e.taken_ns = now_ns
         self.pressure.release(len(batch))
         self._in_flight[key] = self._in_flight.get(key, 0) + 1
         return batch
@@ -643,7 +669,14 @@ class KnnDispatchBatcher:
         launch = max(batch, key=lambda e: e.rank).launch
         t0 = time.perf_counter_ns()
         try:
-            results, retraced = launch([e.payload for e in batch])
+            # the launch belongs to the leader's trace; every follower
+            # names it as the cause of its wait
+            with tracing.detail(span_names.LAUNCH) as span:
+                span.set_attribute("merged", len(batch))
+                span.set_attribute("reason", reason or "lead")
+                for e in batch:
+                    e.leader_span = span.span_id
+                results, retraced = launch([e.payload for e in batch])
         except BaseException as err:
             with self._cond:
                 for e in batch:
@@ -697,7 +730,7 @@ class KnnDispatchBatcher:
         self._cond.notify_all()
 
     def _record_launch(self, merged: int, wall_ns: int,
-                       wait_ms_per_entry: Sequence[int], shards: int = 1,
+                       wait_ms_per_entry: Sequence[float], shards: int = 1,
                        kind: str = "exact") -> None:
         with self._cond:
             self.stats["dispatches"] += 1
@@ -732,6 +765,20 @@ class KnnDispatchBatcher:
                 metrics.counter("knn.dispatch.ann").add(1)
             else:
                 metrics.counter("knn.dispatch.exact").add(1)
+
+
+def _describe_wait(waited, entry: _Entry, reason: str) -> None:
+    """The `batch.wait` span's attributes: `reason` is the flush this
+    waiter leads, or "follower" when another leader's launch served it
+    (`leader` then names that launch's span)."""
+    if waited.detail is None:
+        return
+    attributes = {"queue_wait_ns": entry.taken_ns - entry.enq_ns,
+                  "reason": reason or "follower"}
+    if not reason:
+        attributes["merged"] = entry.batch_size
+        attributes["leader"] = entry.leader_span
+    waited.attributes.update(attributes)
 
 
 # process-wide default: the executor's dispatch sites are module-level code

@@ -55,6 +55,8 @@ from opensearch_tpu.cluster.shard_mesh import default_registry as registry
 from opensearch_tpu.parallel.distributed import build_knn_serving_step
 from opensearch_tpu.parallel.mesh import DATA_AXIS
 from opensearch_tpu.search.executor import ShardHit, ShardQueryResult
+from opensearch_tpu.telemetry import spans as span_names
+from opensearch_tpu.telemetry import tracing
 
 # observability: tests and the multichip dryrun assert the serving path
 # ran. Increment via _count(): searches run on a parallel pool, and a bare
@@ -326,193 +328,200 @@ def mesh_knn_batch(
     dispatch. Returns a MeshLaunchOutcome (per-query per-shard results,
     device-merged row order, launch attribution), or None when this path
     cannot reproduce the host result."""
-    if not shards or len(shards) != len(snaps) or not nodes:
-        return None
-    s = len(shards)
-    first = nodes[0]
-    # batch members must share the device program and the filter mask;
-    # filters are compared by identity (msearch groups by equal body JSON,
-    # the single-query path always has B == 1)
-    for node in nodes:
-        if (node.field != first.field or int(node.k) != int(first.k)
-                or node.filter is not first.filter):
+    with tracing.detail(span_names.LAUNCH_HOST_PRE):
+        if not shards or len(shards) != len(snaps) or not nodes:
             return None
-    has_filter = first.filter is not None or (
-        alias_filters is not None and any(f is not None for f in alias_filters)
-    )
-    served = _can_serve(snaps, first.field, filtered=has_filter)
-    if served is None:
-        _count("fallbacks")
-        return None
-    similarity, dims = served
-    if any(len(node.vector) != dims for node in nodes):
-        return None
+        s = len(shards)
+        first = nodes[0]
+        # batch members must share the device program and the filter mask;
+        # filters are compared by identity (msearch groups by equal body JSON,
+        # the single-query path always has B == 1)
+        for node in nodes:
+            if (node.field != first.field or int(node.k) != int(first.k)
+                    or node.filter is not first.filter):
+                return None
+        has_filter = first.filter is not None or (
+            alias_filters is not None and any(f is not None for f in alias_filters)
+        )
+        served = _can_serve(snaps, first.field, filtered=has_filter)
+        if served is None:
+            _count("fallbacks")
+            return None
+        similarity, dims = served
+        if any(len(node.vector) != dims for node in nodes):
+            return None
 
-    n_devices = _largest_divisor_at_most(s, len(jax.devices()))
-    mesh = _serving_mesh(n_devices)
+        n_devices = _largest_divisor_at_most(s, len(jax.devices()))
+        mesh = _serving_mesh(n_devices)
 
-    index_name = shards[0].shard_id.index
-    # generation-pinned residency key (ShardMeshRegistry.residency_key):
-    # a refresh mid-flight is a different key, so no query is ever merged
-    # against another snapshot's slab
-    cache_key = registry.residency_key(index_name, first.field, shards, snaps)
-    bundle = registry.get(cache_key)
-    if bundle is None:
-        # build OUTSIDE the registry lock: the device upload can take
-        # seconds for a large index and must not stall warm-path queries of
-        # other indexes. A same-key race (two cold misses) wastes one
-        # duplicate upload at worst — registry.put keeps the cache itself
-        # consistent, returns the winning bundle, and frees the loser's
-        # ledger allocation.
-        bundle = registry.put(
-            cache_key,
-            _build_bundle(snaps, first.field, dims, mesh,
-                          index_name=index_name,
-                          generations=cache_key[4]),
+        index_name = shards[0].shard_id.index
+        # generation-pinned residency key (ShardMeshRegistry.residency_key):
+        # a refresh mid-flight is a different key, so no query is ever merged
+        # against another snapshot's slab
+        cache_key = registry.residency_key(index_name, first.field, shards, snaps)
+        bundle = registry.get(cache_key)
+        if bundle is None:
+            # build OUTSIDE the registry lock: the device upload can take
+            # seconds for a large index and must not stall warm-path queries of
+            # other indexes. A same-key race (two cold misses) wastes one
+            # duplicate upload at worst — registry.put keeps the cache itself
+            # consistent, returns the winning bundle, and frees the loser's
+            # ledger allocation.
+            bundle = registry.put(
+                cache_key,
+                _build_bundle(snaps, first.field, dims, mesh,
+                              index_name=index_name,
+                              generations=cache_key[4]),
+            )
+
+        valid = bundle.valid
+        if has_filter:
+            fmask = _filter_valid_mask(
+                shards, snaps, first.filter, alias_filters, bundle.n_flat
+            )
+            # per-request upload, consumed by this launch: transient in the
+            # residency ledger (allocated and freed in one step)
+            from opensearch_tpu.telemetry.device_ledger import (
+                KIND_QUERY_BATCH,
+                default_ledger,
+            )
+
+            default_ledger.record_transient(KIND_QUERY_BATCH, fmask.nbytes)
+            valid = valid & jax.device_put(
+                jnp.asarray(fmask), NamedSharding(mesh, P(DATA_AXIS))
+            )
+
+        b = len(nodes)
+        # pad B to a power of two: B is a static shape under jit, so raw batch
+        # sizes would compile one program per msearch width (query-shape cache,
+        # SURVEY.md §7 hard part #3); padding queries are zero vectors whose
+        # results are sliced off
+        b_pad = 1 << (b - 1).bit_length()
+        q_host = np.zeros((b_pad, dims), np.float32)
+        for i, node in enumerate(nodes):
+            q_host[i] = np.asarray(node.vector, np.float32)
+
+        k_shard = max(1, min(int(first.k), bundle.n_flat))
+        k_final = min(max(k_shard, int(fetch_k)), s * k_shard)
+        # EXACT-path kernel policy (search.knn.kernel / score_precision): the
+        # RESOLVED kernel + precision are part of the program key, so a live
+        # flip compiles a fresh mesh program and never re-ranks a batch formed
+        # under the old policy. The platform read happens ONCE per program
+        # build (pallas interprets only when the backend is the CPU — the
+        # tests' parity path; any accelerator compiles the kernel).
+        from opensearch_tpu.search.ann import (
+            default_config as ann_config,
+            resolve_kernel,
         )
 
-    valid = bundle.valid
-    if has_filter:
-        fmask = _filter_valid_mask(
-            shards, snaps, first.filter, alias_filters, bundle.n_flat
-        )
-        # per-request upload, consumed by this launch: transient in the
-        # residency ledger (allocated and freed in one step)
+        exact_kernel = resolve_kernel(ann_config.exact_kernel)
+        score_precision = ann_config.score_precision
+        fused = (exact_kernel, score_precision) != ("xla", "fp32")
+        prog_key = (n_devices, s, bundle.n_flat, dims, k_shard, k_final,
+                    similarity, b_pad, exact_kernel, score_precision)
+        with _CACHE_LOCK:
+            program = _PROGRAM_CACHE.get(prog_key)
+            retraced = program is None
+            if program is None:
+                interpret = (exact_kernel == "pallas"
+                             and jax.devices()[0].platform == "cpu")
+                program = build_knn_serving_step(
+                    mesh, k_shard=k_shard, k_final=k_final,
+                    similarity=similarity, kernel=exact_kernel,
+                    score_precision=score_precision, interpret=interpret,
+                )
+                _PROGRAM_CACHE[prog_key] = program
+
+        queries = jnp.asarray(q_host)
+    t0 = time.perf_counter_ns()
+    with tracing.detail(span_names.LAUNCH_DEVICE) as span:
+        span.set_attribute("retraced", retraced)
+        with mesh:
+            vals, gids, counts = program(
+                bundle.vectors, bundle.norms_sq, valid, queries
+            )
+        # host materialization is the fence for this launch: the host
+        # needs these rows anyway, so the first copy doubles as the wait
+        vals = np.asarray(vals)[:b]          # [b, k_final]
+    # one span a copy: each is one `np.asarray`, one after the other
+    with tracing.detail(span_names.LAUNCH_FETCH):
+        gids = np.asarray(gids)[:b]
+    with tracing.detail(span_names.LAUNCH_FETCH):
+        counts = np.asarray(counts)[:, :b]   # [s, b]
+    wall_ns = time.perf_counter_ns() - t0
+    with tracing.detail(span_names.LAUNCH_HOST_POST):
+        launch_id = registry.next_launch_id()
+        registry.record_launch_wall(wall_ns)
+        registry.record_launch_kernel(exact_kernel, score_precision)
+        # roofline accounting: ONE sharded launch against the mesh cost model
+        # (per-slot scan + on-device all_gather/top_k merge)
+        from opensearch_tpu.telemetry import roofline
+
+        launch_params = dict(b=b_pad, s=s, n_flat=bundle.n_flat, d=dims,
+                             k_shard=k_shard, devices=n_devices)
+        if fused:
+            from opensearch_tpu.ops.pallas_knn import fused_pool_width
+
+            launch_params.update(
+                precision=score_precision,
+                r=fused_pool_width(k_shard, score_precision),
+                kernel=exact_kernel,
+            )
+            mesh_family = "mesh_knn_fused"
+            roofline.record_launch(
+                f"mesh_knn_fused[{score_precision}]", wall_ns, **launch_params)
+        else:
+            mesh_family = "mesh_knn"
+            roofline.record_launch("mesh_knn", wall_ns, **launch_params)
         from opensearch_tpu.telemetry.device_ledger import (
             KIND_QUERY_BATCH,
             default_ledger,
         )
 
-        default_ledger.record_transient(KIND_QUERY_BATCH, fmask.nbytes)
-        valid = valid & jax.device_put(
-            jnp.asarray(fmask), NamedSharding(mesh, P(DATA_AXIS))
-        )
+        default_ledger.record_transient(KIND_QUERY_BATCH, q_host.nbytes)
+        # heat touch against the mesh bundle this launch scanned, bytes from
+        # the same cost model the roofline fold used (telemetry/device_ledger)
+        default_ledger.touch([getattr(bundle, "allocation", None)],
+                             family=mesh_family, params=launch_params)
+        if retraced:
+            # program-cache miss == fresh jit entry for the mesh kernel family;
+            # the first launch wall includes the compile
+            default_ledger.record_compile(mesh_family, wall_ns)
+        _count("distributed_searches")
+        if has_filter:
+            _count("filtered")
+        if s == 1:
+            _count("single_shard")
+        if b > 1:
+            _count("batched_queries", b)
 
-    b = len(nodes)
-    # pad B to a power of two: B is a static shape under jit, so raw batch
-    # sizes would compile one program per msearch width (query-shape cache,
-    # SURVEY.md §7 hard part #3); padding queries are zero vectors whose
-    # results are sliced off
-    b_pad = 1 << (b - 1).bit_length()
-    q_host = np.zeros((b_pad, dims), np.float32)
-    for i, node in enumerate(nodes):
-        q_host[i] = np.asarray(node.vector, np.float32)
-
-    k_shard = max(1, min(int(first.k), bundle.n_flat))
-    k_final = min(max(k_shard, int(fetch_k)), s * k_shard)
-    # EXACT-path kernel policy (search.knn.kernel / score_precision): the
-    # RESOLVED kernel + precision are part of the program key, so a live
-    # flip compiles a fresh mesh program and never re-ranks a batch formed
-    # under the old policy. The platform read happens ONCE per program
-    # build (pallas interprets only when the backend is the CPU — the
-    # tests' parity path; any accelerator compiles the kernel).
-    from opensearch_tpu.search.ann import (
-        default_config as ann_config,
-        resolve_kernel,
-    )
-
-    exact_kernel = resolve_kernel(ann_config.exact_kernel)
-    score_precision = ann_config.score_precision
-    fused = (exact_kernel, score_precision) != ("xla", "fp32")
-    prog_key = (n_devices, s, bundle.n_flat, dims, k_shard, k_final,
-                similarity, b_pad, exact_kernel, score_precision)
-    with _CACHE_LOCK:
-        program = _PROGRAM_CACHE.get(prog_key)
-        retraced = program is None
-        if program is None:
-            interpret = (exact_kernel == "pallas"
-                         and jax.devices()[0].platform == "cpu")
-            program = build_knn_serving_step(
-                mesh, k_shard=k_shard, k_final=k_final,
-                similarity=similarity, kernel=exact_kernel,
-                score_precision=score_precision, interpret=interpret,
-            )
-            _PROGRAM_CACHE[prog_key] = program
-
-    queries = jnp.asarray(q_host)
-    t0 = time.perf_counter_ns()
-    with mesh:
-        vals, gids, counts = program(
-            bundle.vectors, bundle.norms_sq, valid, queries
-        )
-    # host materialization is the fence for this launch: the host needs
-    # these rows anyway, so the copy doubles as the wait
-    vals = np.asarray(vals)[:b]          # [b, k_final]
-    gids = np.asarray(gids)[:b]
-    counts = np.asarray(counts)[:, :b]   # [s, b]
-    wall_ns = time.perf_counter_ns() - t0
-    launch_id = registry.next_launch_id()
-    registry.record_launch_wall(wall_ns)
-    registry.record_launch_kernel(exact_kernel, score_precision)
-    # roofline accounting: ONE sharded launch against the mesh cost model
-    # (per-slot scan + on-device all_gather/top_k merge)
-    from opensearch_tpu.telemetry import roofline
-
-    launch_params = dict(b=b_pad, s=s, n_flat=bundle.n_flat, d=dims,
-                         k_shard=k_shard, devices=n_devices)
-    if fused:
-        from opensearch_tpu.ops.pallas_knn import fused_pool_width
-
-        launch_params.update(
-            precision=score_precision,
-            r=fused_pool_width(k_shard, score_precision),
-            kernel=exact_kernel,
-        )
-        mesh_family = "mesh_knn_fused"
-        roofline.record_launch(
-            f"mesh_knn_fused[{score_precision}]", wall_ns, **launch_params)
-    else:
-        mesh_family = "mesh_knn"
-        roofline.record_launch("mesh_knn", wall_ns, **launch_params)
-    from opensearch_tpu.telemetry.device_ledger import (
-        KIND_QUERY_BATCH,
-        default_ledger,
-    )
-
-    default_ledger.record_transient(KIND_QUERY_BATCH, q_host.nbytes)
-    # heat touch against the mesh bundle this launch scanned, bytes from
-    # the same cost model the roofline fold used (telemetry/device_ledger)
-    default_ledger.touch([getattr(bundle, "allocation", None)],
-                         family=mesh_family, params=launch_params)
-    if retraced:
-        # program-cache miss == fresh jit entry for the mesh kernel family;
-        # the first launch wall includes the compile
-        default_ledger.record_compile(mesh_family, wall_ns)
-    _count("distributed_searches")
-    if has_filter:
-        _count("filtered")
-    if s == 1:
-        _count("single_shard")
-    if b > 1:
-        _count("batched_queries", b)
-
-    out: list[list[ShardQueryResult]] = []
-    premerged: list[list[tuple[int, ShardHit]]] = []
-    for qi, node in enumerate(nodes):
-        boost = np.float32(getattr(node, "boost", 1.0))
-        per_shard_hits: list[list[ShardHit]] = [[] for _ in range(s)]
-        # device row order IS the final merged order: (-score, shard asc,
-        # segment asc, doc asc) — see build_knn_serving_step's tie-break
-        rows: list[tuple[int, ShardHit]] = []
-        for v, g in zip(vals[qi], gids[qi]):
-            if not np.isfinite(v):
-                continue
-            shard_idx, flat = int(g) // bundle.n_flat, int(g) % bundle.n_flat
-            seg_idx, doc = bundle.locate(shard_idx, flat)
-            hit = ShardHit(float(np.float32(v) * boost), seg_idx, doc)
-            per_shard_hits[shard_idx].append(hit)
-            rows.append((shard_idx, hit))
-        results = []
-        for shard_idx in range(s):
-            hits = per_shard_hits[shard_idx]
-            results.append(ShardQueryResult(
-                hits=hits,
-                total=int(counts[shard_idx, qi]),
-                max_score=max((h.score for h in hits), default=None),
-            ))
-        out.append(results)
-        premerged.append(rows)
-    return MeshLaunchOutcome(out, premerged, launch_id, wall_ns, retraced, s)
+        out: list[list[ShardQueryResult]] = []
+        premerged: list[list[tuple[int, ShardHit]]] = []
+        for qi, node in enumerate(nodes):
+            boost = np.float32(getattr(node, "boost", 1.0))
+            per_shard_hits: list[list[ShardHit]] = [[] for _ in range(s)]
+            # device row order IS the final merged order: (-score, shard asc,
+            # segment asc, doc asc) — see build_knn_serving_step's tie-break
+            rows: list[tuple[int, ShardHit]] = []
+            for v, g in zip(vals[qi], gids[qi]):
+                if not np.isfinite(v):
+                    continue
+                shard_idx, flat = int(g) // bundle.n_flat, int(g) % bundle.n_flat
+                seg_idx, doc = bundle.locate(shard_idx, flat)
+                hit = ShardHit(float(np.float32(v) * boost), seg_idx, doc)
+                per_shard_hits[shard_idx].append(hit)
+                rows.append((shard_idx, hit))
+            results = []
+            for shard_idx in range(s):
+                hits = per_shard_hits[shard_idx]
+                results.append(ShardQueryResult(
+                    hits=hits,
+                    total=int(counts[shard_idx, qi]),
+                    max_score=max((h.score for h in hits), default=None),
+                ))
+            out.append(results)
+            premerged.append(rows)
+        return MeshLaunchOutcome(out, premerged, launch_id, wall_ns, retraced, s)
 
 
 def clear_caches() -> None:

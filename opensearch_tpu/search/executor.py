@@ -53,6 +53,8 @@ from opensearch_tpu.ops import bm25, filters, knn
 from opensearch_tpu.search import profile
 from opensearch_tpu.search import query_dsl as q
 from opensearch_tpu.telemetry import roofline
+from opensearch_tpu.telemetry import spans as span_names
+from opensearch_tpu.telemetry import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -260,52 +262,61 @@ class ShardContext:
                 touch_allocs = _touch_targets(dev, node.field, ann=vf.ann)
 
                 def launch_ann(rows):
-                    q_batch = _pad_query_batch(rows)
-                    t0 = time.perf_counter_ns()
                     with profile.profiling(None):
-                        b_vals, b_ids = ivfpq.search_index(
-                            vf.ann, vf.vectors, vf.norms_sq, valid,
-                            q_batch, k=k_bucket, nprobe=nprobe,
-                            similarity=vf.similarity,
-                            adc_precision=precision,
-                            rescore_multiplier=mult,
-                            kernel=kernel,
+                        with tracing.detail(span_names.LAUNCH_HOST_PRE):
+                            q_batch = _pad_query_batch(rows)
+                            t0 = time.perf_counter_ns()
+                            queries, probes = ivfpq.select_probes(
+                                vf.ann, q_batch, nprobe, kernel)
+                        with tracing.detail(span_names.LAUNCH_DEVICE):
+                            b_vals, b_ids = ivfpq.search_probed(
+                                vf.ann, vf.vectors, vf.norms_sq, valid,
+                                queries, probes, k=k_bucket, nprobe=nprobe,
+                                similarity=vf.similarity,
+                                adc_precision=precision,
+                                rescore_multiplier=mult,
+                            )
+                            # host materialization is the fence for this
+                            # launch: the first copy doubles as the wait
+                            b_vals = np.asarray(b_vals)
+                        with tracing.detail(span_names.LAUNCH_FETCH):
+                            b_ids = np.asarray(b_ids)
+                    wall_ns = time.perf_counter_ns() - t0
+                    with tracing.detail(span_names.LAUNCH_HOST_POST):
+                        # roofline accounting: one fenced launch against
+                        # the variant's cost model, keyed per ADC precision
+                        # so the report can compare the lowerings
+                        # (ANNS-AMP)
+                        launch_params = dict(
+                            b=int(q_batch.shape[0]),
+                            nlist=vf.ann.params.nlist, d=vf.ann.params.d,
+                            m=vf.ann.params.m, ks=vf.ann.params.ks,
+                            nprobe=nprobe, l_pad=vf.ann.l_pad,
+                            rescore=rescore, adc_precision=precision,
                         )
-                    # host materialization is the fence for this launch
-                    b_vals = np.asarray(b_vals)
-                    b_ids = np.asarray(b_ids)
-                    # roofline accounting: one fenced launch against the
-                    # variant's cost model, keyed per ADC precision so the
-                    # report can compare the lowerings (ANNS-AMP)
-                    launch_params = dict(
-                        b=int(q_batch.shape[0]),
-                        nlist=vf.ann.params.nlist, d=vf.ann.params.d,
-                        m=vf.ann.params.m, ks=vf.ann.params.ks,
-                        nprobe=nprobe, l_pad=vf.ann.l_pad,
-                        rescore=rescore, adc_precision=precision,
-                    )
-                    roofline.record_launch(
-                        f"{family}[{precision}]",
-                        time.perf_counter_ns() - t0,
-                        **launch_params,
-                    )
-                    # heat touch against the structures this launch READ
-                    # (IVF-PQ slab + rescore column + live bitmap), bytes
-                    # from the same cost model the roofline fold used
-                    from opensearch_tpu.telemetry.device_ledger import (
-                        default_ledger,
-                    )
+                        roofline.record_launch(
+                            f"{family}[{precision}]", wall_ns,
+                            **launch_params,
+                        )
+                        # heat touch against the structures this launch
+                        # READ (IVF-PQ slab + rescore column + live
+                        # bitmap), bytes from the same cost model the
+                        # roofline fold used
+                        from opensearch_tpu.telemetry.device_ledger import (
+                            default_ledger,
+                        )
 
-                    default_ledger.touch(
-                        touch_allocs, family=f"{family}[{precision}]",
-                        params=launch_params)
-                    retraced = profile.signature_retraced(
-                        "ivfpq_search", (vf.vectors, q_batch),
-                        (k_bucket, nprobe, precision, mult, kernel))
-                    return (
-                        [(b_vals[i], b_ids[i]) for i in range(len(rows))],
-                        retraced,
-                    )
+                        default_ledger.touch(
+                            touch_allocs, family=f"{family}[{precision}]",
+                            params=launch_params)
+                        retraced = profile.signature_retraced(
+                            "ivfpq_search", (vf.vectors, q_batch),
+                            (k_bucket, nprobe, precision, mult, kernel))
+                        return (
+                            [(b_vals[i], b_ids[i])
+                             for i in range(len(rows))],
+                            retraced,
+                        )
 
                 # cross-k coalescing: this request may ride an already-
                 # forming batch of the next-larger k buckets (its rows
@@ -337,17 +348,21 @@ class ShardContext:
                     )
                 _record_ann_metrics(nprobe)
                 _count_knn_path("ann")
-                scores = np.full(dev.n_pad, -np.inf, np.float32)
-                hit = a_ids >= 0
-                scores[a_ids[hit]] = a_vals[hit]
-                # the launch already returned the top candidates sorted —
-                # skip the generic argpartition below and feed them to the
-                # shard cut directly (host work on the serving path is
-                # GIL-serial; every avoided O(n) pass widens the batch win)
-                per_seg_scores.append(scores)
-                for v, d in zip(a_vals[hit][: node.k], a_ids[hit][: node.k]):
-                    if np.isfinite(v):
-                        candidates.append((float(v), seg_idx, int(d)))
+                # per request, after the (shared) launch: the dense scatter
+                with tracing.detail(span_names.SEARCH_COLLECT):
+                    scores = np.full(dev.n_pad, -np.inf, np.float32)
+                    hit = a_ids >= 0
+                    scores[a_ids[hit]] = a_vals[hit]
+                    # the launch already returned the top candidates
+                    # sorted — skip the generic argpartition below and feed
+                    # them to the shard cut directly (host work on the
+                    # serving path is GIL-serial; every avoided O(n) pass
+                    # widens the batch win)
+                    per_seg_scores.append(scores)
+                    for v, d in zip(a_vals[hit][: node.k],
+                                    a_ids[hit][: node.k]):
+                        if np.isfinite(v):
+                            candidates.append((float(v), seg_idx, int(d)))
                 continue
             else:
                 n_pad = dev.n_pad
@@ -2536,20 +2551,21 @@ def execute_query_phase(
             # launch + two transfers + a fence, all GIL-serial)
             prof = profile.active()
             t_collect = time.perf_counter_ns()
-            mask_h = result.host_mask
-            scores_h = result.host_scores
-            if min_score is not None:
-                mask_h = mask_h & (scores_h >= np.float32(min_score))
-            if need_masks:
-                masks.append(mask_h[: host.n_docs])
-                score_arrays.append(scores_h[: host.n_docs])
-            total += int(mask_h.sum())
-            if size > 0:
-                for d in np.nonzero(mask_h)[0]:
-                    v = float(scores_h[d])
-                    all_hits.append(ShardHit(v, seg_idx, int(d)))
-                    if max_score is None or v > max_score:
-                        max_score = v
+            with tracing.detail(span_names.SEARCH_COLLECT):
+                mask_h = result.host_mask
+                scores_h = result.host_scores
+                if min_score is not None:
+                    mask_h = mask_h & (scores_h >= np.float32(min_score))
+                if need_masks:
+                    masks.append(mask_h[: host.n_docs])
+                    score_arrays.append(scores_h[: host.n_docs])
+                total += int(mask_h.sum())
+                if size > 0:
+                    for d in np.nonzero(mask_h)[0]:
+                        v = float(scores_h[d])
+                        all_hits.append(ShardHit(v, seg_idx, int(d)))
+                        if max_score is None or v > max_score:
+                            max_score = v
             if prof is not None:
                 prof.collect_ns += time.perf_counter_ns() - t_collect
             continue

@@ -31,6 +31,8 @@ from opensearch_tpu.search import fetch, profile as search_profile, query_dsl
 
 logger = logging.getLogger(__name__)
 from opensearch_tpu.search.aggs import compute_aggs
+from opensearch_tpu.telemetry import spans as span_names
+from opensearch_tpu.telemetry import tracing
 from opensearch_tpu.search.executor import (
     SegmentExecutor,
     ShardContext,
@@ -86,6 +88,32 @@ def search(
     `shard_numbers`), aggregations carry `_p_*` reduce extras, and pipeline
     aggregations are deferred to the coordinator's final reduce
     (search/reduce.py — InternalAggregations.reduce:162 semantics)."""
+    # the phases below follow one another as sibling detail spans of the
+    # caller's `search` span (no-ops outside a profiler session)
+    phase = tracing.phases()
+    try:
+        return _search(
+            phase, shards, body, acquired, phase_results_config,
+            shard_filters, task, partial, shard_numbers, index_boosts,
+            precomputed_results)
+    finally:
+        phase.close()
+
+
+def _search(
+    phase,
+    shards: list[IndexShard],
+    body: dict | None,
+    acquired: list | None,
+    phase_results_config: dict | None,
+    shard_filters: list | None,
+    task,
+    partial: bool,
+    shard_numbers: list[int] | None,
+    index_boosts: dict | None,
+    precomputed_results: list | None,
+) -> dict[str, Any]:
+    phase.enter(span_names.SEARCH_PARSE)
     t0 = time.monotonic()
     body = body or {}
     known_keys = {
@@ -168,6 +196,7 @@ def search(
     mesh_premerged: list | None = None
     mesh_launch: dict | None = None
 
+    phase.enter(span_names.SEARCH_QUERY_PHASE)
     fetch_k = from_ + size
     if body.get("rescore") is not None:
         # the query phase must collect the full rescore window
@@ -328,6 +357,7 @@ def search(
                 per_shard_results.append((shard, snapshot, result))
 
     # ---- reduce phase (SearchPhaseController analog) ----
+    phase.enter(span_names.SEARCH_REDUCE)
     if index_boosts is None and isinstance(body.get("indices_boost"), dict):
         index_boosts = body["indices_boost"]
     if index_boosts:
@@ -418,6 +448,7 @@ def search(
     page = merged[from_ : from_ + size]
 
     # ---- fetch phase (only winning docs; sub-phase chain in fetch.py) ----
+    phase.enter(span_names.SEARCH_FETCH)
     fields_specs = body.get("fields")
     stored_specs = body.get("stored_fields")
     if isinstance(stored_specs, str):
@@ -660,6 +691,7 @@ def search(
             hit["_tb"] = [gshard, h.segment, h.doc]
         hits_json.append(hit)
 
+    phase.enter(span_names.SEARCH_RESPOND)
     sort_by_score = bool(sort) and _sort_has_score(sort)
     if sort_by_score and max_score is None and merged:
         max_score = max(h.score for _i, h in merged)
@@ -1124,9 +1156,12 @@ def _try_distributed_query_phase(
         )
 
     if key is None:
-        out = distributed_serving.mesh_knn_batch(
-            shards, snaps, [node], fetch_k, alias_filters=filter_nodes
-        )
+        # a filtered query's mask is request-private: no batcher, so the
+        # `launch` span the batcher would open is opened here
+        with tracing.detail(span_names.LAUNCH):
+            out = distributed_serving.mesh_knn_batch(
+                shards, snaps, [node], fetch_k, alias_filters=filter_nodes
+            )
         if out is None:
             return None
         results, premerged = out.per_query[0], out.premerged[0]

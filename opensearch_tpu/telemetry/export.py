@@ -44,7 +44,7 @@ from typing import Any, Callable
 
 from opensearch_tpu.common import randutil
 from opensearch_tpu.common.settings import Property, Setting
-from opensearch_tpu.telemetry.tracing import Span
+from opensearch_tpu.telemetry.tracing import Span, clock_pair
 
 logger = logging.getLogger(__name__)
 
@@ -110,13 +110,20 @@ def _from_otlp_value(v: dict) -> Any:
     return v.get("stringValue")
 
 
-def span_to_otlp(span: Span) -> dict:
+# resource attribute carrying what was added to every time of the request
+# on the way out, so that `parse_otlp` takes it back
+CLOCK_OFFSET_KEY = "opensearch_tpu.clock_offset_ns"
+
+
+def span_to_otlp(span: Span, unix_offset_ns: int = 0) -> dict:
+    """`unix_offset_ns` is time_ns − perf_counter_ns of one clock pair
+    (tracing.clock_pair): a span's stamps are monotonic, OTLP's are Unix."""
     out = {
         "traceId": span.trace_id,
         "spanId": span.span_id,
         "name": span.name,
-        "startTimeUnixNano": str(span.start_ns),
-        "endTimeUnixNano": str(span.end_ns),
+        "startTimeUnixNano": str(span.start_ns + unix_offset_ns),
+        "endTimeUnixNano": str(span.end_ns + unix_offset_ns),
         "attributes": [
             {"key": k, "value": _otlp_value(v)}
             for k, v in span.attributes.items()
@@ -133,7 +140,8 @@ def span_to_otlp(span: Span) -> dict:
     # overflow count survives as droppedEventsCount
     if span.events:
         out["events"] = [
-            {"timeUnixNano": str(e["ts_ns"]), "name": e["name"],
+            {"timeUnixNano": str(e["ts_ns"] + unix_offset_ns),
+             "name": e["name"],
              "attributes": [
                  {"key": k, "value": _otlp_value(v)}
                  for k, v in e["attributes"].items()
@@ -145,17 +153,20 @@ def span_to_otlp(span: Span) -> dict:
     return out
 
 
-def spans_to_otlp(spans: list[Span], service_name: str) -> dict:
+def spans_to_otlp(spans: list[Span], service_name: str,
+                  unix_offset_ns: int = 0) -> dict:
     """One OTLP/HTTP-JSON ExportTraceServiceRequest for a batch of spans."""
+    resource = [{"key": "service.name",
+                 "value": {"stringValue": service_name}}]
+    if unix_offset_ns:
+        resource.append({"key": CLOCK_OFFSET_KEY,
+                         "value": _otlp_value(unix_offset_ns)})
     return {
         "resourceSpans": [{
-            "resource": {"attributes": [
-                {"key": "service.name",
-                 "value": {"stringValue": service_name}},
-            ]},
+            "resource": {"attributes": resource},
             "scopeSpans": [{
                 "scope": {"name": "opensearch_tpu"},
-                "spans": [span_to_otlp(s) for s in spans],
+                "spans": [span_to_otlp(s, unix_offset_ns) for s in spans],
             }],
         }],
     }
@@ -166,6 +177,10 @@ def parse_otlp(doc: dict) -> list[Span]:
     proof: ids, parents, names, attributes and times all survive)."""
     out: list[Span] = []
     for rs in doc.get("resourceSpans", []):
+        offset = next(
+            (_from_otlp_value(a["value"])
+             for a in rs.get("resource", {}).get("attributes", [])
+             if a["key"] == CLOCK_OFFSET_KEY), 0)
         for ss in rs.get("scopeSpans", []):
             for s in ss.get("spans", []):
                 out.append(Span(
@@ -177,11 +192,11 @@ def parse_otlp(doc: dict) -> list[Span]:
                         a["key"]: _from_otlp_value(a["value"])
                         for a in s.get("attributes", [])
                     },
-                    start_ns=int(s["startTimeUnixNano"]),
-                    end_ns=int(s["endTimeUnixNano"]),
+                    start_ns=int(s["startTimeUnixNano"]) - offset,
+                    end_ns=int(s["endTimeUnixNano"]) - offset,
                     events=[
                         {"name": e["name"],
-                         "ts_ns": int(e["timeUnixNano"]),
+                         "ts_ns": int(e["timeUnixNano"]) - offset,
                          "attributes": {
                              a["key"]: _from_otlp_value(a["value"])
                              for a in e.get("attributes", [])
@@ -458,7 +473,9 @@ class SpanExporter:
                 self._queue.clear()
                 self._exporting += len(batch)
             try:
-                self.sink.write(spans_to_otlp(batch, self.service_name))
+                monotonic_ns, unix_ns = clock_pair()
+                self.sink.write(spans_to_otlp(
+                    batch, self.service_name, unix_ns - monotonic_ns))
             except Exception as e:  # noqa: BLE001 - sink failure == drop
                 with self._lock:
                     self.counters["export_errors"] += 1

@@ -1,0 +1,54 @@
+"""Span names of the served `_search` path: one stable literal each, so a
+reader (`perf/hostspans.py`, `perf/hostplanes.py`, a dashboard) can match by
+name after a refactor. Vary attributes, never names (the TPU013 rule, applied
+to spans).
+
+`http_request` and `search` exist always and feed the ring, the slowlog's
+trace ids and the exporter. Every other name here is a DETAIL span
+(`tracing.detail` / `tracing.phases`): it exists only in requests whose root
+opened while a `jax.profiler` session was running, and is written to the
+profiler's trace and the tracer's capture, never to the ring.
+"""
+
+from __future__ import annotations
+
+# HTTP front end (rest/http.py)
+HTTP_REQUEST = "http_request"
+HTTP_PARSE = "http.parse"
+HTTP_POOL_WAIT = "http.pool_wait"
+HTTP_RESPOND = "http.respond"
+
+# search service (node.py, search/service.py, search/executor.py)
+SEARCH = "search"
+SEARCH_PARSE = "search.parse"
+SEARCH_QUERY_PHASE = "search.query_phase"
+SEARCH_COLLECT = "search.collect"
+SEARCH_REDUCE = "search.reduce"
+SEARCH_FETCH = "search.fetch"
+SEARCH_RESPOND = "search.respond"
+
+# dispatch batcher (search/batcher.py)
+BATCH_WAIT = "batch.wait"
+
+# mesh program / per-shard ANN (search/distributed_serving.py,
+# search/executor.py): `launch.device` runs from the program call to the
+# first output's host copy returning, which is the fence; each further
+# output's host copy is a `launch.fetch`
+LAUNCH = "launch"
+LAUNCH_HOST_PRE = "launch.host_pre"
+LAUNCH_DEVICE = "launch.device"
+LAUNCH_FETCH = "launch.fetch"
+LAUNCH_HOST_POST = "launch.host_post"
+
+# process
+RUNTIME_GC = "runtime.gc"
+
+# every name above: what `perf/hostplanes.py` keeps of the host planes
+ALL = (
+    HTTP_REQUEST, HTTP_PARSE, HTTP_POOL_WAIT, HTTP_RESPOND,
+    SEARCH, SEARCH_PARSE, SEARCH_QUERY_PHASE, SEARCH_COLLECT, SEARCH_REDUCE,
+    SEARCH_FETCH, SEARCH_RESPOND,
+    BATCH_WAIT,
+    LAUNCH, LAUNCH_HOST_PRE, LAUNCH_DEVICE, LAUNCH_FETCH, LAUNCH_HOST_POST,
+    RUNTIME_GC,
+)

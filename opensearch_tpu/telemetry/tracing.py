@@ -20,17 +20,35 @@ propagation rides ThreadContext). Here:
   TaskTransportChannel). Span ids come from a per-tracer counter prefixed
   with the tracer name — deterministic under the sim (no uuid/urandom,
   tpulint TPU006) yet unique across the nodes of one simulated cluster.
+- Request DETAIL (PR 26): a root span that opens while a `jax.profiler`
+  session runs marks its whole request as detailed (`Span.detail`, inherited
+  by every child through the same contextvar). A detailed request also
+  opens the detail spans of telemetry/spans.py (`detail()` / `phases()`),
+  and every one of its spans is written twice: as a
+  `jax.profiler.TraceAnnotation`, which puts it on the clock of the device's
+  `XLA Ops` lines, and as a compact record in the tracer's `Capture`, which
+  is written to `<path.data>/telemetry/capture-<n>.json` when the session
+  has ended. No setting turns this on: the profiler session is the switch.
 """
 
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
+import json
+import logging
+import os
+import re
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field as dc_field
 from typing import Any
+
+from opensearch_tpu.telemetry import spans as span_names
+
+logger = logging.getLogger(__name__)
 
 _current_span: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "opensearch_tpu_current_span", default=None
@@ -60,6 +78,9 @@ class Span:
     # Bounded by MAX_SPAN_EVENTS; overflow counts into dropped_events.
     events: list[dict] = dc_field(default_factory=list)
     dropped_events: int = 0
+    # the Capture of the profiler session this span's request opened under
+    # (decided once, at the root; children inherit it), else None
+    detail: "Capture | None" = None
 
     @property
     def duration_ns(self) -> int:
@@ -85,6 +106,7 @@ class Span:
             "parent_id": self.parent_id,
             "name": self.name,
             "attributes": dict(self.attributes),
+            "start_ns": self.start_ns,
             "duration_ns": self.duration_ns,
         }
         if self.events:
@@ -95,7 +117,8 @@ class Span:
 
 
 class _SpanScope:
-    __slots__ = ("_tracer", "_name", "_attributes", "span", "_token")
+    __slots__ = ("_tracer", "_name", "_attributes", "span", "_token",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attributes: dict | None):
         self._tracer = tracer
@@ -104,6 +127,8 @@ class _SpanScope:
 
     def __enter__(self) -> Span:
         self.span = self._tracer.begin_span(self._name, self._attributes)
+        self._annotation = (_annotate(self.span)
+                            if self.span.detail is not None else None)
         self._token = _current_span.set(self.span)
         return self.span
 
@@ -111,8 +136,209 @@ class _SpanScope:
         if exc_type is not None:
             self.span.attributes["error"] = str(exc)
         _current_span.reset(self._token)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         self._tracer.end_span(self.span)
         return False
+
+
+# -- request detail: on exactly while a jax.profiler session runs -----------
+
+# records a capture holds before it counts `dropped` instead (never blocks)
+CAPTURE_MAX_RECORDS = 1 << 18
+CAPTURE_KEEP_FILES = 4
+# a capture is written this long after its session ended, so that the
+# requests in flight at that moment finish into it
+CAPTURE_GRACE_S = 0.5
+_CAPTURE_WRITE_CHUNK = 512
+_CAPTURE_FILE = re.compile(r"capture-(\d+)\.json")
+CAPTURE_FIELDS = ("name", "trace_id", "span_id", "parent_id", "thread",
+                  "start_ns", "end_ns", "attributes")
+
+_trace_annotation: Any = None  # jax.profiler.TraceAnnotation, False if absent
+
+
+def _trace_annotation_type():
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _trace_annotation = TraceAnnotation
+        except ImportError:
+            _trace_annotation = False
+    return _trace_annotation
+
+
+def profiler_session_on() -> bool:
+    """True between `jax.profiler.start_trace` and `stop_trace` (~25 ns)."""
+    annotation = _trace_annotation_type()
+    return bool(annotation) and annotation.is_enabled()
+
+
+def clock_pair() -> tuple[int, int]:
+    """(perf_counter_ns, time_ns) read together: the anchor that puts the
+    tracer's monotonic stamps on the Unix clock (export.py, the capture)."""
+    return time.perf_counter_ns(), time.time_ns()
+
+
+def _annotate(span: Span):
+    """Open the span's twin in the profiler's own trace, on this thread."""
+    annotation = _trace_annotation_type()(
+        span.name, trace_id=span.trace_id, span_id=span.span_id,
+        parent_id=span.parent_id or "")
+    annotation.__enter__()
+    return annotation
+
+
+class Capture:
+    """Every span of the requests that opened under one profiler session,
+    as compact records (`CAPTURE_FIELDS`), with the clock pairs and counter
+    snapshots taken at its open and close."""
+
+    def __init__(self, tracer: "Tracer"):
+        self.tracer = tracer
+        self.records: list[tuple] = []
+        self.dropped = 0
+        self.opened = clock_pair()
+        self.closed: tuple[int, int] | None = None
+        self.counters_open = tracer.read_capture_counters()
+        self.counters_close: dict | None = None
+        # taken only past the cap; re-entrant, since a collection can start
+        # inside `add` and its `runtime.gc` record is added from the same
+        # thread
+        self._dropped_lock = threading.RLock()
+
+    def add(self, name: str, trace_id: str | None, span_id: str | None,
+            parent_id: str | None, start_ns: int, end_ns: int,
+            attributes: dict | None) -> None:
+        # no lock on the way in: `list.append` is atomic, and threads that
+        # race past the check overshoot the cap by one record each at most
+        if len(self.records) >= CAPTURE_MAX_RECORDS:
+            with self._dropped_lock:
+                self.dropped += 1
+            return
+        self.records.append((
+            name, trace_id, span_id, parent_id, threading.get_ident(),
+            start_ns, end_ns, attributes or None))
+
+    def add_span(self, span: Span) -> None:
+        self.add(span.name, span.trace_id, span.span_id, span.parent_id,
+                 span.start_ns, span.end_ns, span.attributes)
+
+
+class _NullSpan:
+    """What a detail site gets when its request is not detailed."""
+
+    __slots__ = ()
+    span_id = None
+    detail = None
+    start_ns = 0
+
+    def set_attribute(self, key: str, value: Any) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _NoDetail:
+    __slots__ = ()
+
+    def __enter__(self) -> _NullSpan:
+        return _NULL_SPAN
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+    def enter(self, name: str) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+_NO_DETAIL = _NoDetail()
+
+
+class _DetailScope:
+    """One detail span: a Span while it is open (so children, events and
+    exemplars see it through the contextvar like any other), an annotation
+    in the profiler's trace, and a record in the capture when it closes.
+    It never reaches the ring or the exporter."""
+
+    __slots__ = ("_name", "_parent", "span", "_token", "_annotation")
+
+    def __init__(self, name: str, parent: Span):
+        self._name = name
+        self._parent = parent
+
+    def __enter__(self) -> Span:
+        parent = self._parent
+        self.span = Span(
+            trace_id=parent.trace_id,
+            span_id=parent.detail.tracer.next_span_id(),
+            parent_id=parent.span_id,
+            name=self._name,
+            start_ns=time.perf_counter_ns(),
+            detail=parent.detail,
+        )
+        self._annotation = _annotate(self.span)
+        self._token = _current_span.set(self.span)
+        return self.span
+
+    def __exit__(self, exc_type, exc, tb):
+        span = self.span
+        _current_span.reset(self._token)
+        self._annotation.__exit__(None, None, None)
+        span.end_ns = time.perf_counter_ns()
+        span.detail.add_span(span)
+        return False
+
+
+class _Phases:
+    """Consecutive sibling detail spans under one parent: `enter(name)`
+    closes the phase that is open and opens the next, `close()` ends the
+    last. For a long function whose phases follow one another (the search
+    service), where a `with` block per phase would re-indent all of it."""
+
+    __slots__ = ("_parent", "_scope")
+
+    def __init__(self, parent: Span):
+        self._parent = parent
+        self._scope: _DetailScope | None = None
+
+    def enter(self, name: str) -> None:
+        self.close()
+        self._scope = _DetailScope(name, self._parent)
+        self._scope.__enter__()
+
+    def close(self) -> None:
+        if self._scope is not None:
+            self._scope.__exit__(None, None, None)
+            self._scope = None
+
+
+def detail(name: str, parent: Span | None = None):
+    """Context manager for one detail span under the current span (or under
+    `parent`, for work that follows its parent's close, as the response
+    write follows `http_request`). Outside a detailed request — no profiler
+    session when its root opened — this is one contextvar read and an
+    attribute read, and yields a span whose `set_attribute` does nothing."""
+    if parent is None:
+        parent = _current_span.get()
+    if parent is None or parent.detail is None:
+        return _NO_DETAIL
+    return _DetailScope(name, parent)
+
+
+def phases():
+    """A `_Phases` under the current span; the same no-op when not detailed.
+    The caller closes it in a `finally`."""
+    parent = _current_span.get()
+    if parent is None or parent.detail is None:
+        return _NO_DETAIL
+    return _Phases(parent)
 
 
 class _RemoteContextScope:
@@ -235,9 +461,23 @@ class Tracer:
         self._ids = itertools.count(1)
         self._finished: deque[Span] = deque(maxlen=max_finished)
         self._lock = threading.Lock()
+        # request detail (module docstring): where captures are written
+        # (<path.data>/telemetry; None keeps them in memory only) and what
+        # reads the counters snapshotted at a capture's open and close —
+        # both set by the node that owns this tracer
+        self.capture_dir = None  # Path | None
+        self.capture_counters = None  # () -> dict | None
+        self._capture: Capture | None = None
+        self._capture_lock = threading.Lock()
+        self._gc_open: tuple | None = None
+        self._captures_closed = 0
+        self._last_capture = {"records": 0, "dropped": 0, "file": None}
 
     def start_span(self, name: str, attributes: dict | None = None):
         return _SpanScope(self, name, attributes)
+
+    def next_span_id(self) -> str:
+        return f"{self.name}-s{next(self._ids):06x}"
 
     def begin_span(self, name: str, attributes: dict | None = None) -> Span:
         """Start a span WITHOUT installing it as the current context — for
@@ -245,7 +485,7 @@ class Tracer:
         with end_span; propagate via restore_trace_context({"trace_id":
         span.trace_id, "span_id": span.span_id})."""
         parent = _current_span.get()
-        sid = f"{self.name}-s{next(self._ids):06x}"
+        sid = self.next_span_id()
         return Span(
             trace_id=parent.trace_id if parent else f"trace-{sid}",
             span_id=sid,
@@ -253,10 +493,16 @@ class Tracer:
             name=name,
             attributes=dict(attributes or {}),
             start_ns=time.perf_counter_ns(),
+            # a request is detailed from end to end or not at all: the
+            # root asks the profiler once, its children inherit the answer
+            detail=(parent.detail if parent is not None
+                    else self._session_capture()),
         )
 
     def end_span(self, span: Span) -> None:
         span.end_ns = time.perf_counter_ns()
+        if span.detail is not None:
+            span.detail.add_span(span)
         if self.enabled:
             with self._lock:
                 self._finished.append(span)
@@ -276,6 +522,135 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._finished.clear()
+
+    # -- request detail: the capture ---------------------------------------
+
+    def _session_capture(self) -> Capture | None:
+        """The open capture if a profiler session is on (opening one at the
+        session's first root span), else None — and the first root span
+        that finds the session over hands the capture to its writer: the
+        node may never shut down in an orderly way (SIGTERM), so the
+        capture is written when the session ends."""
+        # read without the lock: this is every root span's path, and both
+        # transitions below check again under it
+        capture = self._capture  # tpulint: disable=TPU003
+        if profiler_session_on():
+            if capture is None:
+                with self._capture_lock:
+                    capture = self._capture
+                    if capture is None:
+                        capture = self._capture = Capture(self)
+                        gc.callbacks.append(self._on_gc)
+            return capture
+        if capture is not None:
+            self._close_capture(capture)
+        return None
+
+    def read_capture_counters(self) -> dict:
+        read = self.capture_counters
+        out = read() if read is not None else {}
+        out["gc"] = gc.get_stats()
+        return out
+
+    def _close_capture(self, capture: Capture) -> None:
+        with self._capture_lock:
+            if self._capture is not capture:
+                return
+            self._capture = None
+            if self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+            self._captures_closed += 1
+            self._last_capture = {"records": len(capture.records),
+                                  "dropped": capture.dropped, "file": None}
+        capture.closed = clock_pair()
+        capture.counters_close = self.read_capture_counters()
+        if self.capture_dir is not None:
+            # a thread of its own: serving goes on while the file is written
+            threading.Thread(
+                target=self._write_capture, args=(capture,), daemon=True,
+                name="telemetry-capture-writer").start()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """`gc.callbacks` hook, installed while a capture is open: each
+        collection becomes a `runtime.gc` span on the thread it stopped."""
+        # no lock: a collection can start on a thread that holds it
+        capture = self._capture  # tpulint: disable=TPU003
+        if capture is None:
+            return
+        if phase == "start":
+            annotation = _trace_annotation_type()(
+                span_names.RUNTIME_GC, generation=info["generation"])
+            annotation.__enter__()
+            self._gc_open = (time.perf_counter_ns(), annotation)
+        elif self._gc_open is not None:
+            (start_ns, annotation), self._gc_open = self._gc_open, None
+            annotation.__exit__(None, None, None)
+            capture.add(span_names.RUNTIME_GC, None, None, None, start_ns,
+                        time.perf_counter_ns(),
+                        {"generation": info["generation"],
+                         "collected": info["collected"]})
+
+    def _write_capture(self, capture: Capture) -> None:
+        try:
+            time.sleep(CAPTURE_GRACE_S)
+            directory = self.capture_dir
+            os.makedirs(directory, exist_ok=True)
+            kept = sorted(
+                (int(m.group(1)), m.group(0))
+                for m in map(_CAPTURE_FILE.fullmatch, os.listdir(directory))
+                if m is not None)
+            number = kept[-1][0] + 1 if kept else 1
+            path = os.path.join(directory, f"capture-{number}.json")
+            records = capture.records
+            count = len(records)
+            head = {
+                "tracer": self.name,
+                "opened": {"perf_counter_ns": capture.opened[0],
+                           "time_ns": capture.opened[1]},
+                "closed": {"perf_counter_ns": capture.closed[0],
+                           "time_ns": capture.closed[1]},
+                "counters": {"open": capture.counters_open,
+                             "close": capture.counters_close},
+                "threads": {str(t.ident): t.name
+                            for t in threading.enumerate()},
+                "dropped": capture.dropped,
+                "fields": list(CAPTURE_FIELDS),
+            }
+            with open(path + ".tmp", "w") as out:
+                out.write(json.dumps(head)[:-1] + ', "records": [\n')
+                # in chunks: one json.dumps of a whole capture would hold
+                # the interpreter lock against the threads that serve
+                for lo in range(0, count, _CAPTURE_WRITE_CHUNK):
+                    chunk = records[lo:min(lo + _CAPTURE_WRITE_CHUNK, count)]
+                    out.write((",\n" if lo else "")
+                              + json.dumps(chunk, default=str)[1:-1])
+                    time.sleep(0)
+                out.write("\n]}\n")
+            os.replace(path + ".tmp", path)
+            with self._capture_lock:
+                self._last_capture = {"records": count,
+                                      "dropped": capture.dropped,
+                                      "file": path}
+            for _number, name in kept[:max(
+                    0, len(kept) + 1 - CAPTURE_KEEP_FILES)]:
+                os.remove(os.path.join(directory, name))
+        except Exception:  # noqa: BLE001 - a lost capture must not hurt serving
+            logger.exception("telemetry capture was not written")
+
+    def capture_stats(self) -> dict:
+        """The `_nodes/stats` telemetry section's `capture` entry."""
+        with self._capture_lock:
+            capture, last = self._capture, self._last_capture
+            closed = self._captures_closed
+        return {
+            "open": capture is not None,
+            "records": (len(capture.records) if capture is not None
+                        else last["records"]),
+            "dropped": (capture.dropped if capture is not None
+                        else last["dropped"]),
+            "captures": closed,
+            "last_file": last["file"],
+        }
 
 
 class _Counter:

@@ -82,7 +82,9 @@ class TestQueueWaitRecording:
             max_batch_size=8, max_wait_ms=0, metrics=metrics)
         batcher.dispatch("k", 1, lambda rows: ([0] * len(rows), False))
         h = metrics.histogram("knn.batch.queue_wait_ms").stats()
-        assert h["count"] == 1 and h["max"] == 0
+        # measured enqueue -> take in ns resolution: a solo launch takes
+        # its own entry at once, microseconds later
+        assert h["count"] == 1 and 0 <= h["max"] < 1
 
 
 # --------------------------------------------------------------------- #
