@@ -28,8 +28,9 @@ This running-top-k kernel's niche is bounded-memory scans where the XLA
 path's [B, n] score matrix does NOT fit (B x n >= HBM budget, e.g. B=1024
 over 100M docs = 400GB of scores): it is O(B k) resident instead of O(B n),
 the blockwise-tiling pattern SURVEY.md §5 "long-context" calls for. It and
-the two variants below it have no serving caller; `pallas_knn_fused` is the
-served kernel. None of them has a timing on today's code.
+the two variants below it have no serving caller and no timing on today's
+code; `pallas_knn_fused` is the served kernel, and its timings stand in its
+own section below.
 """
 
 from __future__ import annotations
@@ -585,10 +586,39 @@ def knn_topk_auto(vectors, norms_sq, valid, queries, *, k: int,
 # always exact-fp32-rescored, so score values stay in the serving score
 # space at every precision (the ANNS-AMP split from PR 9/13 applied to
 # the exact path).
+#
+# What a launch moves: the [n, d] column once, 8 bytes a row of side
+# operands and [B, r] winners out. The side operands (||v||^2 and the float
+# valid mask) go in as lane-dense [1, n] rows, the layout both serving
+# callers hold them in. As [n, 1] columns a TPU tiles them one value per
+# 128-lane row: XLA then wrote 2 x 512 MB of padded copies per launch
+# (1.07 GB of temporaries, 2 x 0.82 ms), the kernel fetched 1.5 GB for 0.5
+# and relaid both blocks out in VMEM on every grid step (PR 27, call 1).
+#
+# Device ms a launch from profiler traces, n = 2^20 x 128-d (1M live
+# rows), k = 10, TPU v5e (PR 27, chip calls 1-3; "->" from PR 26's tree,
+# whose [n, 1] side operands and 1,024-row tile cost the difference):
+#
+#   fp32  B = 1 (the served launch: 1 row padded to 8)    8.98 -> 0.89
+#         B = 8 / 32 / 128            9.42 / 30.9 / 131.3 -> 1.05 / 1.97 / 6.58
+#   bf16  B = 8, r = 40 (the kernel's part 13.1 -> 1.98)   16.0 -> 3.22
+#   int8  B = 8, r = 40 (the kernel's part 13.2 -> 1.68)   16.5 -> 3.35
+#   `_fused_xla_pool`, fp32, B = 8 / 32 / 128: 0.99 / 2.16 / 6.45
+#
+# 0.89 ms is 512 MB at 590 GB/s, 70% of the v5e's 819 GB/s; with the
+# matmul stubbed out the same launch takes 0.73 ms (call 1), so at small B
+# the column's DMA sets the pace and the six-pass fp32 matmul hides behind
+# it. From B = 32 up the pool merges do: r rounds of max / argmax over
+# [b_tile, tile + r], each a chain of dependent reductions, so fewer and
+# wider merges are cheaper (B = 128: 10.3 ms at 1,024-row tiles, 6.6 at
+# 8,192, call 1). The rest of a reduced-precision launch is
+# `_prep_operands` casting the whole column and the rescore's gather.
 # --------------------------------------------------------------------- #
 
-FK_BLOCK = 1024   # doc rows per grid step (lane-aligned, 8x sublane tile)
+FK_BLOCK = 1024   # the column pads to a multiple of this many doc rows
 FK_QTILE = 128    # query rows per grid step (one MXU tile)
+FK_TILE_BYTES = 4 << 20    # column bytes one grid step streams through VMEM
+FK_SCORE_BYTES = 4 << 20   # ... and the most its f32 score tile may take
 FUSED_MAX_K = 128          # serving cap: pool merge is O(R) VPU rounds
 FUSED_RESCORE_MULT = 4     # reduced-precision pool width multiplier
 SCORE_PRECISIONS = ("fp32", "bf16", "int8")
@@ -669,12 +699,26 @@ def _transform_scores(dots, qsq, nsq, similarity: str):
     return jnp.where(dots >= 0, dots + 1.0, 1.0 / (1.0 - dots))
 
 
+def fused_tile(n_pad: int, d: int, itemsize: int, b_tile: int) -> int:
+    """Doc rows one grid step of the fused scan streams, scores and merges:
+    FK_TILE_BYTES of the [n_pad, d] column as VMEM holds it (the minor dim
+    in whole 128-lane tiles), cut so the [b_tile, tile] f32 score tile
+    stays within FK_SCORE_BYTES, as a power of two that divides `n_pad` (a
+    multiple of FK_BLOCK) — so a power-of-two column, which is what both
+    serving callers hold, is never padded to fit. Read from the operands
+    and from nothing a user sets."""
+    row_bytes = -(-d // 128) * 128 * itemsize
+    rows = min(FK_TILE_BYTES // row_bytes, FK_SCORE_BYTES // (4 * b_tile))
+    rows = 1 << (max(rows, FK_BLOCK).bit_length() - 1)    # a power of two
+    return min(rows, n_pad & -n_pad)    # n & -n: the power of two in n
+
+
 def _knn_fused_kernel(
     q_ref,        # [b_tile, d] f32/bf16/int8 (prepped)
     qsq_ref,      # [b_tile, 1] f32 (always from the ORIGINAL f32 queries)
-    v_ref,        # [FK_BLOCK, d] tile, same dtype as q_ref
-    nsq_ref,      # [FK_BLOCK, 1] f32
-    valid_ref,    # [FK_BLOCK, 1] f32
+    v_ref,        # [tile, d] tile, same dtype as q_ref
+    nsq_ref,      # [1, tile] f32
+    valid_ref,    # [1, tile] f32
     scale_ref,    # [1, 1] f32 dequant scale
     vals_out,     # [b_tile, r] f32
     ids_out,      # [b_tile, r] i32
@@ -697,12 +741,8 @@ def _knn_fused_kernel(
         ids_scr[:] = jnp.full((B, r), -1, jnp.int32)
 
     dots = _fused_dots(q_ref[:], v_ref[:], score_precision, scale_ref[0, 0])
-    scores = _transform_scores(
-        dots, qsq_ref[:], nsq_ref[:].reshape(1, -1), similarity
-    )
-    scores = jnp.where(valid_ref[:].reshape(1, -1) > 0.5, scores, _NEG_INF)
-    base = i * bs
-    block_ids = base + jax.lax.broadcasted_iota(jnp.int32, (B, bs), 1)
+    scores = _transform_scores(dots, qsq_ref[:], nsq_ref[:], similarity)
+    scores = jnp.where(valid_ref[:] > 0.5, scores, _NEG_INF)
 
     # threshold early-exit: merge only when some row's tile-best beats its
     # current Rth-best (O(R log n_blocks) merges on a scanned corpus)
@@ -713,6 +753,7 @@ def _knn_fused_kernel(
     def _merge():
         # carried entries FIRST: argmax takes the first maximum, so score
         # ties keep the earlier (lower doc id) entry — lax.top_k tie-break
+        block_ids = i * bs + jax.lax.broadcasted_iota(jnp.int32, (B, bs), 1)
         ext_vals = jnp.concatenate([vals_scr[:], scores], axis=1)
         ext_ids = jnp.concatenate([ids_scr[:], block_ids], axis=1)
         width = bs + r
@@ -772,9 +813,10 @@ def pallas_knn_fused(
     n, d = v_x.shape
     B = q_x.shape[0]
     assert n % FK_BLOCK == 0, f"n [{n}] must be a multiple of {FK_BLOCK}"
-    n_blocks = n // FK_BLOCK
     b_tile = min(FK_QTILE, B)
     assert B % b_tile == 0, f"B [{B}] must be a multiple of {b_tile}"
+    tile = fused_tile(n, d, v_x.dtype.itemsize, b_tile)
+    n_blocks = n // tile
     kernel = functools.partial(
         _knn_fused_kernel, r=r, similarity=similarity,
         score_precision=score_precision, n_blocks=n_blocks,
@@ -787,9 +829,10 @@ def pallas_knn_fused(
         in_specs=[
             pl.BlockSpec((b_tile, d), lambda j, i: (j, 0)),
             pl.BlockSpec((b_tile, 1), lambda j, i: (j, 0)),
-            pl.BlockSpec((FK_BLOCK, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((FK_BLOCK, 1), lambda j, i: (i, 0)),
-            pl.BlockSpec((FK_BLOCK, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((tile, d), lambda j, i: (i, 0)),
+            # side operands as [1, n] rows, never [n, 1] (see above)
+            pl.BlockSpec((1, tile), lambda j, i: (0, i)),
+            pl.BlockSpec((1, tile), lambda j, i: (0, i)),
             pl.BlockSpec((1, 1), lambda j, i: (0, 0)),
         ],
         out_specs=[
@@ -813,8 +856,8 @@ def pallas_knn_fused(
         q_x,
         qsq,
         v_x,
-        norms_sq.reshape(-1, 1),
-        valid.astype(jnp.float32).reshape(-1, 1),
+        norms_sq.reshape(1, -1),
+        valid.astype(jnp.float32).reshape(1, -1),
         scale.reshape(1, 1),
     )
     return vals, ids

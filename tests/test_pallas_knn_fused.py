@@ -60,19 +60,30 @@ def _operands(rng, n=N_DOCS, d=DIM, b=6, n_dead=25):
     return vecs, norms, jnp.asarray(valid), queries, valid
 
 
-# ---------------------------------------------------------------------------
-# interpret-mode parity vs the XLA reference, per precision x similarity
-# ---------------------------------------------------------------------------
+# (query rows, precision): the B = 8 and B = 128 choices of the rule, and
+# one per operand dtype (a narrower row streams more rows per grid step)
+TILE_CASES = ((6, "fp32"), (128, "fp32"), (6, "bf16"), (6, "int8"))
+N_KINDS = ("tile", "tile+64", "two-tiles", "below-block")
 
 
-@pytest.mark.parametrize("similarity", SIMS)
-@pytest.mark.parametrize("precision", PRECISIONS)
-def test_fused_parity_interpret_vs_xla(precision, similarity):
+def _rule_tile(b: int, d: int, precision: str, n_pad: int = 1 << 30) -> int:
+    """The tile `pallas_knn_fused` picks for `b` query rows of a `d`-wide
+    `precision` column that is long enough not to bound it."""
+    item = {"fp32": 4, "bf16": 2, "int8": 1}[precision]
+    b_tile = min(pallas_knn.FK_QTILE, max(8, -(-b // 8) * 8))
+    return pallas_knn.fused_tile(n_pad, d, item, b_tile)
+
+
+def _n_for(kind: str, tile: int) -> int:
+    return {"tile": tile, "tile+64": tile + 64, "two-tiles": 2 * tile,
+            "below-block": pallas_knn.FK_BLOCK // 2}[kind]
+
+
+def _assert_pallas_matches_xla(vecs, norms, valid, queries, precision,
+                               similarity="l2_norm"):
     """The kernel and its XLA reference share the dot/transform/rescore
     math, so the [B, k] contract is identical — int8 bit-for-bit (integer
     accumulation + scalar dequant), floats to summation order."""
-    rng = np.random.default_rng(3)
-    vecs, norms, valid, queries, _ = _operands(rng)
     out = {}
     for impl in ("pallas", "xla"):
         out[impl] = pallas_knn.knn_fused(
@@ -85,6 +96,20 @@ def test_fused_parity_interpret_vs_xla(precision, similarity):
         assert np.array_equal(pv, xv)
     else:
         assert np.allclose(pv, xv, atol=1e-6, equal_nan=True)
+
+
+# ---------------------------------------------------------------------------
+# interpret-mode parity vs the XLA reference, per precision x similarity
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("similarity", SIMS)
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_fused_parity_interpret_vs_xla(precision, similarity):
+    rng = np.random.default_rng(3)
+    vecs, norms, valid, queries, _ = _operands(rng)
+    _assert_pallas_matches_xla(vecs, norms, valid, queries, precision,
+                               similarity)
 
 
 @pytest.mark.parametrize("precision", PRECISIONS)
@@ -135,18 +160,19 @@ def test_fused_fewer_live_docs_than_k_pads(impl):
 
 
 def test_fused_tie_break_prefers_lower_doc_id():
-    """Duplicate vectors straddling a block boundary: the carried-first
+    """Duplicate vectors straddling a tile boundary: the carried-first
     pool merge must reproduce lax.top_k's lowest-index tie-break."""
     rng = np.random.default_rng(7)
-    n = pallas_knn.FK_BLOCK + 64
-    data = rng.standard_normal((n, 8)).astype(np.float32)
-    dup = data[3].copy()
-    data[pallas_knn.FK_BLOCK + 11] = dup  # same vector, later block
-    vecs = jnp.asarray(data)
-    norms = jnp.sum(vecs * vecs, axis=1)
-    valid = jnp.asarray(np.ones(n, bool))
-    queries = jnp.asarray(dup[None, :] + 0.0)
     for precision in PRECISIONS:
+        tile = _rule_tile(1, 8, precision)
+        n = tile + 64
+        data = rng.standard_normal((n, 8)).astype(np.float32)
+        dup = data[3].copy()
+        data[tile + 11] = dup  # same vector, a later tile
+        vecs = jnp.asarray(data)
+        norms = jnp.sum(vecs * vecs, axis=1)
+        valid = jnp.asarray(np.ones(n, bool))
+        queries = jnp.asarray(dup[None, :] + 0.0)
         pv, pi = map(np.asarray, pallas_knn.knn_fused(
             vecs, norms, valid, queries, k=4, similarity="l2_norm",
             score_precision=precision, impl="pallas", interpret=True))
@@ -154,11 +180,170 @@ def test_fused_tie_break_prefers_lower_doc_id():
             vecs, norms, valid, queries, k=4, similarity="l2_norm",
             score_precision=precision, impl="xla", interpret=True))
         assert np.array_equal(pi, xi), precision
-        both = {3, pallas_knn.FK_BLOCK + 11}
+        both = {3, tile + 11}
         assert both <= set(pi[0].tolist()), precision
         # the duplicate pair ties exactly: lower doc id must rank first
-        assert list(pi[0]).index(3) < list(pi[0]).index(
-            pallas_knn.FK_BLOCK + 11), precision
+        assert list(pi[0]).index(3) < list(pi[0]).index(tile + 11), precision
+
+
+# ---------------------------------------------------------------------------
+# the tiling rule: every tile it can choose, at and around a tile's edge
+# ---------------------------------------------------------------------------
+
+def test_fused_tile_rule_is_a_power_of_two_dividing_the_column():
+    """Read from the operands alone; a power-of-two column (what the mesh
+    bundle holds) is its own multiple, so nothing is ever padded to fit."""
+    seen = set()
+    for b, precision in TILE_CASES:
+        for d in (8, DIM, 128, 768):
+            tile = _rule_tile(b, d, precision)
+            seen.add(tile)
+            assert tile >= pallas_knn.FK_BLOCK and tile & (tile - 1) == 0
+            for n_pad in (1024, 2048, 5 * 1024, 6 * 1024, 1 << 20):
+                got = _rule_tile(b, d, precision, n_pad)
+                assert got & (got - 1) == 0 and n_pad % got == 0
+                assert got == min(tile, n_pad & -n_pad)
+    assert len(seen) > 1, "the rule never adapts"
+
+
+@pytest.mark.parametrize("n_kind", N_KINDS)
+@pytest.mark.parametrize("b,precision", TILE_CASES)
+def test_fused_parity_over_the_rules_tiles(b, precision, n_kind):
+    rng = np.random.default_rng(13)
+    n = _n_for(n_kind, _rule_tile(b, DIM, precision))
+    vecs, norms, valid, queries, _ = _operands(rng, n=n, b=b, n_dead=n // 20)
+    _assert_pallas_matches_xla(vecs, norms, valid, queries, precision)
+
+
+@pytest.mark.parametrize("n_kind", N_KINDS)
+@pytest.mark.parametrize("b,precision", TILE_CASES)
+def test_fused_fewer_live_than_k_over_the_rules_tiles(b, precision, n_kind):
+    """Live rows only in the LAST rows of the column (past every tile edge
+    but the last): k - 5 slots come out (-inf, -1), never a finite score."""
+    rng = np.random.default_rng(5)
+    n, k = _n_for(n_kind, _rule_tile(b, DIM, precision)), 16
+    vecs = jnp.asarray(_corpus(rng, n, DIM))
+    norms = jnp.sum(vecs * vecs, axis=1)
+    valid = np.zeros(n, bool)
+    live = [0, 1, n // 2, n - 2, n - 1]
+    valid[live] = True
+    queries = jnp.asarray(_corpus(rng, b, DIM))
+    vals, ids = map(np.asarray, pallas_knn.knn_fused(
+        vecs, norms, jnp.asarray(valid), queries, k=k,
+        similarity="l2_norm", score_precision=precision, impl="pallas",
+        interpret=True))
+    assert vals.shape == (b, k) and ids.shape == (b, k)
+    for row in range(b):
+        assert set(ids[row, :5]) == set(live)
+    assert np.all(np.isfinite(vals[:, :5]))
+    assert np.all(ids[:, 5:] == -1)
+    assert np.all(np.isneginf(vals[:, 5:]))
+
+
+@pytest.mark.parametrize("twin_in", ("next-tile", "last-tile"))
+@pytest.mark.parametrize("b,precision", TILE_CASES)
+def test_fused_tie_break_over_the_rules_tiles(b, precision, twin_in):
+    """The same vector in the first tile and in the next one, or in the
+    last of four (carried through every merge between): the lower doc id
+    ranks first, as lax.top_k's lowest-index tie-break has it."""
+    rng = np.random.default_rng(7)
+    d = 8
+    tile = _rule_tile(b, d, precision)
+    n = 4 * tile
+    twin = tile + 11 if twin_in == "next-tile" else n - 5
+    data = rng.standard_normal((n, d)).astype(np.float32)
+    dup = data[3].copy()
+    data[twin] = dup
+    vecs = jnp.asarray(data)
+    norms = jnp.sum(vecs * vecs, axis=1)
+    valid = jnp.asarray(np.ones(n, bool))
+    queries = np.repeat(dup[None, :], b, axis=0)
+    queries[1:] += rng.standard_normal((b - 1, d)).astype(np.float32) * 0.01
+    out = {}
+    for impl in ("pallas", "xla"):
+        out[impl] = np.asarray(pallas_knn.knn_fused(
+            vecs, norms, valid, jnp.asarray(queries), k=4,
+            similarity="l2_norm", score_precision=precision, impl=impl,
+            interpret=True)[1])
+    assert np.array_equal(out["pallas"], out["xla"])
+    first = out["pallas"][0].tolist()
+    assert {3, twin} <= set(first)
+    assert first.index(3) < first.index(twin)
+
+
+# ---------------------------------------------------------------------------
+# the lowered launch: the column and two lane-dense rows, nothing relaid
+# ---------------------------------------------------------------------------
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (pjit, shard_map, the kernel's own body)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+def _trace_knn_fused(n, d, b, precision):
+    def f(vectors, norms_sq, valid, queries):
+        return pallas_knn.knn_fused(
+            vectors, norms_sq, valid, queries, k=10, similarity="l2_norm",
+            score_precision=precision, impl="pallas", interpret=True)
+
+    return jax.make_jaxpr(f)(
+        jnp.zeros((n, d)), jnp.zeros((n,)), jnp.zeros((n,), bool),
+        jnp.zeros((b, d))), 1
+
+
+def _trace_mesh_step(n, d, b, precision):
+    from jax.sharding import Mesh
+
+    from opensearch_tpu.parallel import distributed as dist_mod
+
+    s = 2
+    step = dist_mod.build_knn_serving_step(
+        Mesh(np.array(jax.devices()[:1]), ("data",)), k_shard=10,
+        k_final=10, similarity="l2_norm", kernel="pallas",
+        score_precision=precision, interpret=True)
+    return jax.make_jaxpr(step)(
+        jnp.zeros((s, n, d)), jnp.zeros((s, n)), jnp.zeros((s, n), bool),
+        jnp.zeros((b, d))), s
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+@pytest.mark.parametrize("trace", (_trace_knn_fused, _trace_mesh_step),
+                         ids=("knn_fused", "mesh_step"))
+def test_fused_launch_has_no_column_shaped_side_operand_and_no_pad(
+        trace, precision):
+    """Counts and shapes of the traced launch, n = 4 tiles: the kernel is
+    handed the [n, d] column and two [1, n] rows; nothing anywhere in the
+    program has shape [n, 1] (on a TPU one value per 128-lane tile row:
+    128x the bytes, and a relayout of the whole row on every launch), and
+    the column is never padded."""
+    d, b = DIM, 8
+    tile = _rule_tile(b, d, precision)
+    n = 4 * tile
+    closed, launches = trace(n, d, b, precision)
+    eqns = list(_walk_eqns(closed.jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == launches
+    for call in calls:
+        shapes = [tuple(v.aval.shape) for v in call.invars]
+        assert shapes.count((n, d)) == 1
+        assert shapes.count((1, n)) == 2
+        assert call.params["grid_mapping"].grid == (1, 4)
+    for eqn in eqns:
+        for var in (*eqn.invars, *eqn.outvars):
+            shape = tuple(getattr(var.aval, "shape", ()))
+            assert shape != (n, 1), f"{eqn.primitive.name} makes {shape}"
+        if eqn.primitive.name == "pad":
+            assert eqn.outvars[0].aval.shape[0] < n, \
+                f"pad to {eqn.outvars[0].aval.shape}"
 
 
 def test_fused_quantize_symmetric_int8_contract():
