@@ -19,7 +19,9 @@ programs also run, and are checked, at a batch wider than one query.
 
   A  exact kNN, BASELINE.json config 1 (SIFT-1M class): 1,000,000 x 128-d
      float32, space_type l2, 1 shard, 0 replicas, k = 10, size = 10
-  B  the shard-mesh program: the first 262,144 rows in a 4-shard index
+  B  the shard-mesh program: the first 253,952 rows in a 4-shard index
+     (63,488 a shard: every shard inside one padding bucket, so that what
+     each chip holds says how the shards were placed, not how they padded)
   C  ANN: 262,144 rows mapped {"method": {"name": "ivf_pq"}} (defaults:
      nlist 128, m 8, ks 256, nprobe 8), recall@10 >= 0.95 against the same
      brute-force reference. On this mixture the index defaults stop near
@@ -88,6 +90,10 @@ RECALL_FLOOR = 0.95          # the repo's own ratchet (bench.py --ann-gate)
 ANN_REQUEST = {"k": 32, "method_parameters": {"nprobe": 32}}
 SCORE_RTOL = 1e-5
 FULL_PART_DOCS = 262_144     # phases B and C
+# phase B leaves a 32nd out: 262,144 rows over 4 shards come to 65,536 a
+# shard give or take the routing hash, and a shard of 65,537 rows pads to
+# twice the rows of one of 65,535
+MESH_PART = 31 / 32
 # the second burst: the batcher holds arrivals while a launch is in flight
 # and flushes them as one batch. At the defaults its per-key tuner has
 # just learned from the sequential searches that this traffic is solo,
@@ -535,7 +541,8 @@ def residency(client: Client) -> dict:
         "resident_bytes": device["resident_bytes"],
         "by_device": device["by_device"],
         "structures": [
-            {k: s[k] for k in ("index", "field", "kind", "device", "bytes")}
+            {k: s[k] for k in ("index", "field", "kind", "device", "bytes",
+                               "by_device") if k in s}
             for s in device["structures"]],
         "backend_memory": device["backend_memory"],
         "identity_ok": device["identity_ok"],
@@ -618,7 +625,7 @@ def main(argv: list[str] | None = None) -> int:
                        f"queries not",
             "docs_cut": None if args.docs >= 1_000_000 else
             f"phase A holds {args.docs} rows (not 1,000,000), phases B and "
-            f"C {part_docs} (not {FULL_PART_DOCS})",
+            f"C {part_docs} (not {FULL_PART_DOCS}; B a 32nd less)",
         }
         plain = {"type": "knn_vector", "dimension": DIMS, "space_type": "l2"}
         phases = result.setdefault("phases", {})
@@ -630,7 +637,8 @@ def main(argv: list[str] | None = None) -> int:
         resident["A"] = residency(client)
 
         phases["B"] = run_phase(
-            "B", client, child, "smoke-mesh", plain, 4, corpus[:part_docs],
+            "B", client, child, "smoke-mesh", plain, 4,
+            corpus[:int(part_docs * MESH_PART)],
             mix.queries(1, n_queries), mix.queries(5, N_BURST), True, {})
         resident["B"] = residency(client)
         mesh_width = max(w for w in (1, 2, 4) if w <= dev["count"])
@@ -640,6 +648,24 @@ def main(argv: list[str] | None = None) -> int:
               and bundles[0]["device"] == f"mesh[{mesh_width}]",
               f"phase B bundle is {bundles}, expected one on "
               f"mesh[{mesh_width}]")
+        # placement, from the node's own ledger: every chip of the mesh holds
+        # its own shard of smoke-mesh (segment columns and bundle slice) and
+        # no other, so no chip holds more than 1.1 x the mean
+        chips: dict = {}
+        for row in resident["B"]["structures"]:
+            if row["index"] == "smoke-mesh":
+                for name, held in row.get(
+                        "by_device", {row["device"]: row["bytes"]}).items():
+                    chips[name] = chips.get(name, 0) + held
+        phases["B"]["resident_by_chip"] = chips
+        mean = sum(chips.values()) / mesh_width
+        # a dry run at a toy size goes without the bound: a few thousand
+        # rows a shard straddle a padding bucket whatever the size
+        check(len(chips) == mesh_width and (
+            max(chips.values()) <= 1.1 * mean
+            or (platform != "tpu" and part_docs < FULL_PART_DOCS)),
+              f"phase B is not placed shard by chip over {mesh_width} "
+              f"chip(s): resident bytes {chips}")
         if mesh_width > 1 and platform == "tpu":
             # the CPU backend of a dry run reports no memory statistics
             share = bundles[0]["bytes"] // mesh_width

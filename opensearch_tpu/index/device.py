@@ -105,12 +105,14 @@ class DeviceSegment:
     # logical part ("<field>", "_live", "ivfpq:<field>"): the engine frees
     # them when it retires the segment (merge, replicated-install, close)
     allocations: dict | None = None
+    # the chip every column above was put on (None: the default device)
+    device: object | None = None
 
     def with_live(self, live_host: np.ndarray) -> "DeviceSegment":
         """Republishes the deletes bitmap (refresh after deletes)."""
         live = np.zeros(self.n_pad, dtype=bool)
         live[: self.n_docs] = live_host[: self.n_docs]
-        live_dev = jax.device_put(jnp.asarray(live))
+        live_dev = jax.device_put(live, self.device)
         # the republished bitmap supersedes the old one on device: swap the
         # ledger allocation so residency tracks the PUBLISHED set (column
         # allocations move to the new segment object unchanged)
@@ -130,6 +132,7 @@ class DeviceSegment:
             numeric_fields=self.numeric_fields,
             vector_fields=self.vector_fields,
             allocations=allocs,
+            device=self.device,
         )
 
     def free_allocations(self, reason: str = "retired") -> None:
@@ -190,11 +193,14 @@ def _maybe_build_ann(vf, device, field: str | None = None):
 
 
 def to_device(seg: HostSegment, device=None) -> DeviceSegment:
+    """Publish `seg`'s columns on `device` (the chip of the segment's
+    shard, `parallel.mesh.shard_device`; None: the default device). Each
+    column goes from host memory to that chip and touches no other."""
     n_pad = pad_size(seg.n_docs)
-    put = lambda a: jax.device_put(jnp.asarray(a), device)
+    put = lambda a: jax.device_put(a, device)
     # residency accounting: one ledger allocation per published column
     # (bytes == the device arrays' summed .nbytes); index/shard/generation
-    # attribution rides the engine's upload_scope
+    # and device attribution ride the engine's upload_scope
     allocs: dict[str, object] = {}
 
     def track(fname: str, *arrays) -> None:
@@ -276,4 +282,5 @@ def to_device(seg: HostSegment, device=None) -> DeviceSegment:
         numeric_fields=numeric_fields,
         vector_fields=vector_fields,
         allocations=allocs,
+        device=device,
     )

@@ -82,7 +82,8 @@ _ENGINE_SEQ = 0
 class Engine:
     def __init__(self, path: str | Path, mapper_service: MapperService,
                  durability: str = "request",
-                 shard_label: tuple[str, int] | None = None):
+                 shard_label: tuple[str, int] | None = None,
+                 device=None):
         self.path = Path(path)
         self.path.mkdir(parents=True, exist_ok=True)
         self.mapper_service = mapper_service
@@ -90,6 +91,9 @@ class Engine:
         # every to_device publish below runs inside an upload_scope carrying
         # it, so the ledger's per-structure rows name their owner
         self.shard_label = shard_label
+        # the chip this shard's segment columns are published on
+        # (parallel.mesh.shard_device; None: the default device)
+        self.device = device
         self.translog = Translog(self.path / "translog")
         # "request" = fsync once per request before ack (the reference's
         # index.translog.durability=REQUEST — TransportWriteAction syncs at
@@ -325,8 +329,10 @@ class Engine:
         from opensearch_tpu.telemetry.device_ledger import upload_scope
 
         index, shard = self.shard_label or (f"engine:{self.instance_id}", 0)
-        return upload_scope(index=index, shard=shard,
-                            generation=self._refresh_generation + 1)
+        return upload_scope(
+            index=index, shard=shard,
+            generation=self._refresh_generation + 1,
+            device=None if self.device is None else str(self.device))
 
     @staticmethod
     def _retire_devices(pairs, reason: str) -> None:
@@ -362,7 +368,7 @@ class Engine:
                  for d in host.doc_ids], _np.int64,
             )
             with self._upload_scope():
-                dev = to_device(host)
+                dev = to_device(host, self.device)
             self._segments.append((host, dev))
             self._buffer = []
             self._buffer_pos = {}
@@ -466,7 +472,7 @@ class Engine:
 
         merged.doc_versions = _np.asarray(versions, _np.int64)
         with self._upload_scope():
-            self._segments = keep + [(merged, to_device(merged))]
+            self._segments = keep + [(merged, to_device(merged, self.device))]
         self._retire_devices(chosen, reason="merged")
         self._dirty_live -= {h.name for h, _ in chosen}
         self.stats["merge_total"] = self.stats.get("merge_total", 0) + 1
@@ -586,7 +592,7 @@ class Engine:
         old_devs = {id(d): (h, d) for h, d in self._segments}
         with self._upload_scope():
             for host in new_hosts:
-                existing[host.name] = (host, to_device(host))
+                existing[host.name] = (host, to_device(host, self.device))
         self._segments = [existing[n] for n in order if n in existing]
         # replaced same-name copies and merged-away segments the primary
         # dropped both leave the published set: release their residency
@@ -694,7 +700,7 @@ class Engine:
             with self._upload_scope():
                 for name in commit["segments"]:
                     host = load_segment(seg_dir, name)
-                    self._segments.append((host, to_device(host)))
+                    self._segments.append((host, to_device(host, self.device)))
             self.tracker = LocalCheckpointTracker(
                 max_seq_no=commit["max_seq_no"],
                 local_checkpoint=commit["local_checkpoint"],

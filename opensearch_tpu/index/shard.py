@@ -73,11 +73,13 @@ class ShardId:
 
 class IndexShard:
     def __init__(self, shard_id: ShardId, path: Path, mapper_service: MapperService,
-                 durability: str = "request", replication: str = "DOCUMENT"):
+                 durability: str = "request", replication: str = "DOCUMENT",
+                 device=None):
         self.shard_id = shard_id
         self.mapper_service = mapper_service
         self.engine = Engine(path, mapper_service, durability=durability,
-                             shard_label=(shard_id.index, shard_id.shard))
+                             shard_label=(shard_id.index, shard_id.shard),
+                             device=device)
         self.primary = True
         self.replication = replication
         # peer-recovery bookkeeping (IndexShard.recoveryState analog, read

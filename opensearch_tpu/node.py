@@ -310,10 +310,15 @@ class IndexService:
         self.closed = False
         self.shards: dict[int, IndexShard] = {}
         durability = translog_durability(settings)
+        from opensearch_tpu.parallel.mesh import shard_device
+
         for s in range(self.num_shards):
+            # each shard's columns on the chip that holds its slice of the
+            # mesh bundle, and on no other
             self.shards[s] = IndexShard(
                 ShardId(name, s), path / str(s), self.mapper_service,
                 durability=durability,
+                device=shard_device(s, self.num_shards),
             )
 
     def setting(self, key: str, default=None):
@@ -408,12 +413,17 @@ class TpuNode:
         # request-detail captures (telemetry/tracing.py): written under the
         # data path when a profiler session ends, with these counters
         # snapshotted at its open and close
-        from opensearch_tpu.telemetry.device_ledger import default_ledger
+        from opensearch_tpu.telemetry.device_ledger import (
+            backend_memory,
+            default_ledger,
+        )
 
         self.telemetry.tracer.capture_dir = self.data_path / "telemetry"
         self.telemetry.tracer.capture_counters = lambda: {
             "knn_batch": dict(self.knn_batcher.stats),
             "device_resident_bytes": default_ledger.resident_bytes(),
+            "device_resident_by_device": default_ledger.device_totals(),
+            "device_backend_memory": backend_memory(),
         }
         # roofline recorder (telemetry/roofline.py): process-wide like the
         # batcher; this node is its fallback metrics sink (active_metrics()

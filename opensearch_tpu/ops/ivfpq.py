@@ -290,7 +290,7 @@ def build(
         packed_ids[li, : len(rows)] = doc_ids[rows]
         packed_mask[li, : len(rows)] = True
 
-    put = lambda a: jax.device_put(jnp.asarray(a), device)
+    put = lambda a: jax.device_put(a, device)
     coarse_host = np.asarray(params.coarse, dtype=np.float32)
     out = IVFPQIndex(
         params=IVFPQParams(

@@ -10,10 +10,11 @@ and its per-shard fan-out (AbstractSearchAsyncAction.java:281).
 
 Layout: at first use after a refresh, each shard's segment vector columns
 are flattened into one [n_flat, d] slab (segment-ascending, doc-ascending —
-the host merge's tie-break order), stacked to [S, n_flat, d] and device_put
-with the shard axis over the mesh's data axis. The slabs are cached per
-(index, field, per-shard segment generations); a refresh invalidates only
-that index's entry.
+the host merge's tie-break order) and laid out as [S, n_flat, d] with the
+shard axis over the mesh's data axis: every device is handed its own
+shards' slabs from host memory (`_place`), so no chip ever holds another
+shard's rows. The slabs are cached per (index, field, per-shard segment
+generations); a refresh invalidates only that index's entry.
 
 Fallback contract: any shape this path cannot serve identically to the host
 merge (ANN-indexed segments on unfiltered queries, mixed similarities)
@@ -53,7 +54,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from opensearch_tpu.cluster.shard_mesh import default_registry as registry
 from opensearch_tpu.parallel.distributed import build_knn_serving_step
-from opensearch_tpu.parallel.mesh import DATA_AXIS
+from opensearch_tpu.parallel.mesh import DATA_AXIS, serving_devices
 from opensearch_tpu.search.executor import ShardHit, ShardQueryResult
 from opensearch_tpu.telemetry import spans as span_names
 from opensearch_tpu.telemetry import tracing
@@ -138,20 +139,40 @@ class _IndexBundle:
         raise IndexError(f"flat doc {flat} out of range for shard {shard_idx}")
 
 
-def _serving_mesh(n_devices: int) -> Mesh:
-    mesh = _MESH_CACHE.get(n_devices)
+def _serving_mesh(n_shards: int) -> Mesh:
+    """The mesh an index of `n_shards` shards is served on: its data axis
+    over `serving_devices`, the device order `shard_device` places each
+    shard's segment columns by."""
+    devices = serving_devices(n_shards)
+    mesh = _MESH_CACHE.get(len(devices))
     if mesh is None:
-        grid = np.asarray(jax.devices()[:n_devices]).reshape(n_devices)
-        mesh = Mesh(grid, (DATA_AXIS,))
-        _MESH_CACHE[n_devices] = mesh
+        mesh = Mesh(np.asarray(devices), (DATA_AXIS,))
+        _MESH_CACHE[len(devices)] = mesh
     return mesh
 
 
-def _largest_divisor_at_most(s: int, cap: int) -> int:
-    for d in range(min(s, cap), 0, -1):
-        if s % d == 0:
-            return d
-    return 1
+def _place(slabs: list[np.ndarray], mesh: Mesh, spec: P):
+    """[S, ...] over the mesh's data axis from one host slab a shard. Each
+    device is handed its own shards' rows straight from host memory:
+    nothing is staged on another device, nothing is stacked whole on the
+    host (a device that holds several shards gets their stack alone)."""
+    def block(index: tuple) -> np.ndarray:
+        mine = slabs[index[0]]
+        return mine[0][None] if len(mine) == 1 else np.stack(mine)
+
+    return jax.make_array_from_callback(
+        (len(slabs), *slabs[0].shape), NamedSharding(mesh, spec), block)
+
+
+def _peaks(mesh: Mesh) -> list[tuple[int, int]] | None:
+    """(peak, in use) bytes of each mesh device by the backend's own
+    count; None where it keeps none (the CPU's)."""
+    stats = [dev.memory_stats() or {} for dev in mesh.devices.flat]
+    if not all("peak_bytes_in_use" in st and "bytes_in_use" in st
+               for st in stats):
+        return None
+    return [(int(st["peak_bytes_in_use"]), int(st["bytes_in_use"]))
+            for st in stats]
 
 
 def _can_serve(snaps: list, field: str, *,
@@ -243,29 +264,45 @@ def _build_bundle(snaps: list, field: str, dims: int, mesh: Mesh,
         out[: a.shape[0]] = a
         return out
 
-    vecs = np.stack([pad(v) for v in per_shard_vecs])
-    norms = np.stack([pad(n) for n in per_shard_norms])
-    valid = np.stack([pad(v, fill=False) for v in per_shard_valid])
-
-    sharding = NamedSharding(mesh, P(DATA_AXIS))
-    bundle = _IndexBundle(
-        vectors=jax.device_put(jnp.asarray(vecs), NamedSharding(mesh, P(DATA_AXIS, None, None))),
-        norms_sq=jax.device_put(jnp.asarray(norms), sharding),
-        valid=jax.device_put(jnp.asarray(valid), sharding),
-        n_flat=n_flat,
-        seg_offsets=seg_offsets,
-    )
     # HBM residency: the slab stays device-resident until the registry
     # evicts it (superseded generation, byte budget, invalidation)
     from opensearch_tpu.telemetry.device_ledger import (
         KIND_MESH_BUNDLE,
         default_ledger,
+        device_bytes,
     )
 
+    before = _peaks(mesh)
+    with tracing.span(span_names.MESH_BUNDLE_BUILD) as span:
+        bundle = _IndexBundle(
+            vectors=_place([pad(v) for v in per_shard_vecs], mesh,
+                           P(DATA_AXIS, None, None)),
+            norms_sq=_place([pad(n) for n in per_shard_norms], mesh,
+                            P(DATA_AXIS)),
+            valid=_place([pad(v, fill=False) for v in per_shard_valid], mesh,
+                         P(DATA_AXIS)),
+            n_flat=n_flat,
+            seg_offsets=seg_offsets,
+        )
+        by_device = device_bytes(bundle.vectors, bundle.norms_sq,
+                                 bundle.valid)
+        after = _peaks(mesh)
+        span.set_attribute("devices", len(by_device))
+        span.set_attribute("shards", len(snaps))
+        span.set_attribute("bytes_per_device", max(by_device.values()))
+        # how far the build pushed any chip's peak above what that chip
+        # holds once it is done (and above its peak before): a copy staged
+        # on one chip on its way to the others shows here. A backend that
+        # keeps no peaks reads 0: `_place` puts nothing but each device's
+        # own shards, and `register` below refuses a split that holds more
+        span.set_attribute("staging_bytes", max(
+            max(0, peak - max(peak0, in_use))
+            for (peak, in_use), (peak0, _) in zip(after, before))
+            if before and after else 0)
     bundle.allocation = default_ledger.register(
         KIND_MESH_BUNDLE, bundle.nbytes, index=index_name, field=field,
         generation=tuple(generations),
-        device=f"mesh[{len(mesh.devices.flat)}]",
+        device=f"mesh[{len(by_device)}]", by_device=by_device,
     )
     return bundle
 
@@ -328,6 +365,9 @@ def mesh_knn_batch(
     dispatch. Returns a MeshLaunchOutcome (per-query per-shard results,
     device-merged row order, launch attribution), or None when this path
     cannot reproduce the host result."""
+    # the batcher's (or the service's) `launch` detail span, if this request
+    # is detailed: it learns the launch's shape below
+    launch_span = tracing.active_tracer().current_span()
     with tracing.detail(span_names.LAUNCH_HOST_PRE):
         if not shards or len(shards) != len(snaps) or not nodes:
             return None
@@ -351,8 +391,8 @@ def mesh_knn_batch(
         if any(len(node.vector) != dims for node in nodes):
             return None
 
-        n_devices = _largest_divisor_at_most(s, len(jax.devices()))
-        mesh = _serving_mesh(n_devices)
+        mesh = _serving_mesh(s)
+        n_devices = mesh.devices.size
 
         index_name = shards[0].shard_id.index
         # generation-pinned residency key (ShardMeshRegistry.residency_key):
@@ -387,8 +427,10 @@ def mesh_knn_batch(
             )
 
             default_ledger.record_transient(KIND_QUERY_BATCH, fmask.nbytes)
+            # from host memory to each device its own shards' rows: no
+            # copy of the whole mask staged on one chip
             valid = valid & jax.device_put(
-                jnp.asarray(fmask), NamedSharding(mesh, P(DATA_AXIS))
+                fmask, NamedSharding(mesh, P(DATA_AXIS))
             )
 
         b = len(nodes)
@@ -397,6 +439,10 @@ def mesh_knn_batch(
         # SURVEY.md §7 hard part #3); padding queries are zero vectors whose
         # results are sliced off
         b_pad = 1 << (b - 1).bit_length()
+        if launch_span is not None and launch_span.name == span_names.LAUNCH:
+            launch_span.set_attribute("devices", n_devices)
+            launch_span.set_attribute("shards", s)
+            launch_span.set_attribute("b_pad", b_pad)
         q_host = np.zeros((b_pad, dims), np.float32)
         for i, node in enumerate(nodes):
             q_host[i] = np.asarray(node.vector, np.float32)

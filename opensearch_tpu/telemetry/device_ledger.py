@@ -209,11 +209,13 @@ class Allocation:
     overlap and double-accounting would break the identity."""
 
     __slots__ = ("ledger", "alloc_id", "index", "shard", "field", "kind",
-                 "generation", "device", "bytes", "freed", "freed_reason")
+                 "generation", "device", "bytes", "by_device", "freed",
+                 "freed_reason")
 
     def __init__(self, ledger: "DeviceResidencyLedger", alloc_id: int,
                  index: str, shard: int, field: str, kind: str,
-                 generation: Any, device: str, nbytes: int):
+                 generation: Any, device: str, nbytes: int,
+                 by_device: dict[str, int] | None = None):
         self.ledger = ledger
         self.alloc_id = alloc_id
         self.index = index
@@ -223,11 +225,17 @@ class Allocation:
         self.generation = generation
         self.device = device
         self.bytes = int(nbytes)
+        # a sharded structure's bytes chip by chip (`device` stays its one
+        # label, the heat key's); None: all of it on `device`
+        self.by_device = by_device
         self.freed = False
         self.freed_reason = None
 
     def free(self, reason: str = "retired") -> None:
         self.ledger.free(self, reason)
+
+    def chip_bytes(self) -> dict[str, int]:
+        return self.by_device or {self.device: self.bytes}
 
     def row(self) -> dict:
         gen = self.generation
@@ -280,12 +288,18 @@ class DeviceResidencyLedger:
 
     def register(self, kind: str, nbytes: int, *, index: str | None = None,
                  shard: int | None = None, field: str | None = None,
-                 generation: Any = None,
-                 device: str | None = None) -> Allocation:
+                 generation: Any = None, device: str | None = None,
+                 by_device: dict[str, int] | None = None) -> Allocation:
         """Account a device-resident structure of ``nbytes`` (the summed
         ``.nbytes`` of its live arrays). Missing attribution falls back to
         the active :func:`upload_scope`, then to placeholders — bytes are
-        never dropped for want of a label."""
+        never dropped for want of a label. ``by_device`` (see
+        :func:`device_bytes`) splits a sharded structure's bytes chip by
+        chip for :meth:`device_totals`; it sums to ``nbytes``."""
+        if by_device is not None and sum(by_device.values()) != int(nbytes):
+            raise ValueError(
+                f"by_device sums to {sum(by_device.values())}, the "
+                f"structure holds {int(nbytes)} bytes")
         scope = _scope_var.get() or {}
         with self._lock:
             self._next_id += 1
@@ -301,7 +315,7 @@ class DeviceResidencyLedger:
                 else scope.get("generation", 0),
                 device=device if device is not None
                 else scope.get("device") or _default_device(),
-                nbytes=nbytes,
+                nbytes=nbytes, by_device=by_device,
             )
             self._live[alloc.alloc_id] = alloc
             self.counters["allocations"] += 1
@@ -667,6 +681,10 @@ class DeviceResidencyLedger:
                     del cell["shard"]
                 cell["bytes"] += row["bytes"]
                 cell["allocations"] += 1
+                if alloc.by_device is not None:
+                    split = cell.setdefault("by_device", {})
+                    for dev, nbytes in alloc.by_device.items():
+                        split[dev] = split.get(dev, 0) + nbytes
         if with_heat:
             for key, cell in grouped.items():
                 heat = self.heat_summary(key)
@@ -677,10 +695,13 @@ class DeviceResidencyLedger:
                                      str(r["generation"])))
 
     def device_totals(self) -> dict[str, int]:
+        """Resident bytes chip by chip: a sharded structure counts on each
+        chip for what that chip holds of it."""
         with self._lock:
             out: dict[str, int] = {}
             for alloc in self._live.values():
-                out[alloc.device] = out.get(alloc.device, 0) + alloc.bytes
+                for dev, nbytes in alloc.chip_bytes().items():
+                    out[dev] = out.get(dev, 0) + nbytes
         return out
 
     def compile_stats(self) -> dict[str, dict]:
@@ -748,6 +769,19 @@ def array_nbytes(*arrays: Any) -> int:
     return sum(int(a.nbytes) for a in arrays if a is not None)
 
 
+def device_bytes(*arrays: Any) -> dict[str, int]:
+    """What each device holds of these arrays, from their
+    ``addressable_shards``: ``register``'s ``by_device`` for a structure
+    laid over a mesh. Sums to ``array_nbytes`` unless a shard is
+    replicated, which then counts on every chip that holds it."""
+    out: dict[str, int] = {}
+    for a in arrays:
+        for shard in a.addressable_shards:
+            dev = str(shard.device)
+            out[dev] = out.get(dev, 0) + int(shard.data.nbytes)
+    return out
+
+
 def stats_section() -> dict:
     """The `_nodes/stats` `device` section (also returned by
     `/_otel/flush`): the process-wide ledger snapshot plus the shard-mesh
@@ -765,8 +799,8 @@ def stats_section() -> dict:
 def backend_memory() -> list[dict]:
     """What the backend itself reports for each device
     (``Device.memory_stats()``; the CPU backend reports nothing): the
-    outside check on the ledger's own bookkeeping, and the only per-device
-    split of a structure the ledger books to a whole ``mesh[N]``."""
+    outside check on the ledger's own chip-by-chip bookkeeping
+    (``device_totals``), and the only place a chip's peak shows."""
     import jax
 
     rows = []

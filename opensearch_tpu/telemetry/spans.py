@@ -3,8 +3,9 @@ reader (`perf/hostspans.py`, `perf/hostplanes.py`, a dashboard) can match by
 name after a refactor. Vary attributes, never names (the TPU013 rule, applied
 to spans).
 
-`http_request` and `search` exist always and feed the ring, the slowlog's
-trace ids and the exporter. Every other name here is a DETAIL span
+`http_request`, `search` and `mesh.bundle_build` exist always and feed the
+ring, the slowlog's trace ids and the exporter. Every other name here is a
+DETAIL span
 (`tracing.detail` / `tracing.phases`): it exists only in requests whose root
 opened while a `jax.profiler` session was running, and is written to the
 profiler's trace and the tracer's capture, never to the ring.
@@ -33,7 +34,12 @@ BATCH_WAIT = "batch.wait"
 # mesh program / per-shard ANN (search/distributed_serving.py,
 # search/executor.py): `launch.device` runs from the program call to the
 # first output's host copy returning, which is the fence; each further
-# output's host copy is a `launch.fetch`
+# output's host copy is a `launch.fetch`. `launch` carries `merged`,
+# `reason` and, from the mesh program, `devices`, `shards`, `b_pad`.
+# `mesh.bundle_build` (always on) spans the upload of an index's slabs to
+# the mesh after a refresh or a recovery: `devices`, `shards`,
+# `bytes_per_device`, `staging_bytes` (see `_build_bundle`)
+MESH_BUNDLE_BUILD = "mesh.bundle_build"
 LAUNCH = "launch"
 LAUNCH_HOST_PRE = "launch.host_pre"
 LAUNCH_DEVICE = "launch.device"
@@ -43,7 +49,8 @@ LAUNCH_HOST_POST = "launch.host_post"
 # process
 RUNTIME_GC = "runtime.gc"
 
-# every name above: what `perf/hostplanes.py` keeps of the host planes
+# the names of a request's own tree: what `perf/hostplanes.py` keeps of the
+# host planes (`mesh.bundle_build` is no part of a steady window)
 ALL = (
     HTTP_REQUEST, HTTP_PARSE, HTTP_POOL_WAIT, HTTP_RESPOND,
     SEARCH, SEARCH_PARSE, SEARCH_QUERY_PHASE, SEARCH_COLLECT, SEARCH_REDUCE,

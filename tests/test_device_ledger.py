@@ -76,6 +76,37 @@ class TestLedgerCore:
         assert f_row["bytes"] == 30 and f_row["allocations"] == 2
         assert led.device_totals() == {"d0": 35}
 
+    def test_a_sharded_structure_counts_chip_by_chip(self):
+        led = DeviceResidencyLedger()
+        led.register("column", 10, index="i", field="f", device="d0")
+        alloc = led.register("mesh_bundle", 40, index="i", field="f",
+                             generation=(1,), device="mesh[2]",
+                             by_device={"d0": 20, "d1": 20})
+        assert led.device_totals() == {"d0": 30, "d1": 20}
+        row = next(r for r in led.structures() if r["kind"] == "mesh_bundle")
+        assert row["device"] == "mesh[2]" and row["bytes"] == 40
+        assert row["by_device"] == {"d0": 20, "d1": 20}
+        assert led.resident_bytes() == 50
+        alloc.free()
+        assert led.device_totals() == {"d0": 10}
+        led.verify_identity()
+        with pytest.raises(ValueError, match="by_device sums to 30"):
+            led.register("mesh_bundle", 40, by_device={"d0": 30})
+
+    def test_device_bytes_reads_addressable_shards(self):
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from opensearch_tpu.telemetry.device_ledger import device_bytes
+
+        devs = jax.devices()[:2]
+        mesh = Mesh(np.asarray(devs), ("data",))
+        a = jax.device_put(np.zeros((2, 8), np.float32),
+                           NamedSharding(mesh, P("data")))
+        b = jax.device_put(np.zeros(4, np.int32), devs[1])
+        assert device_bytes(a, b) == {str(devs[0]): 32, str(devs[1]): 48}
+
     def test_compile_accounting_per_family(self):
         led = DeviceResidencyLedger()
         led.record_compile("knn_topk_streaming", 1000)

@@ -337,7 +337,9 @@ def test_a_follower_names_the_leaders_launch(node, tmp_path):
         batcher.reset()
     launches = [s for s in doc["spans"] if s["name"] == "launch"]
     assert len(launches) == 1 and launches[0]["attributes"] == {
-        "merged": 2, "reason": "size"}
+        "merged": 2, "reason": "size",
+        # from the mesh program: the launch's shape (one shard, one device)
+        "devices": 1, "shards": 1, "b_pad": 2}
     waits = {s["attributes"]["reason"]: s for s in doc["spans"]
              if s["name"] == "batch.wait"}
     assert set(waits) == {"size", "follower"}
