@@ -28,6 +28,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -237,7 +238,9 @@ def build_knn_serving_step(
     reference: shards across nodes, concurrent segment slices within one).
 
     fn(vectors [S, n, d], norms_sq [S, n], valid [S, n], queries [B, d])
-      -> (scores [B, k_final], global_ids [B, k_final], counts [S, B])
+      -> packed int32 [B, 2 * k_final + S]: ONE array, so a launch's
+         results cross to the host in one transfer; `unpack` reads it as
+         (scores [B, k_final], global_ids [B, k_final], counts [S, B])
 
     global id = shard_idx * n + flat_doc; counts[s, b] = number of finite
     per-shard winners (the shard's matched-doc count, ≤ k_shard). At the
@@ -322,17 +325,34 @@ def build_knn_serving_step(
         top_vals, pos = jax.lax.top_k(all_vals, k_final)
         top_ids = jnp.take_along_axis(all_ids, pos, axis=-1)
         all_counts = jax.lax.all_gather(counts, DATA_AXIS, axis=0, tiled=True)
-        return top_vals, top_ids, all_counts
+        # one output (layout: `unpack`): the scores' float32 bits carried
+        # as int32, -inf included, beside the int32 ids and counts
+        return jnp.concatenate(
+            [jax.lax.bitcast_convert_type(top_vals, jnp.int32), top_ids,
+             all_counts.T], axis=1)
 
     mapped = shard_map(
         step,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None, None), P(DATA_AXIS, None),
                   P(DATA_AXIS, None), P(None, None)),
-        out_specs=(P(), P(), P()),
+        out_specs=P(),
         check_vma=False,
     )
     return jax.jit(mapped)
+
+
+def unpack(packed, k_final: int, s: int):
+    """Host side of `build_knn_serving_step`'s one output: packed int32
+    [B, 2 * k_final + S] on the host -> (scores float32 [B, k_final],
+    global_ids int32 [B, k_final], counts int32 [S, B]), views of it and
+    bit for bit what the step computed."""
+    packed = np.asarray(packed)
+    assert packed.dtype == np.int32 and packed.shape[1] == 2 * k_final + s, (
+        packed.dtype, packed.shape, k_final, s)
+    return (packed[:, :k_final].view(np.float32),
+            packed[:, k_final:2 * k_final],
+            packed[:, 2 * k_final:].T)
 
 
 def shard_arrays_to_mesh(mesh, segments: ShardedSegments) -> ShardedSegments:

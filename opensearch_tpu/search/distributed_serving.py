@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from opensearch_tpu.cluster.shard_mesh import default_registry as registry
-from opensearch_tpu.parallel.distributed import build_knn_serving_step
+from opensearch_tpu.parallel.distributed import build_knn_serving_step, unpack
 from opensearch_tpu.parallel.mesh import DATA_AXIS, serving_devices
 from opensearch_tpu.search.executor import ShardHit, ShardQueryResult
 from opensearch_tpu.telemetry import spans as span_names
@@ -443,6 +443,8 @@ def mesh_knn_batch(
             launch_span.set_attribute("devices", n_devices)
             launch_span.set_attribute("shards", s)
             launch_span.set_attribute("b_pad", b_pad)
+            # device -> host transfers this launch makes: the packed output
+            launch_span.set_attribute("host_copies", 1)
         q_host = np.zeros((b_pad, dims), np.float32)
         for i, node in enumerate(nodes):
             q_host[i] = np.asarray(node.vector, np.float32)
@@ -483,17 +485,14 @@ def mesh_knn_batch(
     with tracing.detail(span_names.LAUNCH_DEVICE) as span:
         span.set_attribute("retraced", retraced)
         with mesh:
-            vals, gids, counts = program(
+            packed = program(
                 bundle.vectors, bundle.norms_sq, valid, queries
             )
-        # host materialization is the fence for this launch: the host
-        # needs these rows anyway, so the first copy doubles as the wait
-        vals = np.asarray(vals)[:b]          # [b, k_final]
-    # one span a copy: each is one `np.asarray`, one after the other
-    with tracing.detail(span_names.LAUNCH_FETCH):
-        gids = np.asarray(gids)[:b]
-    with tracing.detail(span_names.LAUNCH_FETCH):
-        counts = np.asarray(counts)[:, :b]   # [s, b]
+        # host materialization is the fence for this launch and its only
+        # transfer: the host needs these rows anyway, so the copy doubles
+        # as the wait (asking for it at dispatch, `copy_to_host_async`,
+        # brought it no sooner on the chip: PERF.md §6, PR 30)
+        vals, gids, counts = unpack(np.asarray(packed)[:b], k_final, s)
     wall_ns = time.perf_counter_ns() - t0
     with tracing.detail(span_names.LAUNCH_HOST_POST):
         launch_id = registry.next_launch_id()

@@ -33,9 +33,11 @@ BATCH_WAIT = "batch.wait"
 
 # mesh program / per-shard ANN (search/distributed_serving.py,
 # search/executor.py): `launch.device` runs from the program call to the
-# first output's host copy returning, which is the fence; each further
-# output's host copy is a `launch.fetch`. `launch` carries `merged`,
-# `reason` and, from the mesh program, `devices`, `shards`, `b_pad`.
+# first output's host copy returning, which is the fence. The mesh program
+# has one packed output, so that copy is its only one; `launch.fetch` is
+# the host copy of the IVF path's second output. `launch` carries `merged`,
+# `reason` and, from the mesh program, `devices`, `shards`, `b_pad`,
+# `host_copies` (device -> host transfers the launch made: 1).
 # `mesh.bundle_build` (always on) spans the upload of an index's slabs to
 # the mesh after a refresh or a recovery: `devices`, `shards`,
 # `bytes_per_device`, `staging_bytes` (see `_build_bundle`)

@@ -388,7 +388,8 @@ def test_mesh_fused_parity_across_shard_counts(n_dev):
     mesh = Mesh(devices, ("data",))
     legacy = dist_mod.build_knn_serving_step(
         mesh, k_shard=8, k_final=10, similarity="l2")
-    lv, lg, lc = map(np.asarray, legacy(vectors, norms, valid, queries))
+    lv, lg, lc = dist_mod.unpack(
+        legacy(vectors, norms, valid, queries), 10, s)
     for precision in PRECISIONS:
         out = {}
         for kernel in ("pallas", "xla"):
@@ -396,8 +397,8 @@ def test_mesh_fused_parity_across_shard_counts(n_dev):
                 mesh, k_shard=8, k_final=10, similarity="l2",
                 kernel=kernel, score_precision=precision,
                 interpret=True)
-            out[kernel] = tuple(map(
-                np.asarray, step(vectors, norms, valid, queries)))
+            out[kernel] = dist_mod.unpack(
+                step(vectors, norms, valid, queries), 10, s)
         pv, pg, pc = out["pallas"]
         xv, xg, xc = out["xla"]
         assert np.array_equal(pg, xg), (n_dev, precision)
@@ -410,6 +411,104 @@ def test_mesh_fused_parity_across_shard_counts(n_dev):
             assert np.array_equal(pg, lg), n_dev
             assert np.allclose(pv, lv, rtol=1e-6), n_dev
             assert np.array_equal(pc, lc), n_dev
+
+
+@jax.jit
+def _plain_l2_scores(vectors, norms, valid, queries):
+    # the step's default scoring (kernel "xla", fp32), whole stack at once
+    # and compiled: op by op XLA's CPU backend rounds it 2 ulp apart
+    dots = jnp.einsum(
+        "bd,snd->sbn", queries, vectors,
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+    q_sq = jnp.sum(queries * queries, axis=-1)[None, :, None]
+    d_sq = jnp.maximum(q_sq - 2.0 * dots + norms[:, None, :], 0.0)
+    return jnp.where(valid[:, None, :], 1.0 / (1.0 + d_sq), -jnp.inf)
+
+
+def _three_output_reference(vectors, norms, valid, queries, *, k_shard,
+                            k_final, kernel, precision):
+    """What the step computes, plainly: every shard scanned by the scan
+    the step names, one after the other on one device, the merge on the
+    host in (-score, shard, rank) order. No mesh, no pack."""
+    s, n = valid.shape
+    plain = (kernel, precision) == ("xla", "fp32")
+    if plain:
+        scores = _plain_l2_scores(vectors, norms, valid, queries)
+    per_v, per_g = [], []
+    for si in range(s):
+        if plain:
+            v, i = map(np.asarray, jax.lax.top_k(scores[si], k_shard))
+            g = i + si * n
+        else:
+            v, i = map(np.asarray, pallas_knn.knn_fused_shard(
+                vectors[si], norms[si], valid[si], queries, k=k_shard,
+                similarity="l2_norm", score_precision=precision,
+                impl=kernel, interpret=True))
+            g = np.where(i >= 0, i + si * n, -1)
+        per_v.append(v)
+        per_g.append(g.astype(np.int32))
+    counts = np.stack([np.isfinite(v).sum(axis=-1) for v in per_v])
+    all_v = np.concatenate(per_v, axis=1)              # [B, S * k_shard]
+    all_g = np.concatenate(per_g, axis=1)
+    pos = np.argsort(-all_v, axis=1, kind="stable")[:, :k_final]
+    return (np.take_along_axis(all_v, pos, axis=1),
+            np.take_along_axis(all_g, pos, axis=1),
+            counts.astype(np.int32))
+
+
+@pytest.mark.parametrize("n_dev", (1, 4))
+@pytest.mark.parametrize("precision", PRECISIONS)
+@pytest.mark.parametrize("kernel", ("pallas", "xla"))
+def test_mesh_step_hands_back_one_packed_array(kernel, precision, n_dev):
+    """The step's whole result is ONE int32 [B, 2 * k_final + S] array (one
+    device -> host transfer a launch), and `unpack` of it is bit for bit
+    the three arrays of a plain three-output reference: B < b_pad (the
+    padding rows are sliced off before `unpack`, as `mesh_knn_batch`
+    does), one shard with fewer valid rows than k_shard, so that
+    (-inf, -1) slots and a short count cross the pack."""
+    from jax.sharding import Mesh
+
+    from opensearch_tpu.parallel import distributed as dist_mod
+
+    rng = np.random.default_rng(30)
+    s, n, d, b, b_pad, k_shard, k_final = 4, 256, DIM, 3, 4, 8, 10
+    vectors, norms, valid, queries = _mesh_inputs(rng, s, n, d, b_pad)
+    queries = queries.at[b:].set(0.0)
+    valid = valid.at[1].set(False).at[1, jnp.array([7, 90, 201])].set(True)
+    step = dist_mod.build_knn_serving_step(
+        Mesh(np.array(jax.devices()[:n_dev]), ("data",)), k_shard=k_shard,
+        k_final=k_final, similarity="l2_norm", kernel=kernel,
+        score_precision=precision, interpret=True)
+    packed = step(vectors, norms, valid, queries)
+    assert isinstance(packed, jax.Array)
+    assert packed.dtype == jnp.int32
+    assert packed.shape == (b_pad, 2 * k_final + s)
+    assert packed.is_fully_replicated
+    got = dist_mod.unpack(np.asarray(packed)[:b], k_final, s)
+    rv, rg, rc = _three_output_reference(
+        vectors, norms, valid, queries, k_shard=k_shard, k_final=k_final,
+        kernel=kernel, precision=precision)
+    want = (rv[:b], rg[:b], rc[:, :b])
+    for name, g, w in zip(("vals", "gids", "counts"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert np.ascontiguousarray(g).tobytes() == w.tobytes(), name
+    vals, gids, counts = got
+    assert vals.dtype == np.float32 and vals.shape == (b, k_final)
+    assert counts.shape == (s, b) and (counts[1] == 3).all()
+    assert (counts[[0, 2, 3]] == k_shard).all()
+    # a shard's (-inf, -1) slots survive the pack: take k_final past the
+    # finite winners of a step that only sees the short shard
+    lone = dist_mod.build_knn_serving_step(
+        Mesh(np.array(jax.devices()[:1]), ("data",)), k_shard=k_shard,
+        k_final=k_shard, similarity="l2_norm", kernel=kernel,
+        score_precision=precision, interpret=True)
+    lv, lg, lc = dist_mod.unpack(
+        lone(vectors[1:2], norms[1:2], valid[1:2], queries), k_shard, 1)
+    assert np.isneginf(lv[:, 3:]).all() and np.isfinite(lv[:, :3]).all()
+    assert (lc == 3).all()
+    if (kernel, precision) != ("xla", "fp32"):
+        assert (lg[:, 3:] == -1).all()
 
 
 # ---------------------------------------------------------------------------

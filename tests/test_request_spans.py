@@ -33,8 +33,8 @@ from opensearch_tpu.telemetry.export import (
 DIMS = 8
 QUERY = {"size": 5, "query": {"knn": {"vec": {"vector": [0.25] * DIMS,
                                               "k": 5}}}}
-# one served kNN `_search`, root first (search.collect belongs to the
-# per-shard ANN path and is not on the exact path's tree)
+# one served kNN `_search`, root first (search.collect and launch.fetch
+# belong to the per-shard ANN path and are not on the exact path's tree)
 REQUEST_TREE = {
     span_names.HTTP_REQUEST: None,
     span_names.HTTP_PARSE: span_names.HTTP_REQUEST,
@@ -46,7 +46,6 @@ REQUEST_TREE = {
     span_names.LAUNCH: span_names.SEARCH_QUERY_PHASE,
     span_names.LAUNCH_HOST_PRE: span_names.LAUNCH,
     span_names.LAUNCH_DEVICE: span_names.LAUNCH,
-    span_names.LAUNCH_FETCH: span_names.LAUNCH,
     span_names.LAUNCH_HOST_POST: span_names.LAUNCH,
     span_names.SEARCH_REDUCE: span_names.SEARCH,
     span_names.SEARCH_FETCH: span_names.SEARCH,
@@ -182,8 +181,19 @@ def test_off_by_default_no_capture_no_detail_and_the_two_spans_as_before(
 
 
 def test_one_search_under_a_profiler_session_yields_the_whole_tree(
-        node, tmp_path):
+        node, tmp_path, monkeypatch):
+    from opensearch_tpu.search import distributed_serving
+
     node.telemetry.tracer.clear()
+    # when the launch's rows are on the host: `unpack` is handed them
+    on_host = []
+    real_unpack = distributed_serving.unpack
+
+    def unpack(packed, k_final, s):
+        on_host.append((time.perf_counter_ns(), type(packed)))
+        return real_unpack(packed, k_final, s)
+
+    monkeypatch.setattr(distributed_serving, "unpack", unpack)
     t_before = time.perf_counter_ns(), time.time_ns()
     doc, trace_dir = _traced(
         node, tmp_path,
@@ -195,13 +205,10 @@ def test_one_search_under_a_profiler_session_yields_the_whole_tree(
     trace_id = searches[0]["trace_id"]
     tree = [s for s in doc["spans"] if s["trace_id"] == trace_id]
     by_id = {s["span_id"]: s for s in tree}
-    # every span of the table, once (the first of the mesh program's three
-    # outputs is copied inside launch.device, as the fence; the other two
-    # are a launch.fetch each), under the parent the table gives it
-    fetches = [s for s in tree if s["name"] == span_names.LAUNCH_FETCH]
-    assert len(fetches) == 2
-    assert sorted(s["name"] for s in tree if s not in fetches[1:]) == sorted(
-        REQUEST_TREE)
+    # every span of the table, once, under the parent the table gives it
+    # (the mesh program's one packed output is copied inside launch.device,
+    # as the fence: a mesh launch holds no launch.fetch)
+    assert sorted(s["name"] for s in tree) == sorted(REQUEST_TREE)
     for s in tree:
         parent = by_id.get(s["parent_id"])
         assert (parent["name"] if parent else None) == REQUEST_TREE[
@@ -228,16 +235,21 @@ def test_one_search_under_a_profiler_session_yields_the_whole_tree(
             stack.append(s)
     # the loop thread hands over, a pool worker serves
     names = {s["name"]: s for s in tree}
-    # the device span ends with the fence; the other copies follow
-    assert names["launch.device"]["end_ns"] <= fetches[0]["start_ns"]
-    assert fetches[0]["end_ns"] <= fetches[1]["start_ns"]
-    assert fetches[1]["end_ns"] <= names["launch.host_post"]["start_ns"]
+    # the device span ends with the fence: the launch's one transfer has
+    # put the rows on the host before it closes, and nothing is copied after
+    (rows_at, rows_type), = on_host
+    assert rows_type is np.ndarray
+    assert (names["launch.device"]["start_ns"] < rows_at
+            <= names["launch.device"]["end_ns"])
+    assert (names["launch.device"]["end_ns"]
+            <= names["launch.host_post"]["start_ns"])
     assert names["http_request"]["thread"] != names["search"]["thread"]
     assert names["http.pool_wait"]["thread"] == names["search"]["thread"]
     wait = names["http.pool_wait"]["attributes"]
     assert wait["wait_ns"] > 0 and wait["workers"] >= 1
     assert names["batch.wait"]["attributes"]["queue_wait_ns"] >= 0
     assert names["launch"]["attributes"]["merged"] == 1
+    assert names["launch"]["attributes"]["host_copies"] == 1
     assert names["launch.device"]["attributes"] == {"retraced": False}
     assert names["http.respond"]["attributes"]["bytes"] > 0
     # both clock pairs, taken together, around the session
@@ -338,8 +350,9 @@ def test_a_follower_names_the_leaders_launch(node, tmp_path):
     launches = [s for s in doc["spans"] if s["name"] == "launch"]
     assert len(launches) == 1 and launches[0]["attributes"] == {
         "merged": 2, "reason": "size",
-        # from the mesh program: the launch's shape (one shard, one device)
-        "devices": 1, "shards": 1, "b_pad": 2}
+        # from the mesh program: the launch's shape (one shard, one
+        # device) and its one device -> host transfer
+        "devices": 1, "shards": 1, "b_pad": 2, "host_copies": 1}
     waits = {s["attributes"]["reason"]: s for s in doc["spans"]
              if s["name"] == "batch.wait"}
     assert set(waits) == {"size", "follower"}
