@@ -2223,10 +2223,11 @@ class UnmodeledKernelChecker(Checker):
 # ---------------------------------------------------------------------------
 
 # hand-scheduled kernels are allowed ONLY here: everything else consumes
-# them through the module's *_auto wrappers, which own the platform /
-# interpret dispatch (a pallas_call elsewhere bypasses the selection
-# policy, and compiles-or-crashes depending on the backend it happens to
-# meet at runtime)
+# them through the module's *_auto wrappers, or hands them what the
+# module's *_impl rule returned; either owns the platform / interpret
+# dispatch (a pallas_call elsewhere bypasses the selection policy, and
+# compiles-or-crashes depending on the backend it happens to meet at
+# runtime)
 _OPS_MODULE_PATTERNS = ("opensearch_tpu/ops/",)
 _OPS_MARKER = "# tpulint: ops-module"
 _OPS_MARKER_RE = None  # compiled lazily
@@ -2261,18 +2262,22 @@ class NakedPallasCallChecker(Checker):
     that bypasses the selection-policy layer entirely. INSIDE ``ops/``,
     every function containing a ``pallas_call`` must (a) expose an
     ``interpret`` parameter (the CPU-sim parity path is part of the kernel
-    contract, not an afterthought), and (b) be reachable — directly or
-    through module-internal helpers — from a module-level ``*_auto``
-    wrapper that carries the platform guard (an attribute read of
-    ``.platform``), the ``knn_*_auto`` / ``adc_topr_auto`` shape. That
-    wrapper is the ONLY supported entry: it decides pallas-vs-interpret
-    -vs-fallback per backend, so serving code can never hard-bind a Mosaic
-    compile to a backend that lacks it."""
+    contract, not an afterthought), and (b) have the platform guard (an
+    attribute read of ``.platform``) beside it in one of two shapes: it is
+    reachable — directly or through module-internal helpers — from a
+    module-level ``*_auto`` wrapper that carries the guard (the
+    ``adc_topr_auto`` shape), or the module holds a module-level ``*_impl``
+    rule that carries it and RETURNS the pallas-vs-interpret-vs-fallback
+    decision for callers to hand in (the ``pallas_knn.fused_impl`` shape:
+    a program built once, under shard_map, cannot call a wrapper per
+    launch). Either way one function per module decides per backend, so
+    serving code can never hard-bind a Mosaic compile to a backend that
+    lacks it."""
 
     rule_id = "TPU016"
     name = "naked-pallas-call"
-    description = ("pl.pallas_call only under ops/, reachable only "
-                   "through *_auto wrappers carrying the "
+    description = ("pl.pallas_call only under ops/, behind an *_auto "
+                   "wrapper or an *_impl rule carrying the "
                    "platform/interpret guard")
 
     def applies_to(self, display_path: str, source: str) -> bool:
@@ -2328,12 +2333,15 @@ class NakedPallasCallChecker(Checker):
                 elif isinstance(n, ast.Attribute) and n.attr in names \
                         and n.attr != fn.name:
                     rs.add(n.attr)
-        guarded_auto = [
-            fn.name for fn in all_fns
-            if fn.name.endswith("_auto") and any(
-                isinstance(n, ast.Attribute) and n.attr == "platform"
-                for n in ast.walk(fn))
-        ]
+        def reads_platform(fn: ast.AST) -> bool:
+            return any(isinstance(n, ast.Attribute) and n.attr == "platform"
+                       for n in ast.walk(fn))
+
+        guarded_auto = [fn.name for fn in all_fns
+                        if fn.name.endswith("_auto") and reads_platform(fn)]
+        has_rule = any(
+            isinstance(fn, ast.FunctionDef) and fn.name.endswith("_impl")
+            and reads_platform(fn) for fn in ctx.tree.body)
         reachable: set[str] = set(guarded_auto)
         frontier = list(guarded_auto)
         while frontier:
@@ -2350,14 +2358,14 @@ class NakedPallasCallChecker(Checker):
                     "TPU016", fn,
                     f"kernel entry [{fn.name}] has no `interpret` "
                     f"parameter: the CPU-sim parity path is part of the "
-                    f"kernel contract (the knn_*_auto shape)"))
-            if not any(f.name in reachable for f in stack):
+                    f"kernel contract (the adc_topr_auto shape)"))
+            if not has_rule and not any(f.name in reachable for f in stack):
                 out.append(ctx.violation(
                     "TPU016", fn,
                     f"kernel entry [{fn.name}] is not reachable from any "
-                    f"*_auto wrapper carrying a platform guard: add the "
-                    f"pad-and-dispatch wrapper that owns pallas-vs-"
-                    f"interpret selection"))
+                    f"*_auto wrapper carrying a platform guard, and the "
+                    f"module has no *_impl rule carrying one: add the "
+                    f"function that owns pallas-vs-interpret selection"))
         return out
 
 

@@ -1,10 +1,12 @@
-"""Exact k-NN scoring: fused matmul + similarity transform on the MXU.
+"""The dense per-document vector scorer: one score for EVERY row.
 
-The TPU-native replacement for the k-NN plugin's scorer (BASELINE.json north
-star): segment vectors live in HBM as [n_pad, d] matrices; a (batch of)
-queries becomes one [B, d] x [d, n_pad] matmul — exactly the shape the MXU
-wants — followed by the OpenSearch k-NN score-space transforms and
-jax.lax.top_k.
+`script_score`'s `knn_score` / `cosineSimilarity` / `l2Squared` score every
+matching doc (search/executor.py), which is a different operation from a
+kNN query's top-k scan: that one is ops/pallas_knn.knn_fused and nothing
+here serves it. Segment vectors live in HBM as [n_pad, d] matrices; a
+(batch of) queries becomes one [B, d] x [d, n_pad] matmul followed by the
+OpenSearch k-NN score-space transforms. `canonical_similarity` names the
+three spaces for both.
 
 Score spaces match the k-NN plugin's conventions so `_score` values are
 drop-in comparable:

@@ -3,7 +3,7 @@ candidate pool (ROADMAP item 2; the kernel PR the roofline report asked for).
 
 WHY. PR 12's roofline report ranks the ADC scan's XLA lowering among the
 top lost-time offenders and documents the ``ivfpq_search[int8]`` inversion:
-int8 achieves FEWER QPS than fp32 (204 vs 296, BENCH_ANN.json) against a
+int8 achieves less than fp32 on XLA's CPU backend against a
 SMALLER modeled byte floor, because XLA widens the quantized LUT through
 the ``take_along_axis`` gather — the byte saving never reaches HBM. A
 hand-scheduled kernel controls residency directly: the per-(query, probe)
@@ -59,11 +59,11 @@ candidate: that is the whole point.
 SELECTION. Serving reaches this kernel only through
 :func:`adc_topr_auto` / the ``search.knn.ann.kernel`` policy
 (search/ann.py): ``pallas`` on TPU, the ``interpret=True`` parity path
-only when the backend is the CPU (mirroring ``knn_*_auto``), with
+only when the backend is the CPU (as ``pallas_knn.fused_impl`` has it), with
 :func:`adc_scan_xla` as the bit-compatible XLA fallback the parity tests
 diff against. tpulint TPU016 enforces the shape statically:
-``pl.pallas_call`` lives only under ``ops/``, reachable only through
-``*_auto`` wrappers carrying the platform/interpret guard.
+``pl.pallas_call`` lives only under ``ops/``, behind the one function of
+its module that carries the platform/interpret guard.
 """
 
 from __future__ import annotations

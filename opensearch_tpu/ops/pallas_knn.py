@@ -1,36 +1,36 @@
-"""Pallas TPU kernel: blockwise exact-kNN scan with running top-k.
+"""The exact-kNN scan: `knn_fused`, the one implementation of exact kNN
+scoring in the program, and `fused_impl`, the one rule that picks its
+lowering.
 
 The flagship hot loop (ContextIndexSearcher.search + TopScoreDocCollector,
-SURVEY.md §3.2 ★★) as a hand-scheduled TPU kernel. The XLA path
-(ops/fused.knn_topk) materializes the full [B, n] score matrix in HBM
-before lax.top_k; this kernel instead streams the corpus through VMEM in
-[BLOCK, d] tiles (grid iterations are sequential on a TensorCore, so VMEM
-scratch persists across them — the standard accumulation pattern,
-/opt/skills/guides/pallas_guide.md "Grid and Block Specifications") and
-keeps only a running [B, K] top-k:
+SURVEY.md §3.2 ★★). `knn_fused` pads, preps the matmul operands at the
+scan precision, scans the column into a per-query top-R pool and, for the
+reduced precisions, rescores the pool in exact fp32. The pool scan has two
+lowerings that share every line of scoring math:
 
-  per tile:  scores = q @ tile.T on the MXU -> l2/cosine/dot transform
-             ext    = concat(scores, running_vals)          [B, BLOCK+K]
-             K x    (row max, one-hot argmax select, mask out)  on the VPU
-  HBM traffic: n*d tile reads once; no [B, n] intermediate.
+  pallas  `pallas_knn_fused`, a hand-scheduled TPU kernel: the column
+          streams through VMEM in [tile, d] blocks (grid iterations are
+          sequential on a TensorCore, so VMEM scratch persists across them
+          — /opt/skills/guides/pallas_guide.md "Grid and Block
+          Specifications"), each block scored on the MXU and folded into a
+          running [B, R] pool; no [B, n] score matrix ever exists.
+          Selection avoids lax.top_k / sort (not Mosaic-lowerable): R
+          rounds of max / argmax with iota-equality one-hot gathers.
+  xla     `_fused_xla_pool`: the full [B, n] scores and lax.top_k. What
+          serves on the CPU backend, what serves k > FUSED_MAX_K anywhere,
+          and the reference the kernel's tests compare against.
 
-Top-k selection avoids lax.top_k/sort (not Mosaic-lowerable) by K rounds
-of max/argmax with iota-equality one-hot gathers — K is small (<= 64).
+Both callers — the mesh program (parallel/distributed.
+build_knn_serving_step) and the per-shard path (search/executor.
+shard_knn_selection) — ask `fused_impl` which of the two a launch runs;
+nothing else reads the platform for that. interpret=True is the CPU tests'
+parity path and nothing else: the rule turns it on only for a forced
+"pallas" on the CPU backend, so any accelerator compiles the kernel (or
+fails in lowering) instead of silently interpreting.
 
-interpret=True is the CPU tests' parity path and nothing else: the `*_auto`
-wrappers turn it on only when the backend IS the CPU, so any accelerator
-compiles the kernel (or fails in lowering) instead of silently interpreting.
-The shape/dtype contract matches fused.knn_topk, except that slots past
-the valid-doc count carry id -1 (explicit, vs fused's arbitrary masked
-indices) — see pallas_knn_topk's docstring.
-
-This running-top-k kernel's niche is bounded-memory scans where the XLA
-path's [B, n] score matrix does NOT fit (B x n >= HBM budget, e.g. B=1024
-over 100M docs = 400GB of scores): it is O(B k) resident instead of O(B n),
-the blockwise-tiling pattern SURVEY.md §5 "long-context" calls for. It and
-the two variants below it have no serving caller and no timing on today's
-code; `pallas_knn_fused` is the served kernel, and its timings stand in its
-own section below.
+Slots past the valid-doc count carry (-inf, -1): callers drop entries with
+id < 0 (or a non-finite score) BEFORE gathering, since -1 wraps to the last
+row in jnp / numpy indexing.
 """
 
 from __future__ import annotations
@@ -42,535 +42,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from opensearch_tpu.search.profile import profiled_kernel
-
-BLOCK = 1024
 _NEG_INF = float("-inf")
-
-
-def _knn_block_kernel(
-    q_ref,        # [B, d] f32 (VMEM, full)
-    qsq_ref,      # [B, 1] f32 precomputed ||q||^2
-    v_ref,        # [BLOCK, d] f32 (VMEM, one tile)
-    nsq_ref,      # [BLOCK, 1] f32 ||v||^2
-    valid_ref,    # [BLOCK, 1] f32 (1.0 live / 0.0 dead; bool tiles are awkward)
-    vals_out,     # [B, K] f32
-    ids_out,      # [B, K] i32
-    vals_scr,     # scratch [B, K] f32
-    ids_scr,      # scratch [B, K] i32
-    *,
-    k: int,
-    similarity: str,
-    n_blocks: int,
-):
-    pi = pl.program_id(0)
-    B = q_ref.shape[0]
-
-    @pl.when(pi == 0)
-    def _init():
-        vals_scr[:] = jnp.full((B, k), _NEG_INF)
-        ids_scr[:] = jnp.full((B, k), -1, jnp.int32)
-
-    q = q_ref[:]
-    v = v_ref[:]
-    dots = jax.lax.dot_general(
-        q, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                  # [B, BLOCK]
-    nsq = nsq_ref[:].reshape(1, -1)                    # [1, BLOCK]
-    if similarity == "l2_norm":
-        d_sq = jnp.maximum(qsq_ref[:] - 2.0 * dots + nsq, 0.0)
-        scores = 1.0 / (1.0 + d_sq)
-    elif similarity == "cosine":
-        q_norm = jnp.sqrt(jnp.maximum(qsq_ref[:], 1e-24))
-        v_norm = jnp.sqrt(jnp.maximum(nsq, 1e-24))
-        scores = (1.0 + dots / (q_norm * v_norm)) / 2.0
-    else:  # dot_product
-        scores = jnp.where(dots >= 0, dots + 1.0, 1.0 / (1.0 - dots))
-    live = valid_ref[:].reshape(1, -1) > 0.5
-    scores = jnp.where(live, scores, _NEG_INF)
-
-    base = pi * BLOCK
-    block_ids = base + jax.lax.broadcasted_iota(jnp.int32, (B, BLOCK), 1)
-
-    # threshold early-exit (the BottomSortValuesCollector trick,
-    # SURVEY.md §2.5 "cross-shard early termination"): the expensive K-round
-    # merge only runs when this tile holds a score beating some row's
-    # current kth-best — for a scanned corpus that is O(B k log n_blocks)
-    # tiles, so the steady-state per-tile cost is one matmul + one row-max
-    kth_best = vals_scr[:, k - 1]                                # [B]
-    improves = jnp.any(jnp.max(scores, axis=1) > kth_best)
-
-    @pl.when(improves)
-    def _merge():
-        # carried entries FIRST: argmax takes the first maximum, so on
-        # score ties the earlier (lower doc id) entry wins — the
-        # lax.top_k / Lucene doc-id-ascending tie-break the reduce relies on
-        ext_vals = jnp.concatenate([vals_scr[:], scores], axis=1)
-        ext_ids = jnp.concatenate([ids_scr[:], block_ids], axis=1)
-        width = BLOCK + k
-        col = jax.lax.broadcasted_iota(jnp.int32, (B, width), 1)
-        colk = jax.lax.broadcasted_iota(jnp.int32, (B, k), 1)
-
-        # K rounds of extract-max via fori_loop (NOT a Python unroll) so
-        # Mosaic reuses one set of [B, width] buffers. The [B, K]
-        # accumulators ride the loop carry (dynamic lane-offset stores are
-        # not Mosaic-lowerable) and land in scratch once at the end.
-        def select_one(i, carry):
-            ext, acc_v, acc_i = carry
-            best = jnp.max(ext, axis=1, keepdims=True)           # [B, 1]
-            arg = jnp.argmax(ext, axis=1).astype(jnp.int32)      # [B]
-            onehot = col == arg[:, None]
-            best_id = jnp.sum(
-                jnp.where(onehot, ext_ids, 0), axis=1, keepdims=True
-            )
-            # a -inf row yields id -1 (padding), matching fused.knn_topk
-            best_id = jnp.where(best > _NEG_INF, best_id, -1)
-            sel = colk == i
-            acc_v = jnp.where(sel, best, acc_v)
-            acc_i = jnp.where(sel, best_id, acc_i)
-            return jnp.where(onehot, _NEG_INF, ext), acc_v, acc_i
-
-        _, acc_v, acc_i = jax.lax.fori_loop(
-            0, k, select_one,
-            (ext_vals,
-             jnp.full((B, k), _NEG_INF, jnp.float32),
-             jnp.full((B, k), -1, jnp.int32)),
-        )
-        vals_scr[:] = acc_v
-        ids_scr[:] = acc_i
-
-    @pl.when(pi == n_blocks - 1)
-    def _emit():
-        vals_out[:] = vals_scr[:]
-        ids_out[:] = ids_scr[:]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k", "similarity", "interpret")
-)
-def pallas_knn_topk(
-    vectors: jnp.ndarray,    # [n_pad, d] f32, n_pad % BLOCK == 0
-    norms_sq: jnp.ndarray,   # [n_pad]
-    valid: jnp.ndarray,      # [n_pad] bool
-    queries: jnp.ndarray,    # [B, d] f32, B % 8 == 0 preferred
-    *,
-    k: int,
-    similarity: str = "l2_norm",
-    interpret: bool = False,
-):
-    """Returns (scores [B, k], ids [B, k]).
-
-    When fewer than k docs are valid, trailing entries are (-inf, -1) —
-    NOTE this differs from fused.knn_topk, which returns arbitrary masked
-    indices with -inf scores: callers must drop entries with id < 0 (or
-    non-finite score) BEFORE gathering, since -1 wraps to the last row in
-    jnp/numpy indexing. Callers pad n to a BLOCK multiple (pad rows
-    valid=False) and B to a sublane multiple; `knn_topk_auto` does both.
-    """
-    n, d = vectors.shape
-    B = queries.shape[0]
-    assert n % BLOCK == 0, f"n [{n}] must be a multiple of {BLOCK}"
-    n_blocks = n // BLOCK
-    qsq = jnp.sum(queries * queries, axis=1, keepdims=True)
-    kernel = functools.partial(
-        _knn_block_kernel, k=k, similarity=similarity, n_blocks=n_blocks
-    )
-    vals, ids = pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((B, d), lambda i: (0, 0)),          # queries
-            pl.BlockSpec((B, 1), lambda i: (0, 0)),          # ||q||^2
-            pl.BlockSpec((BLOCK, d), lambda i: (i, 0)),      # vector tile
-            pl.BlockSpec((BLOCK, 1), lambda i: (i, 0)),      # ||v||^2 tile
-            pl.BlockSpec((BLOCK, 1), lambda i: (i, 0)),      # valid tile
-        ],
-        out_specs=[
-            pl.BlockSpec((B, k), lambda i: (0, 0)),
-            pl.BlockSpec((B, k), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((B, k), jnp.float32),
-            pltpu.VMEM((B, k), jnp.int32),
-        ],
-        # the K-round selection keeps several [B, BLOCK+K] temporaries live
-        # (Mosaic unrolls short fori_loops); raise the scoped-VMEM cap well
-        # past the default 16M — v5e has 128M physical VMEM per core
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024,
-        ),
-        interpret=interpret,
-    )(
-        queries,
-        qsq,
-        vectors,
-        norms_sq.reshape(-1, 1),
-        valid.astype(jnp.float32).reshape(-1, 1),
-    )
-    return vals, ids
-
-
-# --------------------------------------------------------------------- #
-# per-block top-k kernel (the fast path)
-#
-# The running-top-k kernel above merges [B, BLOCK+K] state on EVERY tile —
-# measured 86ms on v5e-1 for 1M x 128d. This kernel instead computes an
-# INDEPENDENT exact top-k per (query, doc-block) entirely in VMEM — top-k
-# of the union of per-block top-ks is the global top-k, so a tiny second
-# stage (lax.top_k over [B, nb*k]) finishes the job. HBM traffic: the
-# vector tiles once + [B, nb, k] winners out; the [B, n] score matrix
-# never exists.
-# --------------------------------------------------------------------- #
-
-PB_BLOCK = 2048
-PB_QTILE = 128
-
-
-def _knn_pb_kernel(
-    q_ref,        # [B_TILE, d] f32
-    qsq_ref,      # [B_TILE, 1] f32
-    v_ref,        # [PB_BLOCK, d] f32 tile
-    nsq_ref,      # [PB_BLOCK, 1] f32 tile
-    valid_ref,    # [PB_BLOCK, 1] f32 tile
-    vals_out,     # [1, B_TILE, K] f32 (this block's slot)
-    ids_out,      # [1, B_TILE, K] i32
-    s_scr,        # scratch [B_TILE, PB_BLOCK] f32
-    *,
-    k: int,
-    similarity: str,
-    precision,
-):
-    B = q_ref.shape[0]
-    bs = v_ref.shape[0]
-    dots = jax.lax.dot_general(
-        q_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=precision,
-    )                                                   # [B, bs] in VMEM
-    nsq = nsq_ref[:].reshape(1, -1)
-    if similarity == "l2_norm":
-        d_sq = jnp.maximum(qsq_ref[:] - 2.0 * dots + nsq, 0.0)
-        scores = 1.0 / (1.0 + d_sq)
-    elif similarity == "cosine":
-        q_norm = jnp.sqrt(jnp.maximum(qsq_ref[:], 1e-24))
-        v_norm = jnp.sqrt(jnp.maximum(nsq, 1e-24))
-        scores = (1.0 + dots / (q_norm * v_norm)) / 2.0
-    else:
-        scores = jnp.where(dots >= 0, dots + 1.0, 1.0 / (1.0 - dots))
-    scores = jnp.where(valid_ref[:].reshape(1, -1) > 0.5, scores, _NEG_INF)
-    s_scr[:] = scores
-
-    base = pl.program_id(1) * bs
-    colk = jax.lax.broadcasted_iota(jnp.int32, (B, k), 1)
-    # k extract-max rounds through VMEM SCRATCH (loads/stores through the
-    # ref, one round live at a time — an SSA-carried loop spills hundreds
-    # of MB of registers at these widths). Static round index i lets each
-    # round target its own output lane.
-    acc_v = jnp.full((B, k), _NEG_INF, jnp.float32)
-    acc_i = jnp.full((B, k), -1, jnp.int32)
-    for i in range(k):
-        s = s_scr[:]
-        best = jnp.max(s, axis=1, keepdims=True)             # [B, 1]
-        arg = jnp.argmax(s, axis=1).astype(jnp.int32)        # [B]
-        col = jax.lax.broadcasted_iota(jnp.int32, (B, bs), 1)
-        sel = colk == i
-        acc_v = jnp.where(sel, best, acc_v)
-        acc_i = jnp.where(sel, arg[:, None] + base, acc_i)
-        s_scr[:] = jnp.where(col == arg[:, None], _NEG_INF, s)
-    vals_out[0, :, :] = acc_v
-    ids_out[0, :, :] = acc_i
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k", "similarity", "interpret", "exact")
-)
-def pallas_knn_blocktopk(
-    vectors: jnp.ndarray,    # [n_pad, d] f32, n_pad % PB_BLOCK == 0
-    norms_sq: jnp.ndarray,
-    valid: jnp.ndarray,
-    queries: jnp.ndarray,    # [B, d], B % 8 == 0
-    *,
-    k: int,
-    similarity: str = "l2_norm",
-    interpret: bool = False,
-    exact: bool = True,
-):
-    """(scores [B, k], ids [B, k]) — exact incl. doc-id tie-break: per-block
-    argmax-first picks the lowest doc id among ties, the final merge's
-    lax.top_k picks the lowest (block, rank) position, and positions are
-    block-major so lower doc ids win. `exact=True` runs the scoring matmul
-    at HIGHEST precision (fp32-faithful on the MXU)."""
-    n, d = vectors.shape
-    B = queries.shape[0]
-    assert n % PB_BLOCK == 0, f"n [{n}] must be a multiple of {PB_BLOCK}"
-    nb = n // PB_BLOCK
-    b_tile = min(PB_QTILE, B)
-    assert B % b_tile == 0, f"B [{B}] must be a multiple of {b_tile}"
-    qsq = jnp.sum(queries * queries, axis=1, keepdims=True)
-    precision = (jax.lax.Precision.HIGHEST if exact
-                 else jax.lax.Precision.DEFAULT)
-    kernel = functools.partial(
-        _knn_pb_kernel, k=k, similarity=similarity, precision=precision
-    )
-    # 2D grid (query tiles x doc blocks): bounds the VMEM working set
-    # ([b_tile, PB_BLOCK] scores + selection temporaries) so Mosaic's
-    # register allocator never spills
-    vals, ids = pl.pallas_call(
-        kernel,
-        grid=(B // b_tile, nb),
-        in_specs=[
-            pl.BlockSpec((b_tile, d), lambda j, i: (j, 0)),
-            pl.BlockSpec((b_tile, 1), lambda j, i: (j, 0)),
-            pl.BlockSpec((PB_BLOCK, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((PB_BLOCK, 1), lambda j, i: (i, 0)),
-            pl.BlockSpec((PB_BLOCK, 1), lambda j, i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, b_tile, k), lambda j, i: (i, j, 0)),
-            pl.BlockSpec((1, b_tile, k), lambda j, i: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, B, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb, B, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((b_tile, PB_BLOCK), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024,
-        ),
-        interpret=interpret,
-    )(
-        queries, qsq, vectors,
-        norms_sq.reshape(-1, 1),
-        valid.astype(jnp.float32).reshape(-1, 1),
-    )
-    # stage 2: tiny merge over [B, nb*k] (block-major position order)
-    fv = jnp.transpose(vals, (1, 0, 2)).reshape(B, nb * k)
-    fi = jnp.transpose(ids, (1, 0, 2)).reshape(B, nb * k)
-    top_vals, pos = jax.lax.top_k(fv, k)
-    top_ids = jnp.take_along_axis(fi, pos, axis=1)
-    # all--inf rows keep id -1 (matching pallas_knn_topk's contract)
-    top_ids = jnp.where(jnp.isfinite(top_vals), top_ids, -1)
-    return top_vals, top_ids
-
-
-# --------------------------------------------------------------------- #
-# sub-block-max kernel + XLA rescore (the streaming fast path)
-#
-# The per-block top-k kernel above needs k unrolled argmax rounds in VMEM,
-# which Mosaic compiles slowly and spills at large widths. This path keeps
-# the kernel TRIVIAL: score a [B_TILE, PB_BLOCK] tile in VMEM and emit only
-# the max of every 128-doc sub-block — no loops, no selection. Selection
-# moves to XLA over the tiny [B, n/128] maxima array: the k sub-blocks
-# with the largest maxima provably contain every global top-k doc (the
-# block-max pruning argument), so an exact fp32 rescore of those k*128
-# candidate docs finishes the job. HBM traffic: vectors once + [B, n/128]
-# maxima + a [B, k*128, d] candidate gather — the [B, n] score matrix
-# never exists.
-# --------------------------------------------------------------------- #
-
-SUB = 128  # sub-block width (one lane tile)
-
-
-def _knn_sbmax_kernel(
-    q_ref,        # [B_TILE, d]
-    qsq_ref,      # [B_TILE, 1]
-    v_ref,        # [PB_BLOCK, d]
-    nsq_ref,      # [PB_BLOCK, 1]
-    valid_ref,    # [PB_BLOCK, 1]
-    out_ref,      # [1, B_TILE, PB_BLOCK // SUB]
-    *,
-    similarity: str,
-    precision,
-):
-    B = q_ref.shape[0]
-    bs = v_ref.shape[0]
-    dots = jax.lax.dot_general(
-        q_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=precision,
-    )
-    nsq = nsq_ref[:].reshape(1, -1)
-    if similarity == "l2_norm":
-        d_sq = jnp.maximum(qsq_ref[:] - 2.0 * dots + nsq, 0.0)
-        scores = 1.0 / (1.0 + d_sq)
-    elif similarity == "cosine":
-        q_norm = jnp.sqrt(jnp.maximum(qsq_ref[:], 1e-24))
-        v_norm = jnp.sqrt(jnp.maximum(nsq, 1e-24))
-        scores = (1.0 + dots / (q_norm * v_norm)) / 2.0
-    else:
-        scores = jnp.where(dots >= 0, dots + 1.0, 1.0 / (1.0 - dots))
-    scores = jnp.where(valid_ref[:].reshape(1, -1) > 0.5, scores, _NEG_INF)
-    out_ref[0, :, :] = jnp.max(
-        scores.reshape(B, bs // SUB, SUB), axis=-1
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k", "similarity", "interpret", "exact")
-)
-def pallas_knn_sbmax_topk(
-    vectors: jnp.ndarray,    # [n_pad, d], n_pad % PB_BLOCK == 0
-    norms_sq: jnp.ndarray,
-    valid: jnp.ndarray,
-    queries: jnp.ndarray,    # [B, d]
-    *,
-    k: int,
-    similarity: str = "l2_norm",
-    interpret: bool = False,
-    exact: bool = True,
-):
-    """(scores [B, k], ids [B, k]) — exact incl. doc-id tie-break (chosen
-    sub-blocks sorted ascending => candidate positions are doc-id-major)."""
-    n, d = vectors.shape
-    B = queries.shape[0]
-    assert n % PB_BLOCK == 0
-    nb = n // PB_BLOCK
-    subs_per_block = PB_BLOCK // SUB
-    b_tile = min(PB_QTILE, B)
-    assert B % b_tile == 0
-    qsq = jnp.sum(queries * queries, axis=1, keepdims=True)
-    precision = (jax.lax.Precision.HIGHEST if exact
-                 else jax.lax.Precision.DEFAULT)
-    kernel = functools.partial(
-        _knn_sbmax_kernel, similarity=similarity, precision=precision
-    )
-    submax = pl.pallas_call(
-        kernel,
-        grid=(B // b_tile, nb),
-        in_specs=[
-            pl.BlockSpec((b_tile, d), lambda j, i: (j, 0)),
-            pl.BlockSpec((b_tile, 1), lambda j, i: (j, 0)),
-            pl.BlockSpec((PB_BLOCK, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((PB_BLOCK, 1), lambda j, i: (i, 0)),
-            pl.BlockSpec((PB_BLOCK, 1), lambda j, i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, b_tile, subs_per_block),
-                               lambda j, i: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, B, subs_per_block), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024,
-        ),
-        interpret=interpret,
-    )(
-        queries, qsq, vectors,
-        norms_sq.reshape(-1, 1),
-        valid.astype(jnp.float32).reshape(-1, 1),
-    )
-    # [nb, B, subs] -> [B, n_sub] in doc order
-    n_sub = nb * subs_per_block
-    flat = jnp.transpose(submax, (1, 0, 2)).reshape(B, n_sub)
-
-    # the k sub-blocks with the largest maxima contain every top-k doc
-    _, sb_ids = jax.lax.top_k(flat, k)
-    sb_ids = jnp.sort(sb_ids, axis=1)                  # doc-id-major order
-    cand = sb_ids[:, :, None] * SUB + jnp.arange(SUB)[None, None, :]
-    cand = cand.reshape(B, k * SUB)                    # [B, k*SUB] doc ids
-
-    # exact fp32 rescore of the candidates only
-    cvec = vectors[cand]                               # [B, k*SUB, d]
-    cnrm = norms_sq[cand]
-    cok = valid[cand]
-    dots = jnp.einsum("bd,bcd->bc", queries, cvec,
-                      preferred_element_type=jnp.float32,
-                      precision=precision)
-    if similarity == "l2_norm":
-        d_sq = jnp.maximum(qsq - 2.0 * dots + cnrm, 0.0)
-        scores = 1.0 / (1.0 + d_sq)
-    elif similarity == "cosine":
-        q_norm = jnp.sqrt(jnp.maximum(qsq, 1e-24))
-        v_norm = jnp.sqrt(jnp.maximum(cnrm, 1e-24))
-        scores = (1.0 + dots / (q_norm * v_norm)) / 2.0
-    else:
-        scores = jnp.where(dots >= 0, dots + 1.0, 1.0 / (1.0 - dots))
-    scores = jnp.where(cok, scores, _NEG_INF)
-    vals, pos = jax.lax.top_k(scores, k)
-    ids = jnp.take_along_axis(cand, pos, axis=1)
-    ids = jnp.where(jnp.isfinite(vals), ids, -1)
-    return vals, ids
-
-
-def knn_sbmax_auto(vectors, norms_sq, valid, queries, *, k: int,
-                   similarity: str = "l2_norm", exact: bool = True):
-    """Pad-and-dispatch wrapper for the sub-block-max streaming path."""
-    n = vectors.shape[0]
-    B = queries.shape[0]
-    n_pad = -(-n // PB_BLOCK) * PB_BLOCK
-    if B <= PB_QTILE:
-        b_pad = max(8, -(-B // 8) * 8)
-    else:
-        b_pad = -(-B // PB_QTILE) * PB_QTILE
-    if n_pad != n:
-        vectors = jnp.pad(vectors, ((0, n_pad - n), (0, 0)))
-        norms_sq = jnp.pad(norms_sq, (0, n_pad - n))
-        valid = jnp.pad(valid, (0, n_pad - n))
-    if b_pad != B:
-        queries = jnp.pad(queries, ((0, b_pad - B), (0, 0)))
-    interpret = jax.devices()[0].platform == "cpu"
-    vals, ids = pallas_knn_sbmax_topk(
-        vectors, norms_sq, valid, queries,
-        k=k, similarity=similarity, interpret=interpret, exact=exact,
-    )
-    return vals[:B], ids[:B]
-
-
-def knn_blocktopk_auto(vectors, norms_sq, valid, queries, *, k: int,
-                       similarity: str = "l2_norm", exact: bool = True):
-    """Pad-and-dispatch wrapper for the per-block kernel."""
-    n = vectors.shape[0]
-    B = queries.shape[0]
-    n_pad = -(-n // PB_BLOCK) * PB_BLOCK
-    if B <= PB_QTILE:
-        b_pad = max(8, -(-B // 8) * 8)
-    else:
-        b_pad = -(-B // PB_QTILE) * PB_QTILE
-    if n_pad != n:
-        vectors = jnp.pad(vectors, ((0, n_pad - n), (0, 0)))
-        norms_sq = jnp.pad(norms_sq, (0, n_pad - n))
-        valid = jnp.pad(valid, (0, n_pad - n))
-    if b_pad != B:
-        queries = jnp.pad(queries, ((0, b_pad - B), (0, 0)))
-    interpret = jax.devices()[0].platform == "cpu"
-    vals, ids = pallas_knn_blocktopk(
-        vectors, norms_sq, valid, queries,
-        k=k, similarity=similarity, interpret=interpret, exact=exact,
-    )
-    return vals[:B], ids[:B]
-
-
-def knn_topk_auto(vectors, norms_sq, valid, queries, *, k: int,
-                  similarity: str = "l2_norm"):
-    """Pad-and-dispatch wrapper: compiled pallas, interpret-mode on CPU."""
-    n = vectors.shape[0]
-    B = queries.shape[0]
-    n_pad = -(-n // BLOCK) * BLOCK
-    b_pad = max(8, -(-B // 8) * 8)
-    if n_pad != n:
-        vectors = jnp.pad(vectors, ((0, n_pad - n), (0, 0)))
-        norms_sq = jnp.pad(norms_sq, (0, n_pad - n))
-        valid = jnp.pad(valid, (0, n_pad - n))
-    if b_pad != B:
-        queries = jnp.pad(queries, ((0, b_pad - B), (0, 0)))
-    interpret = jax.devices()[0].platform == "cpu"
-    vals, ids = pallas_knn_topk(
-        vectors, norms_sq, valid, queries,
-        k=k, similarity=similarity, interpret=interpret,
-    )
-    return vals[:B], ids[:B]
 
 
 # --------------------------------------------------------------------- #
 # fused exact-kNN kernel (ROADMAP item 2a: "finish the roofline climb")
 #
-# One kernel for BOTH serving shapes (the materializing exact_knn_scores
-# path and the streaming knn_topk_streaming path): blockwise
-# [b_tile, d] x [FK_BLOCK, d] distance tiles on the MXU with a running
+# One kernel for every serving shape (any segment size, any batch width):
+# blockwise [b_tile, d] x [tile, d] distance tiles on the MXU with a running
 # per-query top-R pool in VMEM scratch — the PR 13 ADC kernel's pool
 # idiom (threshold early-exit + carried-entries-first merge), so only
 # [B, R] winners ever reach HBM. Three score precisions:
@@ -686,8 +165,8 @@ def _fused_dots(q_x, v_x, score_precision: str, scale):
 
 
 def _transform_scores(dots, qsq, nsq, similarity: str):
-    """OpenSearch k-NN score-space transforms (identical math to ops/knn
-    and the kernels above; shared so pallas/XLA/rescore agree bitwise).
+    """OpenSearch k-NN score-space transforms (identical math to ops/knn's
+    dense per-document scorer; shared so pallas/XLA/rescore agree bitwise).
     qsq broadcasts as [B, 1], nsq as [1, n] or [B, n]."""
     if similarity == "l2_norm":
         d_sq = jnp.maximum(qsq - 2.0 * dots + nsq, 0.0)
@@ -809,7 +288,7 @@ def pallas_knn_fused(
     """Raw pool scan: (pool_scores [B, r], pool_ids [B, r]), slots past the
     valid-doc count carry (-inf, -1). Operands come pre-prepped from
     `_prep_operands` so this and `_fused_xla_pool` see identical bits;
-    use `knn_fused` / `knn_fused_auto` for the end-to-end contract."""
+    use `knn_fused` for the end-to-end contract."""
     n, d = v_x.shape
     B = q_x.shape[0]
     assert n % FK_BLOCK == 0, f"n [{n}] must be a multiple of {FK_BLOCK}"
@@ -962,41 +441,16 @@ def knn_fused(
     return vals[:B], ids[:B]
 
 
-def knn_fused_shard(vectors, norms_sq, valid, queries, *, k: int,
-                    similarity: str = "l2_norm",
-                    score_precision: str = "fp32",
-                    impl: str = "pallas", interpret: bool = False):
-    """Per-shard fused scan for the mesh one-launch-per-node program.
-    Traced inside shard_map: no platform read here — the caller
-    (distributed.build_knn_serving_step) resolves `interpret` once per
-    program build. Same output contract as `knn_fused`."""
-    return knn_fused(
-        vectors, norms_sq, valid, queries,
-        k=k, similarity=similarity, score_precision=score_precision,
-        impl=impl, interpret=interpret,
-    )
-
-
-@profiled_kernel("knn_fused_pallas")
-def knn_fused_auto(vectors, norms_sq, valid, queries, *, k: int,
-                   similarity: str = "l2_norm",
-                   score_precision: str = "fp32",
-                   impl: str | None = None):
-    """Policy front door for the fused exact path (the serving entry the
-    dispatch batcher launches). impl None/auto -> pallas on TPU, XLA
-    reference elsewhere; "pallas" forces the kernel (interpret-mode only
-    when the backend is the CPU, for parity runs); "xla" forces the
-    reference."""
+def fused_impl(policy: str, k: int) -> tuple[str, bool]:
+    """The one rule: which lowering of `knn_fused` a launch of pool size `k`
+    runs under `search.knn.kernel` = `policy`, as (impl, interpret). The
+    kernel when the policy forces it or is "auto" on a TPU, AND k <=
+    FUSED_MAX_K (its pool merge is O(R) VPU rounds); else the XLA twin.
+    `interpret` only for a forced "pallas" on the CPU backend (the tests'
+    parity path). Callers carry the pair on their batch / program key, so a
+    live policy flip starts new batches and never re-ranks one in flight."""
     platform = jax.devices()[0].platform
-    if impl == "pallas":
-        use, interpret = "pallas", platform == "cpu"
-    elif impl == "xla":
-        use, interpret = "xla", False
-    else:
-        use, interpret = ("pallas", False) if platform == "tpu" \
-            else ("xla", False)
-    return knn_fused(
-        vectors, norms_sq, valid, queries,
-        k=k, similarity=similarity, score_precision=score_precision,
-        impl=use, interpret=interpret,
-    )
+    wanted = policy == "pallas" or (policy != "xla" and platform == "tpu")
+    if not wanted or k > FUSED_MAX_K:
+        return "xla", False
+    return "pallas", platform == "cpu"

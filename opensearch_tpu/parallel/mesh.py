@@ -1,46 +1,15 @@
-"""Device mesh construction for the search engine's parallelism axes.
+"""Where an index's shards sit on the process's devices.
 
-SURVEY.md §2.5 mapping:
-- "data"  axis = shard partitioning (the reference's document-hash sharding,
-  OperationRouting) — each mesh slot along "data" owns one index shard's
-  segment arrays in its HBM
-- "model" axis = intra-shard parallelism (the reference's concurrent segment
-  search) — a shard's vector dim / postings space split across chips, partial
-  results psum-reduced over ICI
-
-Replication across mesh replicas (the availability axis) and cross-slice DCN
-federation (CCS) layer on top of these two compute axes.
+SURVEY.md §2.5 mapping: the "data" axis is shard partitioning (the
+reference's document-hash sharding, OperationRouting) — each mesh slot along
+"data" owns one or more index shards' segment arrays in its HBM.
 """
 
 from __future__ import annotations
 
 import jax
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DATA_AXIS = "data"
-MODEL_AXIS = "model"
-
-
-def build_mesh(
-    n_data: int | None = None,
-    n_model: int = 1,
-    devices: list | None = None,
-) -> Mesh:
-    devs = devices if devices is not None else jax.devices()
-    if n_data is None:
-        n_data = len(devs) // n_model
-    if n_data * n_model > len(devs):
-        raise ValueError(
-            f"mesh {n_data}x{n_model} needs {n_data * n_model} devices, "
-            f"have {len(devs)}"
-        )
-    grid = np.asarray(devs[: n_data * n_model]).reshape(n_data, n_model)
-    return Mesh(grid, (DATA_AXIS, MODEL_AXIS))
-
-
-def shard_spec(mesh: Mesh, *axes: str | None) -> NamedSharding:
-    return NamedSharding(mesh, P(*axes))
 
 
 def serving_devices(n_shards: int) -> list:

@@ -11,13 +11,15 @@ branches) read five dynamic settings on every dispatch:
   search.knn.score_precision         "fp32" | "bf16" | "int8" (EXACT scan)
 
 ``search.knn.kernel`` extends the ANN policy's auto/pallas/xla shape to
-the EXACT path (ISSUE 19): "pallas" serves the fused blockwise exact-kNN
-kernel (ops/pallas_knn.knn_fused_auto — running top-R pool in VMEM, only
-[B, R] winners to HBM) instead of the materializing / streaming XLA
-lowerings; ``search.knn.score_precision`` picks the fused SCAN's matmul
-width (reduced precisions widen the pool and exact-rescore in fp32, so
-returned scores stay in the serving score space). Both values ride the
-batch key, so a live flip never re-ranks an in-flight batch.
+the EXACT path (ISSUE 19): which lowering of the one exact scan
+(ops/pallas_knn.knn_fused) a launch runs — the blockwise kernel (running
+top-R pool in VMEM, only [B, R] winners to HBM) or its XLA twin — is
+decided by ONE rule, ops/pallas_knn.fused_impl, from this policy, the
+platform and the launch's k; ``search.knn.score_precision`` picks the
+SCAN's matmul width (reduced precisions widen the pool and exact-rescore
+in fp32, so returned scores stay in the serving score space). What the
+rule resolved and the precision ride the batch key, so a live flip never
+re-ranks an in-flight batch.
 
 Reduced-precision ADC (ops/ivfpq.search) only ranks CANDIDATES; the fused
 program always ends in an exact fp32 rescore over the widened pool, so
@@ -31,7 +33,7 @@ an in-flight one.
 blockwise scan (ops/pallas_adc) behind the FusionANNS-style host/device
 cooperative split — host coarse quantization + probe selection, one
 batched device scan — interpreted only when the backend is the CPU (the
-tests' parity path, mirroring ``knn_*_auto``; NOT a speed path there). "auto"
+tests' parity path; NOT a speed path there). "auto"
 resolves to "pallas" on a TPU backend and "xla" elsewhere, so the CPU sim
 keeps the fast lowering unless a test/soak forces the kernel.
 
@@ -117,8 +119,8 @@ def _validate_score_precision(v: str) -> None:
 
 
 # the EXACT path's kernel policy (ISSUE 19): same auto/pallas/xla shape as
-# the ANN policy, applied to the fused exact-kNN scan (ops/pallas_knn.
-# knn_fused_auto) vs the XLA exact lowerings (fused.knn_topk / streaming)
+# the ANN policy, read by ops/pallas_knn.fused_impl to pick the lowering of
+# the one exact scan (ops/pallas_knn.knn_fused)
 EXACT_KERNEL_SETTING: Setting[str] = Setting(
     "search.knn.kernel", "auto", str,
     Property.NODE_SCOPE, Property.DYNAMIC,
@@ -216,6 +218,8 @@ class AnnServingConfig:
         )
 
     def snapshot(self) -> dict:
+        from opensearch_tpu.ops.pallas_knn import fused_impl
+
         out = {
             "adc_precision": self.adc_precision,
             "rescore_multiplier": self.rescore_multiplier,
@@ -223,10 +227,11 @@ class AnnServingConfig:
             "exact_kernel": self.exact_kernel,
             "score_precision": self.score_precision,
             # what the policies mean on THIS backend: the kernels the next
-            # dispatch launches (and keys its batch by)
+            # dispatch launches (and keys its batch by; the exact one for a
+            # k within the kernel's cap)
             "resolved": {
                 "kernel": resolve_kernel(self.kernel),
-                "exact_kernel": resolve_kernel(self.exact_kernel),
+                "exact_kernel": fused_impl(self.exact_kernel, 1)[0],
             },
         }
         # index-build accounting (index/device.py): how many IVF-PQ

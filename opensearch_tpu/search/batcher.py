@@ -2,18 +2,16 @@
 
 The continuous-batching pattern of every inference-serving stack applied to
 the search path: today N concurrent requests over the same device-resident
-corpus pay N kernel launches, and the bench shows per-dispatch overhead
-dominates throughput (BENCH dispatch_wall ~145 ms for 2000 solo-chunked
-queries vs ~70 ms for one batched call of 100 — TPU-KNN's whole point,
-arxiv 2206.14286, is amortizing one large batched distance computation
-across many queries; FusionANNS, arxiv 2409.16576, shows the same
-coalescing for heterogeneous serving).
+corpus pay N kernel launches, each with its fixed dispatch cost (TPU-KNN's
+whole point, arxiv 2206.14286, is amortizing one large batched distance
+computation across many queries; FusionANNS, arxiv 2409.16576, shows the
+same coalescing for heterogeneous serving).
 
 Mechanism: shard-level kNN dispatch sites (executor.shard_knn_selection's
-streaming and materializing scans, and the distributed serving program in
+exact scan and ANN search, and the distributed serving program in
 search/service.py) route each query through :func:`dispatch` with a BATCH
 KEY — the identity of the kernel launch they would have made: (kind,
-device-column identity, reader GENERATION, k bucket, similarity, chunk).
+device-column identity, reader GENERATION, k bucket, similarity, ...).
 Concurrent queries with the same key coalesce into one padded batch launch;
 per-query rows scatter back to the waiting requests. Because the key
 carries the snapshot generation, a mid-flight refresh can never merge a

@@ -32,7 +32,7 @@ unfiltered multi-shard queries, one vector per dispatch, are lifted:
    falls back to an exact scan whenever a filter is present, ANN-indexed
    segments are also eligible when filtered.
  - SINGLE-SHARD: s == 1 runs the same program on a 1-device mesh (the
-   all_gather degenerates); the streaming executor path is bypassed in
+   all_gather degenerates); the per-shard executor path is bypassed in
    favor of the resident bundle.
  - BATCHED multi-query: try_distributed_knn_batch dispatches B query
    vectors in ONE program launch ([B, d] padded to a power of two), so
@@ -451,31 +451,25 @@ def mesh_knn_batch(
 
         k_shard = max(1, min(int(first.k), bundle.n_flat))
         k_final = min(max(k_shard, int(fetch_k)), s * k_shard)
-        # EXACT-path kernel policy (search.knn.kernel / score_precision): the
-        # RESOLVED kernel + precision are part of the program key, so a live
-        # flip compiles a fresh mesh program and never re-ranks a batch formed
-        # under the old policy. The platform read happens ONCE per program
-        # build (pallas interprets only when the backend is the CPU — the
-        # tests' parity path; any accelerator compiles the kernel).
-        from opensearch_tpu.search.ann import (
-            default_config as ann_config,
-            resolve_kernel,
-        )
+        # EXACT-path kernel policy (search.knn.kernel / score_precision): what
+        # the one rule (ops/pallas_knn.fused_impl) RESOLVES it to for this
+        # k, and the precision, are part of the program key, so a live flip
+        # compiles a fresh mesh program and never re-ranks a batch formed
+        # under the old policy.
+        from opensearch_tpu.ops.pallas_knn import fused_impl, fused_pool_width
+        from opensearch_tpu.search.ann import default_config as ann_config
 
-        exact_kernel = resolve_kernel(ann_config.exact_kernel)
+        impl, interpret = fused_impl(ann_config.exact_kernel, k_shard)
         score_precision = ann_config.score_precision
-        fused = (exact_kernel, score_precision) != ("xla", "fp32")
         prog_key = (n_devices, s, bundle.n_flat, dims, k_shard, k_final,
-                    similarity, b_pad, exact_kernel, score_precision)
+                    similarity, b_pad, impl, interpret, score_precision)
         with _CACHE_LOCK:
             program = _PROGRAM_CACHE.get(prog_key)
             retraced = program is None
             if program is None:
-                interpret = (exact_kernel == "pallas"
-                             and jax.devices()[0].platform == "cpu")
                 program = build_knn_serving_step(
                     mesh, k_shard=k_shard, k_final=k_final,
-                    similarity=similarity, kernel=exact_kernel,
+                    similarity=similarity, kernel=impl,
                     score_precision=score_precision, interpret=interpret,
                 )
                 _PROGRAM_CACHE[prog_key] = program
@@ -497,27 +491,18 @@ def mesh_knn_batch(
     with tracing.detail(span_names.LAUNCH_HOST_POST):
         launch_id = registry.next_launch_id()
         registry.record_launch_wall(wall_ns)
-        registry.record_launch_kernel(exact_kernel, score_precision)
+        registry.record_launch_kernel(impl, score_precision)
         # roofline accounting: ONE sharded launch against the mesh cost model
         # (per-slot scan + on-device all_gather/top_k merge)
         from opensearch_tpu.telemetry import roofline
 
         launch_params = dict(b=b_pad, s=s, n_flat=bundle.n_flat, d=dims,
-                             k_shard=k_shard, devices=n_devices)
-        if fused:
-            from opensearch_tpu.ops.pallas_knn import fused_pool_width
-
-            launch_params.update(
-                precision=score_precision,
-                r=fused_pool_width(k_shard, score_precision),
-                kernel=exact_kernel,
-            )
-            mesh_family = "mesh_knn_fused"
-            roofline.record_launch(
-                f"mesh_knn_fused[{score_precision}]", wall_ns, **launch_params)
-        else:
-            mesh_family = "mesh_knn"
-            roofline.record_launch("mesh_knn", wall_ns, **launch_params)
+                             k_shard=k_shard, devices=n_devices,
+                             precision=score_precision,
+                             r=fused_pool_width(k_shard, score_precision),
+                             kernel=impl)
+        roofline.record_launch(
+            f"mesh_knn_fused[{score_precision}]", wall_ns, **launch_params)
         from opensearch_tpu.telemetry.device_ledger import (
             KIND_QUERY_BATCH,
             default_ledger,
@@ -527,11 +512,11 @@ def mesh_knn_batch(
         # heat touch against the mesh bundle this launch scanned, bytes from
         # the same cost model the roofline fold used (telemetry/device_ledger)
         default_ledger.touch([getattr(bundle, "allocation", None)],
-                             family=mesh_family, params=launch_params)
+                             family="mesh_knn_fused", params=launch_params)
         if retraced:
             # program-cache miss == fresh jit entry for the mesh kernel family;
             # the first launch wall includes the compile
-            default_ledger.record_compile(mesh_family, wall_ns)
+            default_ledger.record_compile("mesh_knn_fused", wall_ns)
         _count("distributed_searches")
         if has_filter:
             _count("filtered")

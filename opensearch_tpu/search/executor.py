@@ -61,19 +61,11 @@ logger = logging.getLogger(__name__)
 I64_MIN = -(2**63)
 I64_MAX = 2**63 - 1
 
-# exact-kNN scan strategy: segments at or above STREAMING_MIN_DOCS live docs
-# score through the chunked streaming program (ops/fused.knn_topk_streaming,
-# HBM traffic = one [B, chunk] tile per step); smaller segments materialize
-# the [1, n] row eagerly (cheaper than a compiled scan at that size).
-# Tests lower the threshold to pin both paths against each other.
-STREAMING_MIN_DOCS = 16_384
-STREAMING_CHUNK = 32_768
-
-# observability: which scan strategy served _exec_KnnQuery selections.
-# Searches run on a parallel pool (rest/http.py), so increments go through
+# observability: which scan served _exec_KnnQuery selections. Searches run
+# on a parallel pool (rest/http.py), so increments go through
 # _count_knn_path — a bare `dict[k] += 1` is read-modify-write and drops
 # counts under concurrency.
-knn_path_stats = {"streaming": 0, "materializing": 0, "ann": 0, "fused": 0}
+knn_path_stats = {"ann": 0, "fused": 0}
 _knn_path_stats_lock = threading.Lock()
 
 
@@ -178,12 +170,10 @@ class ShardContext:
         """Per-segment (sel_mask bool[n_pad], scores f32[n_pad]) numpy pairs
         for a KnnQuery, with the top-k cut applied across the whole shard.
 
-        Large exact segments score through ops/fused.knn_topk_streaming
-        (the corpus-chunked scan that never materializes [B, n]): only the [1, k] winners
-        come back to host, as a sparse -inf-based score array (the same
-        representation the ANN path uses). Small segments keep the eager
-        materializing scan — a [1, n] row below the streaming threshold
-        costs less than a compiled scan program."""
+        Every exact segment (any size, any k, filter or not) scores through
+        ops/pallas_knn.knn_fused; an ANN-indexed segment under an unfiltered
+        query through IVF-PQ. Either way only the [1, k] winners come back to
+        the host, as a sparse -inf-based score array."""
         cached = self._knn_cache.get(id(node))
         if cached is not None:
             return cached
@@ -371,7 +361,6 @@ class ShardContext:
                 # distinct request ks share compiled programs (same concern
                 # as the ANN branch above)
                 k_bucket = 1 << (k_req - 1).bit_length()
-                chunk = min(STREAMING_CHUNK, n_pad)
                 sim = knn_ops.canonical_similarity(vf.similarity)
                 # cross-request micro-batching (search/batcher.py):
                 # concurrent filterless queries over this SAME segment
@@ -384,240 +373,102 @@ class ShardContext:
                 from opensearch_tpu.search import batcher as batcher_mod
                 from opensearch_tpu.search.ann import (
                     default_config as ann_config,
-                    resolve_kernel,
                 )
 
-                # EXACT-path kernel policy (search.knn.kernel): when it
-                # resolves to "pallas", BOTH exact strategies (streaming
-                # and materializing) serve through the fused blockwise
-                # kernel instead — the RESOLVED kernel and scan precision
-                # ride the batch key, so a live flip starts new batches
-                # and never re-ranks an in-flight one
-                exact_kernel = resolve_kernel(ann_config.exact_kernel)
+                # EXACT-path kernel policy (search.knn.kernel): what the one
+                # rule RESOLVES it to for a k bucket, and the scan
+                # precision, ride the batch key, so a live flip starts new
+                # batches and never re-ranks an in-flight one
+                policy = ann_config.exact_kernel
                 score_precision = ann_config.score_precision
-                if (exact_kernel == "pallas"
-                        and k_bucket <= pallas_knn_ops.FUSED_MAX_K):
+                impl, interpret = pallas_knn_ops.fused_impl(policy, k_bucket)
 
-                    def fused_key(kb: int):
-                        return ("knn_fused", id(vf),
-                                self.snapshot.generation, kb, sim,
-                                score_precision, exact_kernel)
+                def fused_key(kb: int):
+                    return ("knn_fused", id(vf),
+                            self.snapshot.generation, kb, sim,
+                            score_precision,
+                            *pallas_knn_ops.fused_impl(policy, kb))
 
-                    key = (
-                        fused_key(k_bucket)
-                        if node.filter is None else None
+                key = fused_key(k_bucket) if node.filter is None else None
+                # cross-k coalescing: ride an already-forming batch of the
+                # next-larger k buckets (result rows truncate for free)
+                alt_keys = (
+                    (fused_key(k_bucket * 2), fused_key(k_bucket * 4))
+                    if key is not None else ()
+                )
+
+                touch_allocs = _touch_targets(dev, node.field)
+
+                def launch_fused(rows):
+                    q_batch = _pad_query_batch(rows)
+                    t0 = time.perf_counter_ns()
+                    with profile.profiling(None):
+                        b_vals, b_ids = pallas_knn_ops.knn_fused(
+                            vf.vectors, vf.norms_sq, valid, q_batch,
+                            k=k_bucket, similarity=sim,
+                            score_precision=score_precision,
+                            impl=impl, interpret=interpret,
+                        )
+                    # host materialization is the fence for this launch
+                    b_vals = np.asarray(b_vals)
+                    b_ids = np.asarray(b_ids)
+                    launch_params = dict(
+                        b=int(q_batch.shape[0]),
+                        n=int(vf.vectors.shape[0]),
+                        d=int(vf.vectors.shape[1]), k=k_bucket,
+                        r=pallas_knn_ops.fused_pool_width(
+                            k_bucket, score_precision),
+                        precision=score_precision,
                     )
-                    alt_keys = tuple(
-                        fused_key(kb)
-                        for kb in (k_bucket * 2, k_bucket * 4)
-                        if kb <= pallas_knn_ops.FUSED_MAX_K
-                    ) if key is not None else ()
-
-                    touch_allocs = _touch_targets(dev, node.field)
-
-                    def launch_fused(rows):
-                        q_batch = _pad_query_batch(rows)
-                        t0 = time.perf_counter_ns()
-                        with profile.profiling(None):
-                            b_vals, b_ids = pallas_knn_ops.knn_fused_auto(
-                                vf.vectors, vf.norms_sq, valid, q_batch,
-                                k=k_bucket, similarity=sim,
-                                score_precision=score_precision,
-                                impl=exact_kernel,
-                            )
-                        # host materialization is the fence for this launch
-                        b_vals = np.asarray(b_vals)
-                        b_ids = np.asarray(b_ids)
-                        launch_params = dict(
-                            b=int(q_batch.shape[0]),
-                            n=int(vf.vectors.shape[0]),
-                            d=int(vf.vectors.shape[1]), k=k_bucket,
-                            r=pallas_knn_ops.fused_pool_width(
-                                k_bucket, score_precision),
-                            precision=score_precision,
-                        )
-                        roofline.record_launch(
-                            f"knn_fused_pallas[{score_precision}]",
-                            time.perf_counter_ns() - t0,
-                            **launch_params,
-                        )
-                        from opensearch_tpu.telemetry.device_ledger import (
-                            default_ledger,
-                        )
-
-                        default_ledger.touch(
-                            touch_allocs, family="knn_fused_pallas",
-                            params=launch_params)
-                        retraced = profile.signature_retraced(
-                            "knn_fused_pallas", (vf.vectors, q_batch),
-                            (k_bucket, sim, score_precision, exact_kernel))
-                        return (
-                            [(b_vals[i], b_ids[i])
-                             for i in range(len(rows))],
-                            retraced,
-                        )
-
-                    out = batcher_mod.dispatch(
-                        key, qv[0], launch_fused,
-                        shards=1, rank=k_bucket,
-                        alt_keys=alt_keys,
-                        family="knn_fused_pallas",
-                        tune_key=("knn_fused_pallas",
-                                  id(self.mapper_service), node.field,
-                                  k_bucket))
-                    vals, ids = out.value
-                    if prof is not None:
-                        prof.record_kernel(
-                            "knn_fused_pallas", out.kernel_share_ns,
-                            int(qv.nbytes), out.retraced,
-                            annotations={
-                                "score_precision": score_precision,
-                                "kernel": exact_kernel,
-                            },
-                        )
-                    scores = np.full(n_pad, -np.inf, np.float32)
-                    hit = ids >= 0
-                    scores[ids[hit]] = vals[hit]
-                    _count_knn_path("fused")
-                elif (host.n_docs >= STREAMING_MIN_DOCS
-                        and n_pad % chunk == 0 and k_bucket <= chunk):
-                    from opensearch_tpu.ops import fused
-
-                    jfn = fused.cached_knn_streaming(k_bucket, sim, chunk)
-
-                    def stream_key(kb: int):
-                        return ("knn_topk_streaming", id(vf),
-                                self.snapshot.generation, kb, sim, chunk)
-
-                    key = (
-                        stream_key(k_bucket)
-                        if node.filter is None else None
+                    roofline.record_launch(
+                        f"knn_fused_pallas[{score_precision}]",
+                        time.perf_counter_ns() - t0,
+                        **launch_params,
                     )
-                    # cross-k coalescing: ride an already-forming batch of
-                    # the next-larger k buckets (result rows truncate for
-                    # free; kb stays within the streaming chunk bound)
-                    alt_keys = tuple(
-                        stream_key(kb)
-                        for kb in (k_bucket * 2, k_bucket * 4)
-                        if kb <= chunk
-                    ) if key is not None else ()
-
-                    touch_allocs = _touch_targets(dev, node.field)
-
-                    def launch_streaming(rows):
-                        q_batch = _pad_query_batch(rows)
-                        t0 = time.perf_counter_ns()
-                        with profile.profiling(None):
-                            b_vals, b_ids = jfn(
-                                vf.vectors, vf.norms_sq, valid, q_batch
-                            )
-                        # host materialization is the fence for this launch
-                        b_vals = np.asarray(b_vals)
-                        b_ids = np.asarray(b_ids)
-                        launch_params = dict(
-                            b=int(q_batch.shape[0]),
-                            n=int(vf.vectors.shape[0]),
-                            d=int(vf.vectors.shape[1]), k=k_bucket,
-                        )
-                        roofline.record_launch(
-                            "knn_topk_streaming",
-                            time.perf_counter_ns() - t0,
-                            **launch_params,
-                        )
-                        # heat touch: the column + live bitmap this scan
-                        # read, bytes from the same cost model
-                        from opensearch_tpu.telemetry.device_ledger import (
-                            default_ledger,
-                        )
-
-                        default_ledger.touch(
-                            touch_allocs, family="knn_topk_streaming",
-                            params=launch_params)
-                        retraced = profile.signature_retraced(
-                            "knn_topk_streaming", (vf.vectors, q_batch),
-                            (k_bucket, chunk))
-                        return (
-                            [(b_vals[i], b_ids[i]) for i in range(len(rows))],
-                            retraced,
-                        )
-
-                    # shards=1: this is the per-shard fallback path (the
-                    # shard-mesh launch in service.py passes its mesh
-                    # width); the batcher's cross-shard stats stay honest
-                    out = batcher_mod.dispatch(
-                        key, qv[0], launch_streaming,
-                        shards=1, rank=k_bucket,
-                        alt_keys=alt_keys,
-                        family="knn_topk_streaming",
-                        tune_key=("knn_topk_streaming",
-                                  id(self.mapper_service), node.field,
-                                  k_bucket))
-                    vals, ids = out.value
-                    if prof is not None:
-                        # a batched operator owns its SHARE of the fenced
-                        # kernel wall (merged launches split evenly)
-                        prof.record_kernel(
-                            "knn_topk_streaming", out.kernel_share_ns,
-                            int(qv.nbytes), out.retraced,
-                        )
-                    scores = np.full(n_pad, -np.inf, np.float32)
-                    finite = np.isfinite(vals)
-                    scores[ids[finite]] = vals[finite]
-                    _count_knn_path("streaming")
-                else:
-                    key = (
-                        ("knn_exact_scores", id(vf),
-                         self.snapshot.generation, sim)
-                        if node.filter is None else None
+                    # heat touch: the column + live bitmap this scan read,
+                    # bytes from the same cost model
+                    from opensearch_tpu.telemetry.device_ledger import (
+                        default_ledger,
                     )
 
-                    touch_allocs = _touch_targets(dev, node.field)
+                    default_ledger.touch(
+                        touch_allocs, family="knn_fused_pallas",
+                        params=launch_params)
+                    retraced = profile.signature_retraced(
+                        "knn_fused_pallas", (vf.vectors, q_batch),
+                        (k_bucket, sim, score_precision, impl, interpret))
+                    return (
+                        [(b_vals[i], b_ids[i]) for i in range(len(rows))],
+                        retraced,
+                    )
 
-                    def launch_exact(rows):
-                        q_batch = _pad_query_batch(rows)
-                        t0 = time.perf_counter_ns()
-                        with profile.profiling(None):
-                            b_scores = np.asarray(knn_ops.exact_knn_scores(
-                                q_batch, vf.vectors, vf.norms_sq, valid,
-                                vf.similarity,
-                            ))
-                        launch_params = dict(
-                            b=int(q_batch.shape[0]),
-                            n=int(vf.vectors.shape[0]),
-                            d=int(vf.vectors.shape[1]),
-                        )
-                        roofline.record_launch(
-                            "knn_exact_scores",
-                            time.perf_counter_ns() - t0,
-                            **launch_params,
-                        )
-                        # heat touch: the column + live bitmap, bytes from
-                        # the same cost model
-                        from opensearch_tpu.telemetry.device_ledger import (
-                            default_ledger,
-                        )
-
-                        default_ledger.touch(
-                            touch_allocs, family="knn_exact_scores",
-                            params=launch_params)
-                        retraced = profile.signature_retraced(
-                            "knn_exact_scores", (vf.vectors, q_batch), (sim,))
-                        return (
-                            [b_scores[i] for i in range(len(rows))], retraced,
-                        )
-
-                    out = batcher_mod.dispatch(
-                        key, qv[0], launch_exact, shards=1,
-                        family="knn_exact_scores",
-                        tune_key=("knn_exact_scores",
-                                  id(self.mapper_service), node.field))
-                    scores = out.value
-                    if prof is not None:
-                        prof.record_kernel(
-                            "knn_exact_scores", out.kernel_share_ns,
-                            int(qv.nbytes), out.retraced,
-                        )
-                    _count_knn_path("materializing")
+                # shards=1: this is the per-shard fallback path (the
+                # shard-mesh launch in service.py passes its mesh width);
+                # the batcher's cross-shard stats stay honest
+                out = batcher_mod.dispatch(
+                    key, qv[0], launch_fused,
+                    shards=1, rank=k_bucket,
+                    alt_keys=alt_keys,
+                    family="knn_fused_pallas",
+                    tune_key=("knn_fused_pallas",
+                              id(self.mapper_service), node.field,
+                              k_bucket))
+                vals, ids = out.value
+                if prof is not None:
+                    # a batched operator owns its SHARE of the fenced
+                    # kernel wall (merged launches split evenly)
+                    prof.record_kernel(
+                        "knn_fused_pallas", out.kernel_share_ns,
+                        int(qv.nbytes), out.retraced,
+                        annotations={
+                            "score_precision": score_precision,
+                            "kernel": impl,
+                        },
+                    )
+                scores = np.full(n_pad, -np.inf, np.float32)
+                hit = ids >= 0
+                scores[ids[hit]] = vals[hit]
+                _count_knn_path("fused")
             per_seg_scores.append(scores)
             n_take = min(node.k, host.n_docs)
             top = np.argpartition(-scores[: host.n_docs], min(n_take, host.n_docs - 1))[:n_take]

@@ -7,7 +7,7 @@ decision as achieved-vs-peak FLOP/s on the roofline, and ANNS-AMP (arxiv
 memory-bound. Until now the profiler recorded fenced wall time and
 transfer bytes but nothing converted them into achieved FLOP/s, bytes/s,
 or arithmetic intensity — so nobody could even explain why the int8 ADC
-path is SLOWER than fp32 (204 vs 296 QPS, BENCH_ANN.json), let alone rank
+path achieves less than fp32 through the XLA lowering, let alone rank
 which kernel family a Pallas rewrite would buy the most on.
 
 Three pieces close the gap:
@@ -45,7 +45,7 @@ most wall-clock sits furthest under the achievable ceiling is the one a
 kernel swap buys the most on.
 
 Accounting identity (checked by the soak's ``roofline-bounded``
-invariant and the bench gate): ``accounted_flops == Σ per-family model
+invariant): ``accounted_flops == Σ per-family model
 FLOPs`` at all times — a launch is either folded into exactly one family
 row or counted in ``unmodeled_launches``, never both, never dropped.
 
@@ -121,17 +121,6 @@ def _model_knn_raw(p: dict) -> tuple[int, int]:
     return flops, nbytes
 
 
-def _model_knn_streaming(p: dict) -> tuple[int, int]:
-    """Streaming top-k scan (ops/fused.knn_topk_streaming): the same
-    matmul work plus a running [B,k] merge per element, but the [B,n]
-    score row NEVER lands in HBM — only the [B,k] winners come back.
-    FLOPs = 2·B·n·d + 6·B·n; bytes = corpus + norms + queries + B·k·8."""
-    b, n, d, k = int(p["b"]), int(p["n"]), int(p["d"]), int(p["k"])
-    flops = 2 * b * n * d + 6 * b * n
-    nbytes = _F32 * (n * d + n + b * d) + _IDX * b * k
-    return flops, nbytes
-
-
 def _model_ivfpq(p: dict) -> tuple[int, int]:
     """IVF-PQ fused search: coarse quantize + per-probe LUT build + ADC
     gather-accumulate + exact fp32 rescore (ops/ivfpq.search).
@@ -186,8 +175,8 @@ def _model_ivfpq_adc_pallas(p: dict) -> tuple[int, int]:
     vectors out. The ``[B, nprobe, L_pad]`` ADC-distance intermediate and
     the per-slot LUT gather traffic of the XLA lowering (_model_ivfpq) do
     NOT exist — that delta is what the kernel swap buys, and why int8's
-    byte floor finally reaches HBM (the BENCH_ANN.json inversion
-    resolved)."""
+    byte floor finally reaches HBM (the ``ivfpq_search[int8]``
+    inversion resolved)."""
     b = int(p["b"])
     nlist, d, m, ks = int(p["nlist"]), int(p["d"]), int(p["m"]), int(p["ks"])
     nprobe, l_pad, r = int(p["nprobe"]), int(p["l_pad"]), int(p["rescore"])
@@ -203,22 +192,6 @@ def _model_ivfpq_adc_pallas(p: dict) -> tuple[int, int]:
               + b * nprobe * m * ks * lut_entry  # LUT once, native width
               + _F32 * (b * r * d + b * d)      # rescore vecs + queries
               + _IDX * b * r)                   # [B, R] winners out
-    return flops, nbytes
-
-
-def _model_mesh(p: dict) -> tuple[int, int]:
-    """Shard-mesh kNN program (one `shard_map` launch over S shards):
-    per-slot exact scan over [S, n_flat, d] + the on-device
-    all_gather+top_k cross-shard merge. FLOPs = 2·B·S·n_flat·d +
-    4·B·S·n_flat; bytes = slabs + norms/valid + queries + all_gather
-    traffic devices·B·k_shard·8."""
-    b, s = int(p["b"]), int(p["s"])
-    n_flat, d = int(p["n_flat"]), int(p["d"])
-    k_shard = int(p["k_shard"])
-    devices = int(p.get("devices", s))
-    flops = 2 * b * s * n_flat * d + 4 * b * s * n_flat
-    nbytes = (_F32 * (s * n_flat * d + 2 * s * n_flat + b * d)
-              + _IDX * devices * b * k_shard)
     return flops, nbytes
 
 
@@ -252,12 +225,10 @@ def _fused_scan_terms(b: int, n: int, d: int, r: int,
 
 
 def _model_knn_fused(p: dict) -> tuple[int, int]:
-    """Fused blockwise exact-kNN kernel (ops/pallas_knn.knn_fused_auto,
-    family knn_fused_pallas): the [B,n] score matrix of the XLA exact
-    lowerings NEVER exists — only [B,R] winners land in HBM. That delta
-    vs _model_knn_exact's B·n term is what the kernel swap buys on the
-    materializing path; vs _model_knn_streaming the win is on-chip
-    selection width (R rounds in VMEM scratch, no per-chunk carries)."""
+    """The exact-kNN scan (ops/pallas_knn.knn_fused, family
+    knn_fused_pallas), modeled as its kernel lowering: the [B,n] score
+    matrix NEVER exists — only [B,R] winners land in HBM; that is the
+    delta vs the dense scorer's (_model_knn_exact) B·n term."""
     b, n, d = int(p["b"]), int(p["n"]), int(p["d"])
     r = int(p.get("r", p.get("k", 10)))
     precision = str(p.get("precision", "fp32"))
@@ -305,11 +276,9 @@ def _model_constant_terms(p: dict) -> tuple[int, int]:
 COST_MODELS: dict[str, Callable[[dict], tuple[int, int]]] = {
     "knn_exact_scores": _model_knn_exact,
     "knn_raw_similarity": _model_knn_raw,
-    "knn_topk_streaming": _model_knn_streaming,
     "ivfpq_search": _model_ivfpq,
     "ivfpq_adc_pallas": _model_ivfpq_adc_pallas,
     "knn_fused_pallas": _model_knn_fused,
-    "mesh_knn": _model_mesh,
     "mesh_knn_fused": _model_mesh_fused,
     "bm25_term_scores": _model_bm25,
     "constant_term_scores": _model_constant_terms,
@@ -349,19 +318,6 @@ def _adapt_constant(args: tuple, kwargs: dict) -> dict:
             "n_pad": int(_arg(args, kwargs, 4, "n_pad"))}
 
 
-def _adapt_knn_fused(args: tuple, kwargs: dict) -> dict:
-    # ops/pallas_knn.knn_fused_auto(vectors, norms_sq, valid, queries, *,
-    # k, similarity, score_precision, impl)
-    vectors, queries = args[0], args[3]
-    k = int(kwargs.get("k", 10))
-    precision = str(kwargs.get("score_precision", "fp32"))
-    from opensearch_tpu.ops.pallas_knn import fused_pool_width
-
-    return {"b": int(queries.shape[0]), "n": int(vectors.shape[0]),
-            "d": int(vectors.shape[1]), "k": k,
-            "r": fused_pool_width(k, precision), "precision": precision}
-
-
 def _adapt_adc_topr(args: tuple, kwargs: dict) -> dict:
     # ops/pallas_adc.adc_topr_auto(coarse, codebooks, codes, ids, mask,
     # vectors, norms_sq, valid, queries, probes, *, k, rerank, ...)
@@ -378,7 +334,6 @@ def _adapt_adc_topr(args: tuple, kwargs: dict) -> dict:
 _KERNEL_PARAM_ADAPTERS: dict[str, Callable[[tuple, dict], dict]] = {
     "knn_exact_scores": _adapt_knn,
     "knn_raw_similarity": _adapt_knn,
-    "knn_fused_pallas": _adapt_knn_fused,
     "ivfpq_adc_pallas": _adapt_adc_topr,
     "bm25_term_scores": _adapt_bm25,
     "constant_term_scores": _adapt_constant,
@@ -753,8 +708,7 @@ class RooflineRecorder:
                     "int8 ADC achieves less than fp32 against a SMALLER "
                     "modeled byte floor: the XLA lowering widens the "
                     "quantized LUT through the gather, so the byte saving "
-                    "never reaches HBM — the QPS inversion in "
-                    "BENCH_ANN.json. Select the fused Pallas blockwise "
+                    "never reaches HBM. Select the fused Pallas blockwise "
                     "ADC scan (search.knn.ann.kernel=pallas, ROADMAP "
                     "item 2) — it is where this precision pays.")
         return {

@@ -33,7 +33,7 @@ from opensearch_tpu.common.errors import (
     RejectedExecutionException,
 )
 from opensearch_tpu.node import TpuNode
-from opensearch_tpu.ops import fused, ivfpq
+from opensearch_tpu.ops import ivfpq, pallas_knn
 from opensearch_tpu.search import ann as ann_mod
 from opensearch_tpu.search import executor
 from opensearch_tpu.search.batcher import KnnDispatchBatcher
@@ -174,7 +174,7 @@ def test_reduced_precision_recall_parity():
     norms = jnp.sum(vecs * vecs, -1)
     valid = jnp.ones(n, bool)
     q = jnp.asarray(queries)
-    _evals, eids = fused.knn_topk(vecs, norms, valid, q, k=k)
+    _evals, eids = pallas_knn.knn_fused(vecs, norms, valid, q, k=k, impl="xla")
 
     recalls = {}
     for precision in ivfpq.ADC_PRECISIONS:
@@ -202,7 +202,7 @@ def test_wider_rescore_pool_recovers_int8_recall():
     norms = jnp.sum(vecs * vecs, -1)
     valid = jnp.ones(n, bool)
     q = jnp.asarray(_clustered(rng, 16, d, n_centers=16))
-    _evals, eids = fused.knn_topk(vecs, norms, valid, q, k=k)
+    _evals, eids = pallas_knn.knn_fused(vecs, norms, valid, q, k=k, impl="xla")
     narrow = _recall_at_k(np.asarray(ivfpq.search_index(
         idx, vecs, norms, valid, q, k=k, nprobe=8, rerank=2 * k,
         adc_precision="int8")[1]), eids, k)

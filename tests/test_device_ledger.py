@@ -109,13 +109,13 @@ class TestLedgerCore:
 
     def test_compile_accounting_per_family(self):
         led = DeviceResidencyLedger()
-        led.record_compile("knn_topk_streaming", 1000)
-        led.record_compile("knn_topk_streaming", 3000)
-        led.record_compile("mesh_knn", 500)
+        led.record_compile("knn_fused_pallas", 1000)
+        led.record_compile("knn_fused_pallas", 3000)
+        led.record_compile("mesh_knn_fused", 500)
         comp = led.compile_stats()
-        assert comp["knn_topk_streaming"] == {
+        assert comp["knn_fused_pallas"] == {
             "entries": 2, "compile_wall_ns": 4000}
-        assert comp["mesh_knn"]["entries"] == 1
+        assert comp["mesh_knn_fused"]["entries"] == 1
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ import pytest
 
 jnp = pytest.importorskip("jax.numpy")
 
-from opensearch_tpu.ops import fused, ivfpq
+from opensearch_tpu.ops import ivfpq, pallas_knn
 
 
 def _clustered(rng, n, d, n_centers=32, spread=5.0):
@@ -37,7 +37,7 @@ class TestIVFPQKernel:
         vals, ids = ivfpq.search_index(
             idx, vecs, norms, valid, q, k=k, nprobe=16, rerank=128
         )
-        evals, eids = fused.knn_topk(vecs, norms, valid, q, k=k)
+        evals, eids = pallas_knn.knn_fused(vecs, norms, valid, q, k=k, impl="xla")
         ids, eids = np.asarray(ids), np.asarray(eids)
         recall = np.mean(
             [len(set(ids[i]) & set(eids[i])) / k for i in range(len(queries))]
@@ -154,9 +154,9 @@ class TestIVFPQEngine:
             idx, vecs, norms, valid, jnp.asarray(data[:4]),
             k=5, nprobe=16, similarity="cosinesimil",
         )
-        evals, eids = fused.knn_topk(
-            vecs, norms, valid, jnp.asarray(data[:4]), k=5, similarity="cosine"
-        )
+        evals, eids = pallas_knn.knn_fused(
+            vecs, norms, valid, jnp.asarray(data[:4]), k=5,
+            similarity="cosine", impl="xla")
         assert np.array_equal(np.asarray(ids)[:, 0], np.asarray(eids)[:, 0])
         assert np.allclose(np.asarray(vals)[:, 0], np.asarray(evals)[:, 0], atol=1e-3)
 

@@ -36,8 +36,6 @@ def node(tmp_path, monkeypatch):
     # force the shard-level scan paths onto the tiny corpus and keep the
     # distributed bundle out of the way unless a test re-enables it
     monkeypatch.setattr(distributed_serving, "enabled", False)
-    monkeypatch.setattr(executor, "STREAMING_MIN_DOCS", 8)
-    monkeypatch.setattr(executor, "STREAMING_CHUNK", 32)
     n = TpuNode(tmp_path / "node")
     n.create_index("v", {
         "settings": {"number_of_shards": 1},
@@ -109,13 +107,13 @@ def test_concurrent_searches_coalesce_bit_identical(node):
     node.knn_batcher.configure(enabled=True, max_batch_size=B,
                                max_wait_ms=2000)
     node.knn_batcher.reset()
-    s0 = executor.knn_path_stats["streaming"]
+    s0 = executor.knn_path_stats["fused"]
     out = _concurrent_search(node, [_knn_body(q) for q in qs])
 
     st = node.knn_batcher.snapshot_stats()
     assert st["dispatches"] <= math.ceil(K / B)
     assert st["merged_queries"] == K
-    assert executor.knn_path_stats["streaming"] > s0
+    assert executor.knn_path_stats["fused"] > s0
     for got, want in zip(out, ref):
         # BIT-identical: same ids AND float-equal scores vs unbatched
         assert _hits(got) == _hits(want)
@@ -168,17 +166,19 @@ def test_steady_state_batches_report_not_retraced(node):
     snap = node.indices["v"].shards[0].acquire_searcher()
     vf = snap.segments[0][1].vector_fields["x"]
     k_bucket = 8  # k=5 -> next power of two
-    chunk = min(32, snap.segments[0][1].n_pad)
-    from opensearch_tpu.ops import fused, knn as knn_ops
+    from opensearch_tpu.ops import knn as knn_ops, pallas_knn
 
-    jfn = fused.cached_knn_streaming(
-        k_bucket, knn_ops.canonical_similarity(vf.similarity), chunk)
+    sim = knn_ops.canonical_similarity(vf.similarity)
+    impl, interpret = pallas_knn.fused_impl("auto", k_bucket)
     valid = vf.present & snap.segments[0][1].live
     for b in (1, 2, 4, 8):
         q = np.zeros((b, DIM), np.float32)
-        np.asarray(jfn(vf.vectors, vf.norms_sq, valid, q)[0])
+        np.asarray(pallas_knn.knn_fused(
+            vf.vectors, vf.norms_sq, valid, q, k=k_bucket, similarity=sim,
+            score_precision="fp32", impl=impl, interpret=interpret)[0])
         profile.signature_retraced(
-            "knn_topk_streaming", (vf.vectors, q), (k_bucket, chunk))
+            "knn_fused_pallas", (vf.vectors, q),
+            (k_bucket, sim, "fp32", impl, interpret))
 
     out = _concurrent_search(
         node, [_knn_body(q, profile=True) for q in _queries(K)])

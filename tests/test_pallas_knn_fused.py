@@ -1,7 +1,14 @@
-"""Fused exact-kNN Pallas kernel (ISSUE 19): per-precision parity, the
-mesh one-launch-per-node program, and the exact-path kernel policy.
+"""The one exact-kNN scan, `ops/pallas_knn.knn_fused` (ISSUE 19; the only
+one since ISSUE 31): both lowerings against a numpy reference and each
+other, the one rule that picks between them, the mesh
+one-launch-per-node program, and the exact-path kernel policy.
 
 Acceptance properties:
+ - `knn_fused(impl="xla")`, what serves the CPU backend and k >
+   FUSED_MAX_K, equals a plain numpy reference for the three similarities,
+   on ties (lower doc id first) and with fewer live docs than k;
+ - `fused_impl(policy, k)` is the only place the platform picks a
+   lowering, and both serving paths obey its k cap;
  - interpret-mode parity vs the XLA reference per score precision: int8
    pools are BIT-identical (integer matmul + scalar dequant), fp32/bf16
    ids identical with scores equal to summation order, and every reduced
@@ -11,7 +18,7 @@ Acceptance properties:
    the XLA path bit for bit;
  - the shard_map serving program (parallel/distributed) returns identical
    vals/gids/counts for kernel="pallas" vs the XLA reference at 1/2/4
-   devices, and the fp32 fused program equals the legacy einsum program;
+   devices, and equals `knn_fused` per shard + a numpy merge;
  - ``search.knn.kernel`` / ``search.knn.score_precision`` round-trip
    /_cluster/settings with validation + None-deletion, apply live, ride
    the dispatch batch key (no cross-kernel merges), and serve through the
@@ -20,7 +27,11 @@ Acceptance properties:
 
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
 import threading
+import types
 
 import numpy as np
 import pytest
@@ -30,7 +41,7 @@ import jax
 
 from opensearch_tpu.common.errors import IllegalArgumentException
 from opensearch_tpu.node import TpuNode
-from opensearch_tpu.ops import fused, pallas_knn
+from opensearch_tpu.ops import pallas_knn
 from opensearch_tpu.search import ann as ann_mod
 from opensearch_tpu.search import distributed_serving
 from opensearch_tpu.search import executor as executor_mod
@@ -98,6 +109,125 @@ def _assert_pallas_matches_xla(vecs, norms, valid, queries, precision,
         assert np.allclose(pv, xv, atol=1e-6, equal_nan=True)
 
 
+def _numpy_topk(vectors, valid, queries, k, similarity="l2_norm"):
+    """Plain float64 reference of the contract: serving-space scores, the
+    k best by (-score, doc id), (-inf, -1) past the live docs."""
+    v = np.asarray(vectors, np.float64)
+    q = np.asarray(queries, np.float64)
+    dots = q @ v.T
+    if similarity == "l2_norm":
+        d_sq = ((q * q).sum(1)[:, None] - 2.0 * dots
+                + (v * v).sum(1)[None, :])
+        scores = 1.0 / (1.0 + np.maximum(d_sq, 0.0))
+    elif similarity == "cosine":
+        norm = (np.sqrt((q * q).sum(1))[:, None]
+                * np.sqrt((v * v).sum(1))[None, :])
+        scores = (1.0 + dots / np.maximum(norm, 1e-12)) / 2.0
+    else:
+        scores = np.where(dots >= 0, dots + 1.0, 1.0 / (1.0 - dots))
+    scores = np.where(np.asarray(valid)[None, :], scores, -np.inf)
+    n = scores.shape[1]
+    if n < k:
+        scores = np.pad(scores, ((0, 0), (0, k - n)),
+                        constant_values=-np.inf)
+    ids = np.argsort(-scores, axis=1, kind="stable")[:, :k]
+    vals = np.take_along_axis(scores, ids, axis=1)
+    return vals, np.where(np.isfinite(vals), ids, -1)
+
+
+def _xla_twin(vecs, norms, valid, queries, k, similarity="l2_norm"):
+    return map(np.asarray, pallas_knn.knn_fused(
+        vecs, norms, valid, queries, k=k, similarity=similarity,
+        score_precision="fp32", impl="xla", interpret=False))
+
+
+# ---------------------------------------------------------------------------
+# the XLA twin against the numpy reference: what serves the CPU backend and
+# k > FUSED_MAX_K (the cases the deleted streaming / materializing lowerings
+# were held to)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("similarity", SIMS)
+def test_xla_twin_matches_numpy_reference(similarity):
+    rng = np.random.default_rng(1)
+    n, d = 3000, DIM
+    vecs = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
+    norms = jnp.sum(vecs * vecs, axis=1)
+    valid = np.ones(n, bool)
+    valid[rng.choice(n, 40, replace=False)] = False
+    queries = jnp.asarray(rng.standard_normal((7, d)).astype(np.float32))
+    got_v, got_i = _xla_twin(vecs, norms, jnp.asarray(valid), queries, 5,
+                             similarity)
+    ref_v, ref_i = _numpy_topk(vecs, valid, queries, 5, similarity)
+    assert np.array_equal(got_i, ref_i)
+    np.testing.assert_allclose(got_v, ref_v, rtol=1e-5)
+
+
+def test_xla_twin_tied_rows_tiles_apart_keep_the_lower_doc_id():
+    """Two bit-equal rows more than one kernel tile apart tie exactly: the
+    lower doc id ranks first, as the host merge's (-score, doc) order has
+    it."""
+    rng = np.random.default_rng(3)
+    d = 8
+    tile = _rule_tile(5, d, "fp32")
+    n = 2 * tile + 100
+    data = rng.standard_normal((n, d)).astype(np.float32)
+    lo, hi = 17, tile + tile // 2 + 17
+    assert hi - lo > tile
+    data[hi] = data[lo]
+    vecs = jnp.asarray(data)
+    norms = jnp.sum(vecs * vecs, axis=1)
+    valid = np.ones(n, bool)
+    queries = np.repeat(data[lo][None, :], 5, axis=0)
+    queries[1:] += rng.standard_normal((4, d)).astype(np.float32) * 0.01
+    got_v, got_i = _xla_twin(vecs, norms, jnp.asarray(valid),
+                             jnp.asarray(queries), 10)
+    ref_v, ref_i = _numpy_topk(vecs, valid, queries, 10)
+    assert np.array_equal(got_i, ref_i)
+    first = got_i[0].tolist()
+    assert first[:2] == [lo, hi]
+    assert got_v[0, 0] == got_v[0, 1]
+
+
+def test_xla_twin_fewer_live_docs_than_k():
+    vecs = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (3, 4)).astype(np.float32))
+    norms = jnp.sum(vecs * vecs, axis=1)
+    valid = np.ones(3, bool)
+    queries = jnp.asarray(np.ones((2, 4), np.float32))
+    got_v, got_i = _xla_twin(vecs, norms, jnp.asarray(valid), queries, 8)
+    ref_v, ref_i = _numpy_topk(vecs, valid, queries, 8)
+    assert got_v.shape == (2, 8) and got_i.shape == (2, 8)
+    assert np.array_equal(got_i, ref_i)
+    assert np.all(got_i[:, 3:] == -1) and np.all(np.isneginf(got_v[:, 3:]))
+    np.testing.assert_allclose(got_v[:, :3], ref_v[:, :3], rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the one rule: policy x platform x k against the kernel's cap
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("k", (pallas_knn.FUSED_MAX_K,
+                               pallas_knn.FUSED_MAX_K + 1))
+@pytest.mark.parametrize("platform", ("tpu", "cpu"))
+@pytest.mark.parametrize("policy", ("auto", "pallas", "xla"))
+def test_fused_impl_rule(monkeypatch, policy, platform, k):
+    """The kernel when the policy forces it or is "auto" on a TPU, and k is
+    within its cap; else the XLA twin; interpret only for a forced kernel
+    on the CPU backend."""
+    monkeypatch.setattr(pallas_knn, "jax", types.SimpleNamespace(
+        devices=lambda: [types.SimpleNamespace(platform=platform)]))
+    wants_kernel = policy == "pallas" or (policy == "auto"
+                                          and platform == "tpu")
+    if wants_kernel and k <= pallas_knn.FUSED_MAX_K:
+        want = ("pallas", platform == "cpu")
+    else:
+        want = ("xla", False)
+    assert pallas_knn.fused_impl(policy, k) == want
+
+
 # ---------------------------------------------------------------------------
 # interpret-mode parity vs the XLA reference, per precision x similarity
 # ---------------------------------------------------------------------------
@@ -114,20 +244,18 @@ def test_fused_parity_interpret_vs_xla(precision, similarity):
 
 @pytest.mark.parametrize("precision", PRECISIONS)
 def test_fused_recall_vs_exact_reference(precision):
-    """fp32 must reproduce ops/fused.knn_topk exactly; the reduced
+    """fp32 must reproduce the numpy reference exactly; the reduced
     precisions widen the pool then rescore in exact fp32, holding
-    recall@10 == 1.0 on the clustered corpus (the --fused-knn bench
-    gate's recall floor, asserted here on the CPU sim)."""
+    recall@10 == 1.0 on the clustered corpus."""
     rng = np.random.default_rng(11)
-    vecs, norms, valid, queries, _ = _operands(rng)
-    ev, ei = map(np.asarray, fused.knn_topk(
-        vecs, norms, valid, queries, k=10, similarity="l2_norm"))
+    vecs, norms, valid, queries, valid_np = _operands(rng)
+    ev, ei = _numpy_topk(vecs, valid_np, queries, 10)
     fv, fi = map(np.asarray, pallas_knn.knn_fused(
         vecs, norms, valid, queries, k=10, similarity="l2_norm",
         score_precision=precision, impl="pallas", interpret=True))
     if precision == "fp32":
         assert np.array_equal(fi, ei)
-        assert np.allclose(fv, ev, rtol=1e-6)
+        assert np.allclose(fv, ev, rtol=1e-5)
     else:
         recall = np.mean([
             len(set(fi[b]) & set(ei[b])) / 10 for b in range(fi.shape[0])])
@@ -374,8 +502,7 @@ def _mesh_inputs(rng, s, n, d, b):
 def test_mesh_fused_parity_across_shard_counts(n_dev):
     """build_knn_serving_step with kernel="pallas" (interpret on the CPU
     sim) and the XLA reference agree bit for bit on vals/gids/counts at
-    every device count, at every precision; the fp32 fused program also
-    equals the legacy einsum program exactly."""
+    every device count, at every precision."""
     from jax.sharding import Mesh
 
     from opensearch_tpu.parallel import distributed as dist_mod
@@ -386,15 +513,11 @@ def test_mesh_fused_parity_across_shard_counts(n_dev):
     s, n, d, b = 4, 256, DIM, 8
     vectors, norms, valid, queries = _mesh_inputs(rng, s, n, d, b)
     mesh = Mesh(devices, ("data",))
-    legacy = dist_mod.build_knn_serving_step(
-        mesh, k_shard=8, k_final=10, similarity="l2")
-    lv, lg, lc = dist_mod.unpack(
-        legacy(vectors, norms, valid, queries), 10, s)
     for precision in PRECISIONS:
         out = {}
         for kernel in ("pallas", "xla"):
             step = dist_mod.build_knn_serving_step(
-                mesh, k_shard=8, k_final=10, similarity="l2",
+                mesh, k_shard=8, k_final=10, similarity="l2_norm",
                 kernel=kernel, score_precision=precision,
                 interpret=True)
             out[kernel] = dist_mod.unpack(
@@ -407,47 +530,23 @@ def test_mesh_fused_parity_across_shard_counts(n_dev):
             assert np.array_equal(pv, xv), n_dev
         else:
             assert np.allclose(pv, xv, atol=1e-6), (n_dev, precision)
-        if precision == "fp32":
-            assert np.array_equal(pg, lg), n_dev
-            assert np.allclose(pv, lv, rtol=1e-6), n_dev
-            assert np.array_equal(pc, lc), n_dev
-
-
-@jax.jit
-def _plain_l2_scores(vectors, norms, valid, queries):
-    # the step's default scoring (kernel "xla", fp32), whole stack at once
-    # and compiled: op by op XLA's CPU backend rounds it 2 ulp apart
-    dots = jnp.einsum(
-        "bd,snd->sbn", queries, vectors,
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)
-    q_sq = jnp.sum(queries * queries, axis=-1)[None, :, None]
-    d_sq = jnp.maximum(q_sq - 2.0 * dots + norms[:, None, :], 0.0)
-    return jnp.where(valid[:, None, :], 1.0 / (1.0 + d_sq), -jnp.inf)
 
 
 def _three_output_reference(vectors, norms, valid, queries, *, k_shard,
-                            k_final, kernel, precision):
-    """What the step computes, plainly: every shard scanned by the scan
-    the step names, one after the other on one device, the merge on the
-    host in (-score, shard, rank) order. No mesh, no pack."""
+                            k_final, kernel, precision,
+                            similarity="l2_norm"):
+    """What the step computes, plainly: every shard scanned by `knn_fused`,
+    one after the other on one device, the merge on the host in (-score,
+    shard, rank) order. No mesh, no pack."""
     s, n = valid.shape
-    plain = (kernel, precision) == ("xla", "fp32")
-    if plain:
-        scores = _plain_l2_scores(vectors, norms, valid, queries)
     per_v, per_g = [], []
     for si in range(s):
-        if plain:
-            v, i = map(np.asarray, jax.lax.top_k(scores[si], k_shard))
-            g = i + si * n
-        else:
-            v, i = map(np.asarray, pallas_knn.knn_fused_shard(
-                vectors[si], norms[si], valid[si], queries, k=k_shard,
-                similarity="l2_norm", score_precision=precision,
-                impl=kernel, interpret=True))
-            g = np.where(i >= 0, i + si * n, -1)
+        v, i = map(np.asarray, pallas_knn.knn_fused(
+            vectors[si], norms[si], valid[si], queries, k=k_shard,
+            similarity=similarity, score_precision=precision,
+            impl=kernel, interpret=kernel == "pallas"))
         per_v.append(v)
-        per_g.append(g.astype(np.int32))
+        per_g.append(np.where(i >= 0, i + si * n, -1).astype(np.int32))
     counts = np.stack([np.isfinite(v).sum(axis=-1) for v in per_v])
     all_v = np.concatenate(per_v, axis=1)              # [B, S * k_shard]
     all_g = np.concatenate(per_g, axis=1)
@@ -507,8 +606,41 @@ def test_mesh_step_hands_back_one_packed_array(kernel, precision, n_dev):
         lone(vectors[1:2], norms[1:2], valid[1:2], queries), k_shard, 1)
     assert np.isneginf(lv[:, 3:]).all() and np.isfinite(lv[:, :3]).all()
     assert (lc == 3).all()
-    if (kernel, precision) != ("xla", "fp32"):
-        assert (lg[:, 3:] == -1).all()
+    assert (lg[:, 3:] == -1).all()
+
+
+@pytest.mark.parametrize("similarity", SIMS)
+def test_mesh_step_on_four_devices_is_knn_fused_per_shard(similarity):
+    """The program the CPU tests run is the one the chip runs, with the
+    lowering the rule gives here: on 4 virtual devices it equals `knn_fused`
+    shard by shard + a numpy merge, bit for bit, in every score space."""
+    from jax.sharding import Mesh
+
+    from opensearch_tpu.parallel import distributed as dist_mod
+
+    impl, interpret = pallas_knn.fused_impl("auto", 8)
+    rng = np.random.default_rng(41)
+    s, n, b, k_shard, k_final = 4, 256, 4, 8, 10
+    vectors, norms, valid, queries = _mesh_inputs(rng, s, n, DIM, b)
+    step = dist_mod.build_knn_serving_step(
+        Mesh(np.array(jax.devices()[:4]), ("data",)), k_shard=k_shard,
+        k_final=k_final, similarity=similarity, kernel=impl,
+        score_precision="fp32", interpret=interpret)
+    got = dist_mod.unpack(step(vectors, norms, valid, queries), k_final, s)
+    want = _three_output_reference(
+        vectors, norms, valid, queries, k_shard=k_shard, k_final=k_final,
+        kernel=impl, precision="fp32", similarity=similarity)
+    for name, g, w in zip(("vals", "gids", "counts"), got, want):
+        assert np.ascontiguousarray(g).tobytes() == w.tobytes(), name
+    # and the scan it wraps is right: shard 2's winners against numpy
+    ref_v, ref_i = _numpy_topk(vectors[2], np.asarray(valid[2]), queries,
+                               k_shard, similarity)
+    sv, si = map(np.asarray, pallas_knn.knn_fused(
+        vectors[2], norms[2], valid[2], queries, k=k_shard,
+        similarity=similarity, score_precision="fp32", impl=impl,
+        interpret=interpret))
+    assert np.array_equal(si, ref_i)
+    np.testing.assert_allclose(sv, ref_v, rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -637,6 +769,89 @@ def test_served_fused_path_accounting(exact_node):
         distributed_serving.enabled = True
 
 
+def _brute_ids(data, query, k, keep=None):
+    d_sq = ((data.round(3).astype(np.float64) - np.asarray(query)) ** 2
+            ).sum(1)
+    if keep is not None:
+        d_sq = np.where(keep, d_sq, np.inf)
+    return [str(i) for i in np.argsort(d_sq, kind="stable")[:k]]
+
+
+def test_executor_serves_every_exact_query_through_the_fused_scan(exact_node):
+    """Default policy, per-shard path, a segment of any size, k under and
+    over the kernel's cap: the one scan serves it (nothing else is left to),
+    and the hits are brute force's."""
+    data = exact_node._test_data
+    distributed_serving.enabled = False
+    try:
+        for k in (7, 150):
+            query = data[9].round(3).tolist()
+            before = dict(executor_mod.knn_path_stats)
+            resp = exact_node.search("ex", {"size": k, "query": {
+                "knn": {"x": {"vector": query, "k": k}}}})
+            after = executor_mod.knn_path_stats
+            assert set(after) == {"ann", "fused"}
+            assert after["fused"] == before["fused"] + 1
+            assert after["ann"] == before["ann"]
+            assert [h["_id"] for h in resp["hits"]["hits"]] == \
+                _brute_ids(data, query, k)
+    finally:
+        distributed_serving.enabled = True
+
+
+def test_executor_fused_scan_honours_the_knn_filter(exact_node):
+    """The filter's mask is folded into `valid` BEFORE the top-k: every hit
+    passes it and the hits are brute force's over the rows that pass."""
+    data = exact_node._test_data
+    exact_node.create_index("fx", {
+        "settings": {"number_of_shards": 1},
+        "mappings": {"properties": {
+            "x": {"type": "knn_vector", "dimension": DIM},
+            "n": {"type": "long"}}},
+    })
+    exact_node.bulk([
+        ("index", {"_index": "fx", "_id": str(i)},
+         {"x": data[i].round(3).tolist(), "n": i})
+        for i in range(64)
+    ], refresh=True)
+    query = data[40].round(3).tolist()
+    body = {"size": 5, "query": {"knn": {"x": {
+        "vector": query, "k": 5,
+        "filter": {"range": {"n": {"lt": 20}}}}}}}
+    distributed_serving.enabled = False
+    try:
+        fused_before = executor_mod.knn_path_stats["fused"]
+        resp = exact_node.search("fx", body)
+        assert executor_mod.knn_path_stats["fused"] == fused_before + 1
+    finally:
+        distributed_serving.enabled = True
+    hits = resp["hits"]["hits"]
+    assert all(h["_source"]["n"] < 20 for h in hits)
+    assert [h["_id"] for h in hits] == \
+        _brute_ids(data[:64], query, 5, keep=np.arange(64) < 20)
+
+
+def test_mesh_path_obeys_the_kernel_k_cap(exact_node):
+    """search.knn.kernel = pallas over the mesh: k within FUSED_MAX_K
+    launches the kernel, k above it the XLA twin (the cap the per-shard
+    path always had; before ISSUE 31 the mesh compiled a k-round merge)."""
+    from opensearch_tpu.cluster.shard_mesh import default_registry
+
+    data = exact_node._test_data
+    exact_node.put_cluster_settings({"persistent": {"search": {"knn": {
+        "kernel": "pallas"}}}})
+    query = data[3].round(3).tolist()
+    for k, want in ((10, "pallas"), (pallas_knn.FUSED_MAX_K + 22, "xla")):
+        launches = default_registry.snapshot_stats()["launches"]
+        resp = exact_node.search("ex", {"size": k, "query": {
+            "knn": {"x": {"vector": query, "k": k}}}})
+        st = default_registry.snapshot_stats()
+        assert st["launches"] == launches + 1, "not served by the mesh"
+        assert st["last_kernel"] == want, k
+        assert [h["_id"] for h in resp["hits"]["hits"]] == \
+            _brute_ids(data, query, k)
+
+
 def test_mesh_serving_uses_fused_family_under_policy(exact_node):
     """A multi-shard knn search with kernel=pallas runs the fused
     shard_map program: hits identical to the host merge, the
@@ -761,3 +976,23 @@ def test_cost_models_rank_fused_families_with_nonzero_fractions():
     int8 = rows["knn_fused_pallas[int8]"]
     fp32 = rows["knn_fused_pallas[fp32]"]
     assert int8["bytes"] > fp32["bytes"]
+
+
+# ---------------------------------------------------------------------------
+# the driver contract points at the served program
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("call", ("check_entry()", "dryrun_multichip(4)"))
+def test_graft_entry_runs_the_served_program(call):
+    """`entry()` jits (knn_fused, the platform's lowering) and the dry run
+    serves a 4-shard search over 4 virtual CPU devices; each in a child of
+    its own, as `__graft_entry__`'s docstring prescribes."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", f"import __graft_entry__ as g; g.{call}"],
+        cwd=root, capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "JAX_NUM_CPU_DEVICES": "4"})
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert " OK" in done.stdout

@@ -63,12 +63,13 @@ class TestCostModels:
         assert flops == 160          # 2·2·8·4 + 2·2·8
         assert nbytes == 256
 
-    def test_streaming_scan_returns_only_winners(self):
-        flops, nbytes = COST_MODELS["knn_topk_streaming"](
-            {"b": 2, "n": 8, "d": 4, "k": 3})
+    def test_fused_scan_returns_only_winners(self):
+        flops, nbytes = COST_MODELS["knn_fused_pallas"](
+            {"b": 2, "n": 8, "d": 4, "k": 3, "r": 3})
         assert flops == 224          # 2·2·8·4 + 6·2·8
-        # corpus + norms + queries stream; only [B,k] (f32,i32) rows back
-        assert nbytes == 4 * (32 + 8 + 8) + 8 * 2 * 3
+        # corpus + norms + valid + queries stream; only [B,r] (f32,i32)
+        # rows back
+        assert nbytes == 4 * (32 + 2 * 8 + 8) + 8 * 2 * 3
 
     def test_ivfpq_per_precision(self):
         params = {"b": 2, "nlist": 4, "d": 8, "m": 2, "ks": 16,
@@ -92,11 +93,14 @@ class TestCostModels:
         assert byi8 < bybf < by32
 
     def test_mesh_launch(self):
-        flops, nbytes = COST_MODELS["mesh_knn"](
+        flops, nbytes = COST_MODELS["mesh_knn_fused"](
             {"b": 2, "s": 2, "n_flat": 8, "d": 4, "k_shard": 3,
              "devices": 2})
-        assert flops == 2 * 2 * 2 * 8 * 4 + 4 * 2 * 2 * 8
-        assert nbytes == 4 * (2 * 8 * 4 + 2 * 2 * 8 + 2 * 4) + 8 * 2 * 2 * 3
+        # S fused scans (each: matmul + transform/merge; column + norms +
+        # valid + queries in, [B,r] winners out) + the all_gather traffic
+        assert flops == 2 * (2 * 2 * 8 * 4 + 6 * 2 * 8)
+        assert nbytes == (2 * (4 * (8 * 4 + 2 * 8 + 2 * 4) + 8 * 2 * 3)
+                          + 8 * 2 * 2 * 3)
 
     def test_bm25_postings_scan(self):
         flops, nbytes = COST_MODELS["bm25_term_scores"](
@@ -112,13 +116,15 @@ class TestCostModels:
 
     def test_base_family_strips_variant(self):
         assert base_family("ivfpq_search[int8]") == "ivfpq_search"
-        assert base_family("mesh_knn") == "mesh_knn"
+        assert base_family("mesh_knn_fused[fp32]") == "mesh_knn_fused"
+        assert base_family("knn_fused_pallas") == "knn_fused_pallas"
 
     def test_every_repo_launch_site_family_is_registered(self):
         # the TPU015 contract, asserted dynamically too: every family the
         # serving tier records has a model
         for family in ("knn_exact_scores", "knn_raw_similarity",
-                       "knn_topk_streaming", "ivfpq_search", "mesh_knn",
+                       "knn_fused_pallas", "ivfpq_search",
+                       "ivfpq_adc_pallas", "mesh_knn_fused",
                        "bm25_term_scores", "constant_term_scores"):
             assert family in KNOWN_FAMILIES
 
@@ -159,8 +165,8 @@ class TestRecorder:
         row = rec.snapshot_stats()["families"]["knn_exact_scores"]
         assert row["roofline_fraction"] == 1.0
         # and a truthfully tiny one stays strictly positive
-        rec.record("mesh_knn", 10**12, flops=1, nbytes=1)
-        row = rec.snapshot_stats()["families"]["mesh_knn"]
+        rec.record("mesh_knn_fused", 10**12, flops=1, nbytes=1)
+        row = rec.snapshot_stats()["families"]["mesh_knn_fused"]
         assert 0.0 < row["roofline_fraction"] <= 1.0
 
     def test_model_driven_record_uses_params(self, stubbed_peaks):
@@ -172,9 +178,9 @@ class TestRecorder:
 
     def test_ewma_tracks_recent_launches(self, stubbed_peaks):
         rec = RooflineRecorder()
-        rec.record("mesh_knn", 1_000_000_000, flops=100, nbytes=10)
-        rec.record("mesh_knn", 1_000_000_000, flops=300, nbytes=10)
-        fam = rec.snapshot_stats()["families"]["mesh_knn"]
+        rec.record("mesh_knn_fused", 1_000_000_000, flops=100, nbytes=10)
+        rec.record("mesh_knn_fused", 1_000_000_000, flops=300, nbytes=10)
+        fam = rec.snapshot_stats()["families"]["mesh_knn_fused"]
         # 0.7·100 + 0.3·300 = 160 flops/s
         assert fam["ewma_gflops"] == pytest.approx(160 / 1e9, rel=1e-3)
         assert fam["achieved_gflops"] == pytest.approx(200 / 1e9, rel=1e-3)
@@ -184,7 +190,7 @@ class TestRecorder:
         for i in range(5):
             rec.record("knn_exact_scores", 1000 + i,
                        params={"b": 1 + i, "n": 16, "d": 4})
-        rec.record("mesh_knn", 2000, flops=77, nbytes=11)
+        rec.record("mesh_knn_fused", 2000, flops=77, nbytes=11)
         snap = rec.snapshot_stats()
         assert snap["identity_ok"]
         total = sum(r["flops"] for r in snap["families"].values())
@@ -226,19 +232,19 @@ class TestRecorder:
         rec = RooflineRecorder()
         # same fraction shape, very different cumulative wall: the family
         # with more wall under the roofline loses more
-        rec.record("mesh_knn", 10_000_000_000, flops=100, nbytes=100)
+        rec.record("mesh_knn_fused", 10_000_000_000, flops=100, nbytes=100)
         rec.record("bm25_term_scores", 1_000_000_000, flops=10, nbytes=10)
         report = rec.report()
         assert [r["family"] for r in report["families"]] == \
-            ["mesh_knn", "bm25_term_scores"]
-        assert report["top_offender"] == "mesh_knn"
+            ["mesh_knn_fused", "bm25_term_scores"]
+        assert report["top_offender"] == "mesh_knn_fused"
         assert report["identity_ok"]
 
     def test_report_explains_int8_inversion(self, stubbed_peaks):
         rec = RooflineRecorder()
         params = {"b": 8, "nlist": 16, "d": 32, "m": 8, "ks": 16,
                   "nprobe": 4, "l_pad": 16, "rescore": 32}
-        # fp32 fast, int8 SLOW on the same work (the BENCH_ANN inversion)
+        # fp32 fast, int8 SLOW on the same work (the int8 inversion)
         rec.record("ivfpq_search[fp32]", 1_000_000,
                    params={**params, "adc_precision": "fp32"})
         rec.record("ivfpq_search[int8]", 5_000_000,
@@ -253,7 +259,7 @@ class TestRecorder:
 
     def test_reset(self, stubbed_peaks):
         rec = RooflineRecorder()
-        rec.record("mesh_knn", 1000, flops=1, nbytes=1)
+        rec.record("mesh_knn_fused", 1000, flops=1, nbytes=1)
         rec.reset()
         snap = rec.snapshot_stats()
         assert snap["families"] == {}
@@ -395,11 +401,11 @@ def warm_node(tmp_path):
     distributed_serving.enabled = False
     try:
         for _ in range(3):
-            knn("ex")                      # knn_exact_scores
+            knn("ex")                      # knn_fused_pallas[fp32]
     finally:
         distributed_serving.enabled = True
     for _ in range(3):
-        knn("m2")                          # mesh_knn
+        knn("m2")                          # mesh_knn_fused[fp32]
     for precision in ("fp32", "bf16", "int8"):
         ann_mod.default_config.configure(adc_precision=precision)
         for _ in range(3):
@@ -424,7 +430,8 @@ class TestRestSurfaces:
         assert losses == sorted(losses, reverse=True)
         assert report["top_offender"] == rows[0]["family"]
         names = {r["family"] for r in rows}
-        assert {"knn_exact_scores", "mesh_knn", "bm25_term_scores",
+        assert {"knn_fused_pallas[fp32]", "mesh_knn_fused[fp32]",
+                "bm25_term_scores",
                 "ivfpq_search[fp32]", "ivfpq_search[int8]"} <= names
         for r in rows:
             assert 0.0 < r["roofline_fraction"] <= 1.0, r
@@ -440,7 +447,7 @@ class TestRestSurfaces:
         section = resp["nodes"]["node-0"]["roofline"]
         assert section["identity_ok"]
         assert section["peaks"]["source"] == "stub"
-        assert "mesh_knn" in section["families"]
+        assert "mesh_knn_fused[fp32]" in section["families"]
 
     def test_nodes_stats_metric_filter_accepts_roofline(self, warm_node):
         status, resp = _handle(warm_node, "GET", "/_nodes/stats/roofline")
@@ -460,7 +467,8 @@ class TestRestSurfaces:
         for ln in frac_lines:
             value = float(ln.rsplit(" ", 1)[1])
             assert 0.0 < value <= 1.0
-        assert any('family="mesh_knn"' in ln for ln in frac_lines)
+        assert any('family="mesh_knn_fused[fp32]"' in ln
+                   for ln in frac_lines)
         assert "opensearch_tpu_roofline_achieved_flops{family=" in text
 
     def test_profile_rows_carry_roofline_fields(self, warm_node):
