@@ -419,8 +419,15 @@ class TpuNode:
         )
 
         self.telemetry.tracer.capture_dir = self.data_path / "telemetry"
+        # how a kNN selection reached the hits (search/executor.py):
+        # registered here so that `_nodes/stats` shows a 0 as a 0
+        knn_collect = {
+            "dense": self.telemetry.metrics.counter("knn.collect.dense"),
+            "sparse": self.telemetry.metrics.counter("knn.collect.sparse"),
+        }
         self.telemetry.tracer.capture_counters = lambda: {
             "knn_batch": dict(self.knn_batcher.stats),
+            "knn_collect": {k: c.value for k, c in knn_collect.items()},
             "device_resident_bytes": default_ledger.resident_bytes(),
             "device_resident_by_device": default_ledger.device_totals(),
             "device_backend_memory": backend_memory(),

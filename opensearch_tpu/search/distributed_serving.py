@@ -28,7 +28,8 @@ unfiltered multi-shard queries, one vector per dispatch, are lifted:
    evaluated host-side per segment (the same SegmentExecutor the host path
    uses), flattened to a [S, n_flat] mask, ANDed with the bundle's valid
    mask, and the SAME device program runs — pre-filter semantics identical
-   to the host (executor.shard_knn_selection:118). Because the host path
+   to the host (executor.ShardContext.shard_knn_selection, which ANDs the
+   filter's mask into `valid` before its launch). Because the host path
    falls back to an exact scan whenever a filter is present, ANN-indexed
    segments are also eligible when filtered.
  - SINGLE-SHARD: s == 1 runs the same program on a 1-device mesh (the

@@ -5,7 +5,9 @@ The analog of the reference's per-shard query phase
 QueryBuilder.toQuery compile step): each query node is executed against each
 segment's device arrays, producing a dense (scores[n_pad] f32, mask[n_pad]
 bool) pair; composition (bool logic) is elementwise on the VPU instead of
-Lucene's doc-at-a-time conjunction/disjunction iterators.
+Lucene's doc-at-a-time conjunction/disjunction iterators. A kNN node alone
+produces its <= k winners as short (docs, scores) arrays (HostNodeResult),
+and the dense pair only for a consumer that indexes by document.
 
 Scoring follows Lucene semantics: BM25 with shard-level stats (idf over
 summed per-segment doc freqs, avgdl over all segments — matching
@@ -25,6 +27,7 @@ import re
 import threading
 import time
 from dataclasses import dataclass, field as dc_field
+from functools import cached_property
 from typing import Any
 
 import jax
@@ -74,16 +77,19 @@ def _count_knn_path(kind: str) -> None:
         knn_path_stats[kind] += 1
 
 
-def _record_ann_metrics(nprobe: int) -> None:
-    """`knn.batch.nprobe` histogram for an ANN dispatch — recorded into the
-    EXECUTING node's registry when a request scope is active (the batcher's
-    attribution rule), else the attached sink."""
+def _knn_metrics():
+    """The EXECUTING node's registry when a request scope is active (the
+    batcher's attribution rule), else the attached sink; None without."""
     from opensearch_tpu.search import batcher as batcher_mod
-    from opensearch_tpu.telemetry.tracing import active_metrics
 
-    metrics = active_metrics() or batcher_mod.default_batcher.metrics
+    return tracing.active_metrics() or batcher_mod.default_batcher.metrics
+
+
+def _count_knn_collect(name: str) -> None:
+    """`knn.collect.dense` / `.sparse`: once a request and shard."""
+    metrics = _knn_metrics()
     if metrics is not None:
-        metrics.histogram("knn.batch.nprobe").record(nprobe)
+        metrics.counter(name).add(1)
 
 
 def _pad_query_batch(rows: list) -> np.ndarray:
@@ -131,6 +137,9 @@ class ShardContext:
         # per-query cache: knn nodes select k docs PER SHARD (k-NN plugin
         # semantics), so the top-k cut must span all segments of the shard
         self._knn_cache: dict[int, list] = {}
+        # True once a kNN selection of this request was made n_pad wide
+        # (HostNodeResult's dense view was touched)
+        self.knn_dense = False
         # query_string trees are parsed once per shard, not per segment
         self._qs_cache: dict[int, Any] = {}
 
@@ -167,24 +176,26 @@ class ShardContext:
         return fields or ["_all_absent_"]
 
     def shard_knn_selection(self, node) -> list:
-        """Per-segment (sel_mask bool[n_pad], scores f32[n_pad]) numpy pairs
-        for a KnnQuery, with the top-k cut applied across the whole shard.
+        """Per segment, the KnnQuery's winners as two short numpy arrays
+        (docs int32[w], scores f32[w]), best first, with the top-k cut
+        applied across the whole shard (w <= k over all segments together);
+        None where the segment has no such field.
 
         Every exact segment (any size, any k, filter or not) scores through
         ops/pallas_knn.knn_fused; an ANN-indexed segment under an unfiltered
-        query through IVF-PQ. Either way only the [1, k] winners come back to
-        the host, as a sparse -inf-based score array."""
+        query through IVF-PQ. Either way only a [1, k_bucket] row comes back
+        to the host, and nothing n_pad wide is built from it here."""
         cached = self._knn_cache.get(id(node))
         if cached is not None:
             return cached
         from opensearch_tpu.ops import knn as knn_ops
 
-        per_seg_scores: list[np.ndarray | None] = []
-        candidates: list[tuple[float, int, int]] = []
-        for seg_idx, (host, dev) in enumerate(self.snapshot.segments):
+        # each segment's launch row (vals, ids), None without the field
+        seg_rows: list[tuple[np.ndarray, np.ndarray] | None] = []
+        for host, dev in self.snapshot.segments:
             vf = dev.vector_fields.get(node.field)
             if vf is None:
-                per_seg_scores.append(None)
+                seg_rows.append(None)
                 continue
             valid = vf.present & dev.live
             if node.filter is not None:
@@ -321,10 +332,6 @@ class ShardContext:
                     tune_key=("ivfpq", id(self.mapper_service),
                               node.field, k_bucket),
                 )
-                a_vals, a_ids = out.value
-                # the batch leader may have run a LARGER k bucket: the
-                # scatter below accepts any row count, the shard cut
-                # truncates to node.k
                 if prof is not None:
                     prof.record_kernel(
                         family, out.kernel_share_ns,
@@ -336,26 +343,15 @@ class ShardContext:
                             "kernel": kernel,
                         },
                     )
-                _record_ann_metrics(nprobe)
+                metrics = _knn_metrics()
+                if metrics is not None:
+                    metrics.histogram("knn.batch.nprobe").record(nprobe)
                 _count_knn_path("ann")
-                # per request, after the (shared) launch: the dense scatter
-                with tracing.detail(span_names.SEARCH_COLLECT):
-                    scores = np.full(dev.n_pad, -np.inf, np.float32)
-                    hit = a_ids >= 0
-                    scores[a_ids[hit]] = a_vals[hit]
-                    # the launch already returned the top candidates
-                    # sorted — skip the generic argpartition below and feed
-                    # them to the shard cut directly (host work on the
-                    # serving path is GIL-serial; every avoided O(n) pass
-                    # widens the batch win)
-                    per_seg_scores.append(scores)
-                    for v, d in zip(a_vals[hit][: node.k],
-                                    a_ids[hit][: node.k]):
-                        if np.isfinite(v):
-                            candidates.append((float(v), seg_idx, int(d)))
-                continue
+                # per request, after the (shared) launch: its own row
+                with tracing.detail(span_names.SEARCH_COLLECT) as collect:
+                    seg_rows.append(out.value)
+                    collect.set_attribute("dense", int(self.knn_dense))
             else:
-                n_pad = dev.n_pad
                 k_req = max(1, min(int(node.k), host.n_docs))
                 # k is a static jit arg: bucket to the next power of two so
                 # distinct request ks share compiled programs (same concern
@@ -453,7 +449,6 @@ class ShardContext:
                     tune_key=("knn_fused_pallas",
                               id(self.mapper_service), node.field,
                               k_bucket))
-                vals, ids = out.value
                 if prof is not None:
                     # a batched operator owns its SHARE of the fenced
                     # kernel wall (merged launches split evenly)
@@ -465,27 +460,27 @@ class ShardContext:
                             "kernel": impl,
                         },
                     )
-                scores = np.full(n_pad, -np.inf, np.float32)
-                hit = ids >= 0
-                scores[ids[hit]] = vals[hit]
+                seg_rows.append(out.value)
                 _count_knn_path("fused")
-            per_seg_scores.append(scores)
-            n_take = min(node.k, host.n_docs)
-            top = np.argpartition(-scores[: host.n_docs], min(n_take, host.n_docs - 1))[:n_take]
-            for d in top:
-                if np.isfinite(scores[d]):
-                    candidates.append((float(scores[d]), seg_idx, int(d)))
+        # a row's finite entries with a document ARE its segment's
+        # candidates: min(k, n_docs) or more (the batch leader may have run a
+        # larger k bucket), of which the shard cut takes exactly node.k
+        candidates: list[tuple[float, int, int]] = []
+        for seg_idx, row in enumerate(seg_rows):
+            if row is not None:
+                vals, ids = row
+                keep = (ids >= 0) & np.isfinite(vals)
+                candidates.extend(
+                    (v, seg_idx, d)
+                    for v, d in zip(vals[keep].tolist(), ids[keep].tolist()))
         candidates.sort(key=lambda c: (-c[0], c[1], c[2]))
         winners = candidates[: node.k]
-        out = []
-        for seg_idx, (host, dev) in enumerate(self.snapshot.segments):
-            scores = per_seg_scores[seg_idx]
-            sel = np.zeros(dev.n_pad, bool)
-            if scores is not None:
-                for s, si, d in winners:
-                    if si == seg_idx:
-                        sel[d] = True
-            out.append((sel, scores))
+        out = [
+            None if row is None else (
+                np.array([d for _, si, d in winners if si == i], np.int32),
+                np.array([v for v, si, _ in winners if si == i], np.float32))
+            for i, row in enumerate(seg_rows)
+        ]
         self._knn_cache[id(node)] = out
         return out
 
@@ -818,36 +813,38 @@ class NodeResult:
 
 
 class HostNodeResult:
-    """NodeResult duck-type for host-resident selections (the bare-kNN hot
-    path): the shard cut already picked <= k winners on host, so a
-    top-level consumer (execute_query_phase's host fast path) never needs
-    device arrays — uploading the scatter arrays and re-top-k'ing them on
-    device costs more than the whole remaining request. A COMPOUND parent
-    (knn inside bool, rescore, ...) touching `.scores`/`.mask` transparently
-    materializes the device arrays, so query semantics never change."""
+    """NodeResult duck-type for a kNN selection: the shard cut's winners in
+    this segment, `docs` int32[w] and `doc_scores` f32[w] with w <= k, as
+    execute_query_phase's host fast path reads them. A consumer that
+    indexes by document touches the dense view, one scatter of the winners
+    built once: `host_scores` (f32 [n_pad], 0 where unselected) and
+    `host_mask` (bool [n_pad]) for aggregations, `.scores` / `.mask` (their
+    device copies) for a COMPOUND parent (knn inside bool, rescore, ...) or
+    a sort, so query semantics never change. The first touch in a request
+    counts it `knn.collect.dense`; untouched, it counts `.sparse`."""
 
-    __slots__ = ("host_scores", "host_mask", "scoring",
-                 "_dev_scores", "_dev_mask")
+    scoring = True
 
-    def __init__(self, host_scores: np.ndarray, host_mask: np.ndarray,
-                 scoring: bool = True):
-        self.host_scores = host_scores    # f32 [n_pad], 0 where unselected
-        self.host_mask = host_mask        # bool [n_pad]
-        self.scoring = scoring
-        self._dev_scores = None
-        self._dev_mask = None
+    def __init__(self, ctx: ShardContext, n_pad: int, docs: np.ndarray,
+                 doc_scores: np.ndarray):
+        self.ctx, self.n_pad = ctx, n_pad
+        self.docs, self.doc_scores = docs, doc_scores
 
-    @property
-    def scores(self) -> jnp.ndarray:
-        if self._dev_scores is None:
-            self._dev_scores = jnp.asarray(self.host_scores)
-        return self._dev_scores
+    @cached_property
+    def _dense(self) -> tuple[np.ndarray, np.ndarray]:
+        if not self.ctx.knn_dense:
+            self.ctx.knn_dense = True
+            _count_knn_collect("knn.collect.dense")
+        scores = np.zeros(self.n_pad, np.float32)
+        scores[self.docs] = self.doc_scores
+        mask = np.zeros(self.n_pad, bool)
+        mask[self.docs] = True
+        return scores, mask
 
-    @property
-    def mask(self) -> jnp.ndarray:
-        if self._dev_mask is None:
-            self._dev_mask = jnp.asarray(self.host_mask)
-        return self._dev_mask
+    host_scores = property(lambda self: self._dense[0])
+    host_mask = property(lambda self: self._dense[1])
+    scores = cached_property(lambda self: jnp.asarray(self._dense[0]))
+    mask = cached_property(lambda self: jnp.asarray(self._dense[1]))
 
 
 def _const_result(mask: jnp.ndarray, boost: float, scoring: bool) -> NodeResult:
@@ -1761,17 +1758,14 @@ class SegmentExecutor:
         seg_idx = next(
             i for i, (h, d) in enumerate(self.ctx.snapshot.segments) if d is self.dev
         )
-        sel_host, scores_host = selections[seg_idx]
-        if scores_host is None:
+        selection = selections[seg_idx]
+        if selection is None:
             return _empty(self.dev)
-        # host-resident result: the shard cut already chose the winners;
-        # device arrays materialize only if a compound parent needs them
-        out_scores = np.where(
-            sel_host & np.isfinite(scores_host), scores_host, 0.0
-        ).astype(np.float32)
+        # the shard cut already chose the winners: they travel as they are
+        docs, scores = selection
         if node.boost != 1.0:
-            out_scores *= np.float32(node.boost)
-        return HostNodeResult(out_scores, sel_host, scoring=True)
+            scores = scores * np.float32(node.boost)
+        return HostNodeResult(self.ctx, self.dev.n_pad, docs, scores)
 
     def _exec_ScriptScoreQuery(self, node: q.ScriptScoreQuery) -> NodeResult:
         inner = self.execute(node.query) if node.query else self._exec_MatchAllQuery(q.MatchAllQuery())
@@ -2395,28 +2389,30 @@ def execute_query_phase(
         result = ex.execute(query_node)
         if isinstance(result, HostNodeResult) and not sort:
             # host fast path (bare kNN): the selection is already the
-            # shard-level top-k cut, computed against the SNAPSHOT's
-            # device live mask — re-uploading the scatter arrays just to
-            # segment_top_k <= k winners on device would cost more than
-            # the rest of the request (a real serving-path tax: one
-            # launch + two transfers + a fence, all GIL-serial)
+            # shard-level top-k cut, computed against the SNAPSHOT's device
+            # live mask: total, hits and max_score come from its <= k
+            # winners. Only aggregations (need_masks) index by document
             prof = profile.active()
             t_collect = time.perf_counter_ns()
-            with tracing.detail(span_names.SEARCH_COLLECT):
-                mask_h = result.host_mask
-                scores_h = result.host_scores
+            with tracing.detail(span_names.SEARCH_COLLECT) as collect:
+                docs, scores = result.docs, result.doc_scores
                 if min_score is not None:
-                    mask_h = mask_h & (scores_h >= np.float32(min_score))
+                    keep = scores >= np.float32(min_score)
+                    docs, scores = docs[keep], scores[keep]
                 if need_masks:
+                    mask_h = result.host_mask
+                    if min_score is not None:
+                        mask_h = mask_h & (
+                            result.host_scores >= np.float32(min_score))
                     masks.append(mask_h[: host.n_docs])
-                    score_arrays.append(scores_h[: host.n_docs])
-                total += int(mask_h.sum())
+                    score_arrays.append(result.host_scores[: host.n_docs])
+                total += len(docs)
                 if size > 0:
-                    for d in np.nonzero(mask_h)[0]:
-                        v = float(scores_h[d])
-                        all_hits.append(ShardHit(v, seg_idx, int(d)))
+                    for v, d in zip(scores.tolist(), docs.tolist()):
+                        all_hits.append(ShardHit(v, seg_idx, d))
                         if max_score is None or v > max_score:
                             max_score = v
+                collect.set_attribute("dense", int(ctx.knn_dense))
             if prof is not None:
                 prof.collect_ns += time.perf_counter_ns() - t_collect
             continue
@@ -2456,6 +2452,8 @@ def execute_query_phase(
             # the top-k cut / field sort is this engine's collector
             prof.collect_ns += time.perf_counter_ns() - t_collect
 
+    if ctx._knn_cache and not ctx.knn_dense:
+        _count_knn_collect("knn.collect.sparse")
     t_final = time.perf_counter_ns()
     if not sort:
         all_hits.sort(key=lambda h: (-h.score, h.segment, h.doc))

@@ -19,7 +19,10 @@ HTTP_PARSE = "http.parse"
 HTTP_POOL_WAIT = "http.pool_wait"
 HTTP_RESPOND = "http.respond"
 
-# search service (node.py, search/service.py, search/executor.py)
+# search service (node.py, search/service.py, search/executor.py).
+# `search.collect` (the per-shard kNN path: the launch's row, then the
+# winners -> hits) carries `dense`: 1 when the request built an n_pad-wide
+# array from its kNN selection, else 0
 SEARCH = "search"
 SEARCH_PARSE = "search.parse"
 SEARCH_QUERY_PHASE = "search.query_phase"

@@ -290,7 +290,8 @@ def test_one_search_under_a_profiler_session_yields_the_whole_tree(
 def test_the_ann_closure_opens_the_same_launch_spans(node, tmp_path):
     """The per-shard IVF-PQ launch (`search/executor.py`'s closure over
     `ops/ivfpq.select_probes` / `search_probed`): the four launch spans one
-    after the other under the batcher's `launch`, then `search.collect`."""
+    after the other under the batcher's `launch`, then `search.collect`,
+    which says whether the request built an n_pad-wide array (`dense`)."""
     _req("PUT", "/ann", {
         "settings": {"number_of_shards": 1, "number_of_replicas": 0},
         "mappings": {"properties": {"vec": {
@@ -323,9 +324,25 @@ def test_the_ann_closure_opens_the_same_launch_spans(node, tmp_path):
     assert launch["start_ns"] <= steps[0]["start_ns"]
     assert steps[-1]["end_ns"] <= launch["end_ns"]
     by_id = {s["span_id"]: s for s in tree}
-    collect = next(s for s in tree if s["name"] == "search.collect")
-    assert by_id[collect["parent_id"]]["name"] == "search.query_phase"
-    assert collect["start_ns"] >= launch["end_ns"]
+    # both sites, the launch's row and the hits: the winners stayed short
+    collects = [s for s in tree if s["name"] == "search.collect"]
+    assert len(collects) == 2
+    for collect in collects:
+        assert by_id[collect["parent_id"]]["name"] == "search.query_phase"
+        assert collect["start_ns"] >= launch["end_ns"]
+        assert collect["attributes"] == {"dense": 0}
+    # the same request with aggregations indexes by document: the site
+    # that builds the n_pad-wide view says so, and the counters agree
+    with_aggs = dict(QUERY, aggs={"n": {"value_count": {"field": "_id"}}})
+    doc, _ = _traced(node, tmp_path / "aggs",
+                     lambda: _req("POST", "/ann/_search", with_aggs))
+    dense = [s["attributes"]["dense"] for s in sorted(
+        (s for s in doc["spans"] if s["name"] == "search.collect"),
+        key=lambda s: s["start_ns"])]
+    assert dense == [0, 1]
+    opened, closed = doc["counters"]["open"], doc["counters"]["close"]
+    assert closed["knn_collect"]["dense"] == opened["knn_collect"]["dense"] + 1
+    assert closed["knn_collect"]["sparse"] == opened["knn_collect"]["sparse"]
 
 
 def test_a_follower_names_the_leaders_launch(node, tmp_path):
