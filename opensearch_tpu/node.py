@@ -425,9 +425,17 @@ class TpuNode:
             "dense": self.telemetry.metrics.counter("knn.collect.dense"),
             "sparse": self.telemetry.metrics.counter("knn.collect.sparse"),
         }
+        # filtered kNN queries served and the bytes of the eligibility
+        # masks built for them (search/executor.py count_knn_filter)
+        knn_filter = {
+            "requests": self.telemetry.metrics.counter("knn.filter.requests"),
+            "mask_bytes": self.telemetry.metrics.counter(
+                "knn.filter.mask_bytes"),
+        }
         self.telemetry.tracer.capture_counters = lambda: {
             "knn_batch": dict(self.knn_batcher.stats),
             "knn_collect": {k: c.value for k, c in knn_collect.items()},
+            "knn_filter": {k: c.value for k, c in knn_filter.items()},
             "device_resident_bytes": default_ledger.resident_bytes(),
             "device_resident_by_device": default_ledger.device_totals(),
             "device_backend_memory": backend_memory(),

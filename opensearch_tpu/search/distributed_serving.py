@@ -25,13 +25,21 @@ checks.
 Widening: the gates that restricted this path to
 unfiltered multi-shard queries, one vector per dispatch, are lifted:
  - FILTERED kNN: the filter (knn-level and per-shard alias filters) is
-   evaluated host-side per segment (the same SegmentExecutor the host path
-   uses), flattened to a [S, n_flat] mask, ANDed with the bundle's valid
-   mask, and the SAME device program runs — pre-filter semantics identical
-   to the host (executor.ShardContext.shard_knn_selection, which ANDs the
-   filter's mask into `valid` before its launch). Because the host path
-   falls back to an exact scan whenever a filter is present, ANN-indexed
-   segments are also eligible when filtered.
+   evaluated per segment by the same SegmentExecutor the per-shard path
+   uses (device programs over the segment's columns), each segment's mask
+   copied to the host, flattened to a [S, n_flat] mask, uploaded, ANDed
+   with the bundle's valid mask, and the SAME device program runs —
+   pre-filter semantics identical to the per-shard path
+   (executor.ShardContext.shard_knn_selection, which ANDs the filter's
+   mask into `valid` before its launch). All of that is the DETAIL span
+   `filter.mask` (`rows`, `eligible`, `clauses`, `upload_bytes`), the
+   launch says `filtered` 1, and the node's counters `knn.filter.requests`
+   / `knn.filter.mask_bytes` count it (executor.count_knn_filter; this
+   module's `stats["filtered"]` is fed at the same place). A filtered
+   query's mask is request-private, so it shares no launch
+   (search/service.py hands it to the batcher with key None). Because the
+   per-shard path falls back to an exact scan whenever a filter is
+   present, ANN-indexed segments are also eligible when filtered.
  - SINGLE-SHARD: s == 1 runs the same program on a 1-device mesh (the
    all_gather degenerates); the per-shard executor path is bypassed in
    favor of the resident bundle.
@@ -56,7 +64,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from opensearch_tpu.cluster.shard_mesh import default_registry as registry
 from opensearch_tpu.parallel.distributed import build_knn_serving_step, unpack
 from opensearch_tpu.parallel.mesh import DATA_AXIS, serving_devices
-from opensearch_tpu.search.executor import ShardHit, ShardQueryResult
+from opensearch_tpu.search.executor import (
+    ShardHit,
+    ShardQueryResult,
+    count_knn_filter,
+    filter_clauses,
+)
 from opensearch_tpu.telemetry import spans as span_names
 from opensearch_tpu.telemetry import tracing
 
@@ -66,7 +79,8 @@ from opensearch_tpu.telemetry import tracing
 stats = {
     "distributed_searches": 0,
     "fallbacks": 0,
-    "filtered": 0,          # dispatches that carried a filter mask
+    "filtered": 0,          # dispatches that carried a filter mask; counted
+                            # where `knn.filter.requests` is (the tests' view)
     "single_shard": 0,      # dispatches with s == 1
     "batched_queries": 0,   # total query vectors sent in B>1 dispatches
 }
@@ -417,22 +431,35 @@ def mesh_knn_batch(
 
         valid = bundle.valid
         if has_filter:
-            fmask = _filter_valid_mask(
-                shards, snaps, first.filter, alias_filters, bundle.n_flat
-            )
-            # per-request upload, consumed by this launch: transient in the
-            # residency ledger (allocated and freed in one step)
-            from opensearch_tpu.telemetry.device_ledger import (
-                KIND_QUERY_BATCH,
-                default_ledger,
-            )
+            with tracing.detail(span_names.FILTER_MASK) as masked:
+                fmask = _filter_valid_mask(
+                    shards, snaps, first.filter, alias_filters, bundle.n_flat
+                )
+                # per-request upload, consumed by this launch: transient in
+                # the residency ledger (allocated and freed in one step)
+                from opensearch_tpu.telemetry.device_ledger import (
+                    KIND_QUERY_BATCH,
+                    default_ledger,
+                )
 
-            default_ledger.record_transient(KIND_QUERY_BATCH, fmask.nbytes)
-            # from host memory to each device its own shards' rows: no
-            # copy of the whole mask staged on one chip
-            valid = valid & jax.device_put(
-                fmask, NamedSharding(mesh, P(DATA_AXIS))
-            )
+                default_ledger.record_transient(KIND_QUERY_BATCH, fmask.nbytes)
+                # from host memory to each device its own shards' rows: no
+                # copy of the whole mask staged on one chip
+                valid = valid & jax.device_put(
+                    fmask, NamedSharding(mesh, P(DATA_AXIS))
+                )
+                if masked.detail is not None:
+                    masked.set_attribute("rows", int(fmask.size))
+                    masked.set_attribute(
+                        "eligible", int(np.count_nonzero(fmask)))
+                    masked.set_attribute("clauses", sum(
+                        filter_clauses(f)
+                        for f in (first.filter, *(alias_filters or ()))))
+                    masked.set_attribute("upload_bytes", int(fmask.nbytes))
+            # the one count of launches that carried a filter mask: the
+            # node's `knn.filter.*` counters and this module's dict
+            count_knn_filter(len(nodes), int(fmask.nbytes))
+            _count("filtered")
 
         b = len(nodes)
         # pad B to a power of two: B is a static shape under jit, so raw batch
@@ -446,6 +473,7 @@ def mesh_knn_batch(
             launch_span.set_attribute("b_pad", b_pad)
             # device -> host transfers this launch makes: the packed output
             launch_span.set_attribute("host_copies", 1)
+            launch_span.set_attribute("filtered", int(has_filter))
         q_host = np.zeros((b_pad, dims), np.float32)
         for i, node in enumerate(nodes):
             q_host[i] = np.asarray(node.vector, np.float32)
@@ -519,8 +547,6 @@ def mesh_knn_batch(
             # the first launch wall includes the compile
             default_ledger.record_compile("mesh_knn_fused", wall_ns)
         _count("distributed_searches")
-        if has_filter:
-            _count("filtered")
         if s == 1:
             _count("single_shard")
         if b > 1:

@@ -92,6 +92,35 @@ def _count_knn_collect(name: str) -> None:
         metrics.counter(name).add(1)
 
 
+def count_knn_filter(requests: int, mask_bytes: int) -> None:
+    """`knn.filter.requests` / `.mask_bytes`: filtered kNN queries served
+    (once a request on the mesh road, once a request and shard on the
+    per-shard road) and the bytes of the eligibility masks built for their
+    launches."""
+    metrics = _knn_metrics()
+    if metrics is not None:
+        metrics.counter("knn.filter.requests").add(requests)
+        metrics.counter("knn.filter.mask_bytes").add(mask_bytes)
+
+
+def filter_clauses(node) -> int:
+    """Clauses of one filter node: a bool's direct children, else 1 (0 for
+    no filter). The `filter.mask` span's `clauses`."""
+    if node is None:
+        return 0
+    if isinstance(node, q.BoolQuery):
+        return (len(node.must) + len(node.should) + len(node.filter)
+                + len(node.must_not))
+    return 1
+
+
+def _mark_launch_filtered(filtered: bool) -> None:
+    """`filtered` on the batcher's `launch` span, from inside its closure."""
+    span = tracing.active_tracer().current_span()
+    if span is not None and span.name == span_names.LAUNCH:
+        span.set_attribute("filtered", int(filtered))
+
+
 def _pad_query_batch(rows: list) -> np.ndarray:
     """Stack per-request query vectors into a [B_pad, d] batch, B padded to
     the next power of two (zero rows, results sliced off by the caller) so
@@ -192,15 +221,12 @@ class ShardContext:
 
         # each segment's launch row (vals, ids), None without the field
         seg_rows: list[tuple[np.ndarray, np.ndarray] | None] = []
-        for host, dev in self.snapshot.segments:
+        valids = self._knn_valid_masks(node)
+        for (host, dev), valid in zip(self.snapshot.segments, valids):
             vf = dev.vector_fields.get(node.field)
             if vf is None:
                 seg_rows.append(None)
                 continue
-            valid = vf.present & dev.live
-            if node.filter is not None:
-                ex = SegmentExecutor(self, host, dev)
-                valid = valid & ex.execute(node.filter).mask
             # host numpy: the query vector is this path's whole per-request
             # host->device transfer (the profiler counts host-typed args)
             qv = np.asarray([node.vector], np.float32)
@@ -263,6 +289,7 @@ class ShardContext:
                 touch_allocs = _touch_targets(dev, node.field, ann=vf.ann)
 
                 def launch_ann(rows):
+                    _mark_launch_filtered(False)
                     with profile.profiling(None):
                         with tracing.detail(span_names.LAUNCH_HOST_PRE):
                             q_batch = _pad_query_batch(rows)
@@ -396,6 +423,7 @@ class ShardContext:
                 touch_allocs = _touch_targets(dev, node.field)
 
                 def launch_fused(rows):
+                    _mark_launch_filtered(node.filter is not None)
                     q_batch = _pad_query_batch(rows)
                     t0 = time.perf_counter_ns()
                     with profile.profiling(None):
@@ -483,6 +511,33 @@ class ShardContext:
         ]
         self._knn_cache[id(node)] = out
         return out
+
+    def _knn_valid_masks(self, node) -> list:
+        """Per segment, the rows a KnnQuery's launch may return: `present &
+        live`, under a filter also the filter executor's mask; None where
+        the segment has no such field. A filtered query's masks are made
+        under ONE `filter.mask` span, before the first launch."""
+        segments = self.snapshot.segments
+        valids = [
+            None if (vf := dev.vector_fields.get(node.field)) is None
+            else vf.present & dev.live for _host, dev in segments]
+        if node.filter is None:
+            return valids
+        with tracing.detail(span_names.FILTER_MASK) as masked:
+            for i, (host, dev) in enumerate(segments):
+                if valids[i] is not None:
+                    valids[i] = valids[i] & SegmentExecutor(
+                        self, host, dev).execute(node.filter).mask
+            built = [v for v in valids if v is not None]
+            if masked.detail is not None:
+                masked.set_attribute("rows", sum(int(v.size) for v in built))
+                masked.set_attribute("eligible", sum(
+                    int(jnp.count_nonzero(v)) for v in built))
+                masked.set_attribute("clauses", filter_clauses(node.filter))
+                # the masks are made on the device: nothing is uploaded
+                masked.set_attribute("upload_bytes", 0)
+        count_knn_filter(1, sum(int(v.nbytes) for v in built))
+        return valids
 
     def mlt_rewrite(self, node) -> Any:
         """MoreLikeThisQuery -> bool-should of term queries, selected by

@@ -1155,44 +1155,34 @@ def _try_distributed_query_phase(
             tuple(len(snap.segments) for snap in snaps),
         )
 
-    if key is None:
-        # a filtered query's mask is request-private: no batcher, so the
-        # `launch` span the batcher would open is opened here
-        with tracing.detail(span_names.LAUNCH):
-            out = distributed_serving.mesh_knn_batch(
-                shards, snaps, [node], fetch_k, alias_filters=filter_nodes
-            )
-        if out is None:
-            return None
-        results, premerged = out.per_query[0], out.premerged[0]
-        launch_info = {"launch_id": out.launch_id, "wall_ns": out.wall_ns,
-                       "retraced": out.retraced, "shards": out.shards,
-                       "merged": 1}
-    else:
-        from opensearch_tpu.search import batcher as batcher_mod
+    from opensearch_tpu.search import batcher as batcher_mod
 
-        def launch(nodes_batch):
-            out_b = distributed_serving.mesh_knn_batch(
-                shards, snaps, list(nodes_batch), fetch_k
-            )
-            if out_b is None:  # ineligible: every member falls back
-                return [None] * len(nodes_batch), False
-            info = {"launch_id": out_b.launch_id, "wall_ns": out_b.wall_ns,
-                    "retraced": out_b.retraced, "shards": out_b.shards}
-            return [
-                (out_b.per_query[i], out_b.premerged[i], info)
-                for i in range(len(nodes_batch))
-            ], out_b.retraced
+    def launch(nodes_batch):
+        out_b = distributed_serving.mesh_knn_batch(
+            shards, snaps, list(nodes_batch), fetch_k,
+            alias_filters=filter_nodes,
+        )
+        if out_b is None:  # ineligible: every member falls back
+            return [None] * len(nodes_batch), False
+        info = {"launch_id": out_b.launch_id, "wall_ns": out_b.wall_ns,
+                "retraced": out_b.retraced, "shards": out_b.shards}
+        return [
+            (out_b.per_query[i], out_b.premerged[i], info)
+            for i in range(len(nodes_batch))
+        ], out_b.retraced
 
-        outcome = batcher_mod.dispatch(
-            key, node, launch, shards=len(shards),
-            # generation-free family for the wait auto-tuner
-            tune_key=("distributed_knn", shards[0].shard_id.index,
-                      node.field, int(node.k)))
-        if outcome.value is None:
-            return None
-        results, premerged, launch_info = outcome.value
-        launch_info = dict(launch_info, merged=outcome.merged)
+    # a filtered query's mask is request-private: key None, so the batcher
+    # launches it alone, under the same `launch` span and in the same
+    # `dispatches` / `merged_queries` as every other launch
+    outcome = batcher_mod.dispatch(
+        key, node, launch, shards=len(shards),
+        # generation-free family for the wait auto-tuner
+        tune_key=("distributed_knn", shards[0].shard_id.index,
+                  node.field, int(node.k)))
+    if outcome.value is None:
+        return None
+    results, premerged, launch_info = outcome.value
+    launch_info = dict(launch_info, merged=outcome.merged)
     return (
         [(shard, snap, res)
          for shard, snap, res in zip(shards, snaps, results)],

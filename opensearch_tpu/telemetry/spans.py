@@ -40,7 +40,8 @@ BATCH_WAIT = "batch.wait"
 # has one packed output, so that copy is its only one; `launch.fetch` is
 # the host copy of the IVF path's second output. `launch` carries `merged`,
 # `reason` and, from the mesh program, `devices`, `shards`, `b_pad`,
-# `host_copies` (device -> host transfers the launch made: 1).
+# `host_copies` (device -> host transfers the launch made: 1); on either
+# road it carries `filtered`: 1 when the launch served a filtered query.
 # `mesh.bundle_build` (always on) spans the upload of an index's slabs to
 # the mesh after a refresh or a recovery: `devices`, `shards`,
 # `bytes_per_device`, `staging_bytes` (see `_build_bundle`)
@@ -50,6 +51,16 @@ LAUNCH_HOST_PRE = "launch.host_pre"
 LAUNCH_DEVICE = "launch.device"
 LAUNCH_FETCH = "launch.fetch"
 LAUNCH_HOST_POST = "launch.host_post"
+
+# a filtered kNN request's eligibility (DETAIL; kept beside `ALL`, as
+# `mesh.bundle_build` is): everything that turns the request's filter nodes
+# into what its launch may return, whatever implements it. Today the filter
+# executor over every segment, then on the mesh road the mask's upload and
+# `valid & mask`, on the per-shard road `present & live & mask`. It opens
+# once a filtered request (and shard, on the per-shard road) and carries
+# `rows` (the mask's width), `eligible` (rows that pass), `clauses`,
+# `upload_bytes` (host -> device bytes the mask cost)
+FILTER_MASK = "filter.mask"
 
 # process
 RUNTIME_GC = "runtime.gc"

@@ -28,7 +28,10 @@ propagation rides ThreadContext). Here:
   `jax.profiler.TraceAnnotation`, which puts it on the clock of the device's
   `XLA Ops` lines, and as a compact record in the tracer's `Capture`, which
   is written to `<path.data>/telemetry/capture-<n>.json` when the session
-  has ended. No setting turns this on: the profiler session is the switch.
+  has ended and the requests that opened under it have finished (the
+  capture drains for CAPTURE_DRAIN_S at most, and details the requests
+  that open meanwhile, so that a request of seconds is held whole). No
+  setting turns this on: the profiler session is the switch.
 """
 
 from __future__ import annotations
@@ -147,8 +150,12 @@ class _SpanScope:
 # records a capture holds before it counts `dropped` instead (never blocks)
 CAPTURE_MAX_RECORDS = 1 << 18
 CAPTURE_KEEP_FILES = 4
-# a capture is written this long after its session ended, so that the
-# requests in flight at that moment finish into it
+# a capture stays open after its session ended until the requests that
+# opened under the session have finished (a filtered kNN search under 32
+# clients takes seconds, not milliseconds), but no longer than this
+CAPTURE_DRAIN_S = 30.0
+# and is written this long after it closed, so that the spans that follow
+# their root's close (`http.respond`) finish into it
 CAPTURE_GRACE_S = 0.5
 _CAPTURE_WRITE_CHUNK = 512
 _CAPTURE_FILE = re.compile(r"capture-(\d+)\.json")
@@ -202,6 +209,12 @@ class Capture:
         self.dropped = 0
         self.opened = clock_pair()
         self.closed: tuple[int, int] | None = None
+        # span ids of the roots that opened under the session and have not
+        # ended: while there are any, the capture outlives its session
+        # (`draining`) and goes on detailing the requests that open, so
+        # that what it holds of that stretch is whole
+        self.session_roots: set[str] = set()
+        self._session_over: float | None = None
         self.counters_open = tracer.read_capture_counters()
         self.counters_close: dict | None = None
         # taken only past the cap; re-entrant, since a collection can start
@@ -225,6 +238,14 @@ class Capture:
     def add_span(self, span: Span) -> None:
         self.add(span.name, span.trace_id, span.span_id, span.parent_id,
                  span.start_ns, span.end_ns, span.attributes)
+
+    def draining(self) -> bool:
+        """The session is over (the caller saw that) and a request that
+        opened under it is still in flight, for CAPTURE_DRAIN_S at most."""
+        if self._session_over is None:
+            self._session_over = time.monotonic()
+        return (bool(self.session_roots)
+                and time.monotonic() - self._session_over < CAPTURE_DRAIN_S)
 
 
 class _NullSpan:
@@ -496,13 +517,22 @@ class Tracer:
             # a request is detailed from end to end or not at all: the
             # root asks the profiler once, its children inherit the answer
             detail=(parent.detail if parent is not None
-                    else self._session_capture()),
+                    else self._session_capture(sid)),
         )
 
     def end_span(self, span: Span) -> None:
         span.end_ns = time.perf_counter_ns()
-        if span.detail is not None:
-            span.detail.add_span(span)
+        capture = span.detail
+        if capture is not None:
+            capture.add_span(span)
+            if span.parent_id is None:
+                capture.session_roots.discard(span.span_id)
+                # the last request of a session that is over closes its
+                # capture: `closed` is the moment it holds them all
+                if (not capture.session_roots
+                        and capture is self._capture  # tpulint: disable=TPU003
+                        and not profiler_session_on()):
+                    self._close_capture(capture)
         if self.enabled:
             with self._lock:
                 self._finished.append(span)
@@ -525,12 +555,14 @@ class Tracer:
 
     # -- request detail: the capture ---------------------------------------
 
-    def _session_capture(self) -> Capture | None:
+    def _session_capture(self, root_id: str) -> Capture | None:
         """The open capture if a profiler session is on (opening one at the
-        session's first root span), else None — and the first root span
-        that finds the session over hands the capture to its writer: the
-        node may never shut down in an orderly way (SIGTERM), so the
-        capture is written when the session ends."""
+        session's first root span) or over with requests of its own still
+        in flight (`Capture.draining`), else None. The last of those
+        requests to end, or the first root span that finds the session
+        over and none left, hands the capture to its writer: the node may
+        never shut down in an orderly way (SIGTERM), so the capture is
+        written when the session's requests have ended."""
         # read without the lock: this is every root span's path, and both
         # transitions below check again under it
         capture = self._capture  # tpulint: disable=TPU003
@@ -541,8 +573,11 @@ class Tracer:
                     if capture is None:
                         capture = self._capture = Capture(self)
                         gc.callbacks.append(self._on_gc)
+            capture.session_roots.add(root_id)
             return capture
         if capture is not None:
+            if capture.draining():
+                return capture
             self._close_capture(capture)
         return None
 

@@ -368,8 +368,9 @@ def test_a_follower_names_the_leaders_launch(node, tmp_path):
     assert len(launches) == 1 and launches[0]["attributes"] == {
         "merged": 2, "reason": "size",
         # from the mesh program: the launch's shape (one shard, one
-        # device) and its one device -> host transfer
-        "devices": 1, "shards": 1, "b_pad": 2, "host_copies": 1}
+        # device), its one device -> host transfer, and no filter
+        "devices": 1, "shards": 1, "b_pad": 2, "host_copies": 1,
+        "filtered": 0}
     waits = {s["attributes"]["reason"]: s for s in doc["spans"]
              if s["name"] == "batch.wait"}
     assert set(waits) == {"size", "follower"}
@@ -404,6 +405,49 @@ def test_a_collection_during_a_capture_is_a_runtime_gc_span(node, tmp_path):
     assert node.telemetry.tracer._on_gc not in gc.callbacks   # hook is gone
     assert (doc["counters"]["close"]["gc"][2]["collections"]
             > doc["counters"]["open"]["gc"][2]["collections"])
+
+
+def test_a_capture_outlives_its_session_until_its_requests_have_ended(
+        monkeypatch):
+    """A request of seconds (a filtered kNN search under 32 clients) is
+    held whole: the capture closes when the last request that opened under
+    the session ends, and details the requests that open meanwhile."""
+    session = {"on": True}
+    monkeypatch.setattr(tracing, "profiler_session_on",
+                        lambda: session["on"])
+    monkeypatch.setattr(tracing, "_annotate", lambda span: None)
+    tracer = tracing.Tracer(name="drain")
+    slow = tracer.begin_span("search")
+    assert slow.detail is not None and tracer.capture_stats()["open"]
+    session["on"] = False
+    # the session is over and `slow` is in flight: still open, still detail
+    with tracer.start_span("search") as meanwhile:
+        assert meanwhile.detail is slow.detail
+    assert tracer.capture_stats()["open"] is True
+    capture = slow.detail
+    tracer.end_span(slow)
+    assert tracer.capture_stats()["open"] is False
+    assert capture.closed[0] >= slow.end_ns
+    assert {r[2] for r in capture.records} == {slow.span_id,
+                                               meanwhile.span_id}
+    # nothing is detailed once it has closed
+    with tracer.start_span("search") as after:
+        assert after.detail is None
+
+
+def test_a_capture_drains_for_a_bounded_time(monkeypatch):
+    session = {"on": True}
+    monkeypatch.setattr(tracing, "profiler_session_on",
+                        lambda: session["on"])
+    monkeypatch.setattr(tracing, "_annotate", lambda span: None)
+    monkeypatch.setattr(tracing, "CAPTURE_DRAIN_S", 0.0)
+    tracer = tracing.Tracer(name="stuck")
+    stuck = tracer.begin_span("search")
+    session["on"] = False
+    with tracer.start_span("search") as later:
+        assert later.detail is None     # overdue: closed, not drained
+    assert tracer.capture_stats()["open"] is False
+    tracer.end_span(stuck)              # a late end changes nothing
 
 
 def test_past_the_cap_records_are_counted_as_dropped_never_kept(monkeypatch):
