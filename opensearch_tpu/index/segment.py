@@ -22,6 +22,7 @@ cache entries stay bounded across segments.
 from __future__ import annotations
 
 import json
+import threading
 from dataclasses import dataclass, field as dc_field
 from pathlib import Path
 from typing import Any
@@ -140,6 +141,11 @@ class HostTextField:
         return self.positions.size > 0
 
 
+# one lock for every field's first `build_postings`: a build is once a
+# segment and field, so fields do not need a lock each
+_postings_build_lock = threading.Lock()
+
+
 @dataclass
 class HostKeywordField:
     ord_values: list[str]            # ordinal -> value (sorted)
@@ -148,6 +154,30 @@ class HostKeywordField:
     mv_offsets: np.ndarray           # int32 [n_docs+1] CSR into mv_ords
     mv_ords: np.ndarray              # int32 [E] ordinals per doc (sorted per doc)
     mv_docs: np.ndarray              # int32 [E] owning doc of each entry
+    # the ordinal-major view of the same pairs (HostTextField.term_offsets /
+    # postings_docs analog), None until `build_postings`: host memory only,
+    # never stored
+    ord_offsets: np.ndarray | None = None   # int64 [n_ords+1] CSR into ord_docs
+    ord_docs: np.ndarray | None = None      # int32 [E] docs ascending per ordinal
+
+    def build_postings(self) -> bool:
+        """Make `ord_offsets` / `ord_docs` on first use; True for the one
+        caller that built them. Search workers race here on a cold field:
+        the lock lets one sort and the others wait for its arrays."""
+        if self.ord_docs is not None:
+            return False
+        with _postings_build_lock:
+            if self.ord_docs is not None:
+                return False
+            self.ord_offsets = np.concatenate((
+                np.zeros(1, np.int64),
+                np.cumsum(np.bincount(
+                    self.mv_ords, minlength=len(self.ord_values)), dtype=np.int64),
+            ))
+            # mv_docs ascends, so a stable sort by ordinal keeps the docs of
+            # one ordinal ascending. Stored last: a reader tests this one
+            self.ord_docs = self.mv_docs[np.argsort(self.mv_ords, kind="stable")]
+            return True
 
 
 @dataclass

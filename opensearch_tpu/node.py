@@ -426,11 +426,15 @@ class TpuNode:
             "sparse": self.telemetry.metrics.counter("knn.collect.sparse"),
         }
         # filtered kNN queries served and the bytes of the eligibility
-        # masks built for them (search/executor.py count_knn_filter)
+        # masks built for them (search/executor.py count_knn_filter), and
+        # the keyword fields' ordinal-major views built, once a segment and
+        # field on its first keyword clause (executor._keyword_postings)
         knn_filter = {
             "requests": self.telemetry.metrics.counter("knn.filter.requests"),
             "mask_bytes": self.telemetry.metrics.counter(
                 "knn.filter.mask_bytes"),
+            "postings_builds": self.telemetry.metrics.counter(
+                "knn.filter.postings_builds"),
         }
         self.telemetry.tracer.capture_counters = lambda: {
             "knn_batch": dict(self.knn_batcher.stats),

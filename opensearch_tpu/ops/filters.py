@@ -2,8 +2,13 @@
 
 The analog of Lucene filter clauses / points-range queries executing against
 doc values (reference: index/query/* compiled through QueryShardContext into
-Lucene queries). Here every filter compiles to a [n_pad] bool mask computed
-on the VPU; bool-query composition is elementwise &, |, &~.
+Lucene queries). Every filter compiles to a [n_pad] bool mask; bool-query
+composition is elementwise &, |, &~ on the VPU. Numeric ranges and a text
+term's postings window are computed on the device from resident columns. A
+keyword field's ordinals are not: `keyword_mask_from_postings` (numpy)
+makes their mask on the host from the posting lists of the ordinals a query
+names, so its work follows those lists and not the field's E pairs; the
+caller uploads the mask.
 
 int64 columns arrive as two int32 words (see segment.split_i64): range
 comparison is lexicographic (hi, lo) with lo pre-offset so signed compare
@@ -13,6 +18,7 @@ behaves as unsigned — exact int64 semantics without x64 mode.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def i64_ge(hi: jnp.ndarray, lo: jnp.ndarray, qhi: jnp.ndarray, qlo: jnp.ndarray) -> jnp.ndarray:
@@ -45,26 +51,29 @@ def range_mask_f32(
     return present & lower & upper
 
 
-def term_mask_keyword(
-    mv_ords: jnp.ndarray,     # int32 [E_pad] CSR ordinals (pad = -2)
-    mv_docs: jnp.ndarray,     # int32 [E_pad] owning doc (pad = 0)
-    query_ord: jnp.ndarray,   # scalar int32 (-3 = term not in segment dict)
-    n_pad: int,
-) -> jnp.ndarray:
-    hit = (mv_ords == query_ord).astype(jnp.int32)
-    mask = jnp.zeros(n_pad, jnp.int32).at[mv_docs].max(hit)
-    return mask.astype(bool)
+def keyword_mask_from_postings(kf, ords, n_pad: int) -> tuple[np.ndarray, int]:
+    """Host mask of the docs holding any of `ords` in one keyword field.
 
-
-def terms_mask_keyword(
-    mv_ords: jnp.ndarray,
-    mv_docs: jnp.ndarray,
-    query_ords: jnp.ndarray,  # int32 [T_pad], pad slots = -3
-    n_pad: int,
-) -> jnp.ndarray:
-    hit = jnp.any(mv_ords[:, None] == query_ords[None, :], axis=1).astype(jnp.int32)
-    mask = jnp.zeros(n_pad, jnp.int32).at[mv_docs].max(hit)
-    return mask.astype(bool)
+    `kf` is a HostKeywordField whose ordinal-major view is built
+    (`build_postings`); `ords` is any collection of ordinals, a `range` for
+    a range on ordinals; ordinals the segment does not hold (negative) are
+    skipped. Each run of consecutive ordinals is ONE slice of `ord_docs`.
+    Returns (bool [n_pad], posting entries scattered)."""
+    mask = np.zeros(n_pad, bool)
+    if isinstance(ords, range) and ords.step == 1:
+        runs = [(ords.start, ords.stop)] if len(ords) else []
+    else:
+        held = np.unique(np.asarray(list(ords), np.int64))
+        held = held[held >= 0]
+        cuts = np.flatnonzero(np.diff(held) != 1) + 1
+        runs = [(int(run[0]), int(run[-1]) + 1)
+                for run in np.split(held, cuts) if len(run)]
+    postings = 0
+    for lo, hi in runs:
+        a, b = int(kf.ord_offsets[lo]), int(kf.ord_offsets[hi])
+        mask[kf.ord_docs[a:b]] = True
+        postings += b - a
+    return mask, postings
 
 
 def exists_mask(present: jnp.ndarray) -> jnp.ndarray:

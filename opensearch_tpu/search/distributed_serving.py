@@ -26,16 +26,20 @@ Widening: the gates that restricted this path to
 unfiltered multi-shard queries, one vector per dispatch, are lifted:
  - FILTERED kNN: the filter (knn-level and per-shard alias filters) is
    evaluated per segment by the same SegmentExecutor the per-shard path
-   uses (device programs over the segment's columns), each segment's mask
-   copied to the host, flattened to a [S, n_flat] mask, uploaded, ANDed
-   with the bundle's valid mask, and the SAME device program runs —
-   pre-filter semantics identical to the per-shard path
+   uses (a keyword clause: a host mask from the posting lists of the
+   ordinals it names, uploaded; numeric and text clauses and the bool
+   composition: device programs over the segment's columns), each
+   segment's mask copied to the host, flattened to a [S, n_flat] mask,
+   uploaded, ANDed with the bundle's valid mask, and the SAME device
+   program runs — pre-filter semantics identical to the per-shard path
    (executor.ShardContext.shard_knn_selection, which ANDs the filter's
    mask into `valid` before its launch). All of that is the DETAIL span
-   `filter.mask` (`rows`, `eligible`, `clauses`, `upload_bytes`), the
-   launch says `filtered` 1, and the node's counters `knn.filter.requests`
-   / `knn.filter.mask_bytes` count it (executor.count_knn_filter; this
-   module's `stats["filtered"]` is fed at the same place). A filtered
+   `filter.mask` (`rows`, `eligible`, `clauses`, `postings`,
+   `upload_bytes`), the launch says `filtered` 1, and the node's counters
+   `knn.filter.requests` / `knn.filter.mask_bytes` count it
+   (executor.count_knn_filter; this module's `stats["filtered"]` is fed at
+   the same place); `knn.filter.postings_builds` counts a keyword field's
+   ordinal-major view being built, once a segment and field. A filtered
    query's mask is request-private, so it shares no launch
    (search/service.py hands it to the batcher with key None). Because the
    per-shard path falls back to an exact scan whenever a filter is
@@ -328,15 +332,17 @@ def _filter_valid_mask(
     knn_filter,
     alias_filters: list | None,
     n_flat: int,
-) -> np.ndarray:
+) -> tuple[np.ndarray, int]:
     """[S, n_flat] bool: per-query-eligible docs under the knn-level filter
     and each shard's alias filter, laid out exactly like the bundle slabs
-    (segment-ascending, doc-ascending, zero-padded). Runs the SAME
+    (segment-ascending, doc-ascending, zero-padded); and the posting entries
+    the filters' keyword clauses scattered to make it. Runs the SAME
     SegmentExecutor the host path uses for the filter
     (executor.shard_knn_selection), so pre-filter semantics match."""
     from opensearch_tpu.search.executor import SegmentExecutor, ShardContext
 
     out = np.zeros((len(snaps), n_flat), bool)
+    postings = 0
     for si, (shard, snap) in enumerate(zip(shards, snaps)):
         fnodes = [f for f in (
             knn_filter, alias_filters[si] if alias_filters else None
@@ -349,9 +355,10 @@ def _filter_valid_mask(
             for fnode in fnodes:
                 ex = SegmentExecutor(ctx, host, dev)
                 m &= np.asarray(ex.execute(fnode).mask)[:n]
+                postings += ex.postings
             out[si, pos:pos + n] = m
             pos += n
-    return out
+    return out, postings
 
 
 def try_distributed_knn_batch(
@@ -432,7 +439,7 @@ def mesh_knn_batch(
         valid = bundle.valid
         if has_filter:
             with tracing.detail(span_names.FILTER_MASK) as masked:
-                fmask = _filter_valid_mask(
+                fmask, postings = _filter_valid_mask(
                     shards, snaps, first.filter, alias_filters, bundle.n_flat
                 )
                 # per-request upload, consumed by this launch: transient in
@@ -455,6 +462,7 @@ def mesh_knn_batch(
                     masked.set_attribute("clauses", sum(
                         filter_clauses(f)
                         for f in (first.filter, *(alias_filters or ()))))
+                    masked.set_attribute("postings", postings)
                     masked.set_attribute("upload_bytes", int(fmask.nbytes))
             # the one count of launches that carried a filter mask: the
             # node's `knn.filter.*` counters and this module's dict

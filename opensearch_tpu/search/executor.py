@@ -85,8 +85,9 @@ def _knn_metrics():
     return tracing.active_metrics() or batcher_mod.default_batcher.metrics
 
 
-def _count_knn_collect(name: str) -> None:
-    """`knn.collect.dense` / `.sparse`: once a request and shard."""
+def _count_knn(name: str) -> None:
+    """One of the node's kNN counters by 1: `knn.collect.dense` / `.sparse`
+    (once a request and shard), `knn.filter.postings_builds`."""
     metrics = _knn_metrics()
     if metrics is not None:
         metrics.counter(name).add(1)
@@ -101,6 +102,15 @@ def count_knn_filter(requests: int, mask_bytes: int) -> None:
     if metrics is not None:
         metrics.counter("knn.filter.requests").add(requests)
         metrics.counter("knn.filter.mask_bytes").add(mask_bytes)
+
+
+def _keyword_postings(kf):
+    """The keyword field with its ordinal-major view built;
+    `knn.filter.postings_builds` counts the one build a segment and field,
+    so that a build inside a measured window shows."""
+    if kf.build_postings():
+        _count_knn("knn.filter.postings_builds")
+    return kf
 
 
 def filter_clauses(node) -> int:
@@ -524,17 +534,21 @@ class ShardContext:
         if node.filter is None:
             return valids
         with tracing.detail(span_names.FILTER_MASK) as masked:
+            postings = 0
             for i, (host, dev) in enumerate(segments):
                 if valids[i] is not None:
-                    valids[i] = valids[i] & SegmentExecutor(
-                        self, host, dev).execute(node.filter).mask
+                    ex = SegmentExecutor(self, host, dev)
+                    valids[i] = valids[i] & ex.execute(node.filter).mask
+                    postings += ex.postings
             built = [v for v in valids if v is not None]
             if masked.detail is not None:
                 masked.set_attribute("rows", sum(int(v.size) for v in built))
                 masked.set_attribute("eligible", sum(
                     int(jnp.count_nonzero(v)) for v in built))
                 masked.set_attribute("clauses", filter_clauses(node.filter))
-                # the masks are made on the device: nothing is uploaded
+                masked.set_attribute("postings", postings)
+                # the flat mask is composed on the device: no upload of it
+                # (a keyword clause uploads its own mask inside the executor)
                 masked.set_attribute("upload_bytes", 0)
         count_knn_filter(1, sum(int(v.nbytes) for v in built))
         return valids
@@ -800,7 +814,8 @@ class ShardContext:
                 continue
             o = kf.ord_dict.get(value)
             if o is not None:
-                df += int(np.sum(kf.mv_ords == o))
+                offsets = _keyword_postings(kf).ord_offsets
+                df += int(offsets[o + 1] - offsets[o])
         return df
 
     def keyword_doc_count(self, field: str) -> int:
@@ -889,7 +904,7 @@ class HostNodeResult:
     def _dense(self) -> tuple[np.ndarray, np.ndarray]:
         if not self.ctx.knn_dense:
             self.ctx.knn_dense = True
-            _count_knn_collect("knn.collect.dense")
+            _count_knn("knn.collect.dense")
         scores = np.zeros(self.n_pad, np.float32)
         scores[self.docs] = self.doc_scores
         mask = np.zeros(self.n_pad, bool)
@@ -917,6 +932,9 @@ class SegmentExecutor:
         self.ctx = ctx
         self.host = host
         self.dev = dev
+        # posting entries this executor's keyword clauses scattered into
+        # masks: the `filter.mask` span's `postings`
+        self.postings = 0
 
     # -- text scoring ------------------------------------------------------
 
@@ -1205,14 +1223,11 @@ class SegmentExecutor:
                     ),
                     node.boost,
                 )
-            kf_dev = self.dev.keyword_fields.get(field)
             kf_host = self.host.keyword_fields.get(field)
-            if kf_dev is None:
+            if kf_host is None:
                 return _empty(self.dev)
-            qord = kf_host.ord_dict.get(str(value), -3)
-            mask = filters.term_mask_keyword(
-                kf_dev.mv_ords, kf_dev.mv_docs, jnp.int32(qord), self.dev.n_pad
-            ) & self.dev.live
+            mask = self._keyword_mask(
+                kf_host, (kf_host.ord_dict.get(str(value), -3),))
             # keyword term scoring: norms omitted -> idf * tf/(tf+k1), tf=1
             df = self.ctx.keyword_df(field, str(value))
             doc_count = max(self.ctx.keyword_doc_count(field), 1)
@@ -1250,20 +1265,13 @@ class SegmentExecutor:
                 ))
         ftype = mapper.type if mapper else None
         if ftype in ("keyword", "flat_object"):
-            kf_dev = self.dev.keyword_fields.get(node.field)
             kf_host = self.host.keyword_fields.get(node.field)
-            if kf_dev is None:
+            if kf_host is None:
                 return _empty(self.dev)
-            ords = [
+            mask = self._keyword_mask(kf_host, [
                 kf_host.ord_dict.get(self._normalize_kw(node.field, str(v)), -3)
                 for v in node.values
-            ]
-            t_pad = max(pad_window(len(ords)), 8)
-            ords_arr = np.full(t_pad, -3, np.int32)
-            ords_arr[: len(ords)] = ords
-            mask = filters.terms_mask_keyword(
-                kf_dev.mv_ords, kf_dev.mv_docs, jnp.asarray(ords_arr), self.dev.n_pad
-            ) & self.dev.live
+            ])
             return _const_result(mask, node.boost, scoring=True)
         # numeric/text fallback: OR of term queries
         out: NodeResult | None = None
@@ -1414,7 +1422,6 @@ class SegmentExecutor:
         if mapper is not None and mapper.type == "keyword":
             # lexicographic range over ordinals (ordinals are sorted)
             kf_host = self.host.keyword_fields.get(node.field)
-            kf_dev = self.dev.keyword_fields.get(node.field)
             if kf_host is None:
                 return _empty(self.dev)
             import bisect
@@ -1432,14 +1439,7 @@ class SegmentExecutor:
                 hi = min(hi, bisect.bisect_left(vals, str(node.lt)) - 1)
             if hi < lo:
                 return _empty(self.dev)
-            in_range = (kf_dev.mv_ords >= lo) & (kf_dev.mv_ords <= hi)
-            mask = (
-                jnp.zeros(self.dev.n_pad, jnp.int32)
-                .at[kf_dev.mv_docs]
-                .max(in_range.astype(jnp.int32))
-                .astype(bool)
-                & self.dev.live
-            )
+            mask = self._keyword_mask(kf_host, range(lo, hi + 1))
             return _const_result(mask, node.boost, scoring=True)
         return self._exec_range_numeric(node)
 
@@ -1895,11 +1895,22 @@ class SegmentExecutor:
                     mask[host_tf.postings_docs[off:end]] = True
         kf = self.host.keyword_fields.get(field)
         if kf is not None:
-            ords = [o for o, v in enumerate(kf.ord_values) if match_fn(v)]
-            if ords:
-                sel = np.isin(kf.mv_ords, np.asarray(ords, kf.mv_ords.dtype))
-                mask[kf.mv_docs[sel]] = True
+            mask |= self._keyword_host_mask(
+                kf, [o for o, v in enumerate(kf.ord_values) if match_fn(v)])
         return mask
+
+    def _keyword_host_mask(self, kf, ords) -> np.ndarray:
+        """bool [n_pad] on the host: the docs of this segment that hold any
+        of `ords` (a collection of ordinals, or a `range` of them) in the
+        keyword field `kf`, from those ordinals' posting lists alone."""
+        mask, postings = filters.keyword_mask_from_postings(
+            _keyword_postings(kf), ords, self.dev.n_pad)
+        self.postings += postings
+        return mask
+
+    def _keyword_mask(self, kf, ords) -> jnp.ndarray:
+        """`_keyword_host_mask`, uploaded and cut to the live docs."""
+        return jnp.asarray(self._keyword_host_mask(kf, ords)) & self.dev.live
 
     def _multi_term_result(self, field: str, match_fn, boost: float) -> NodeResult:
         mask = jnp.asarray(self._host_mask_for_terms(field, match_fn)) & self.dev.live
@@ -2508,7 +2519,7 @@ def execute_query_phase(
             prof.collect_ns += time.perf_counter_ns() - t_collect
 
     if ctx._knn_cache and not ctx.knn_dense:
-        _count_knn_collect("knn.collect.sparse")
+        _count_knn("knn.collect.sparse")
     t_final = time.perf_counter_ns()
     if not sort:
         all_hits.sort(key=lambda h: (-h.score, h.segment, h.doc))

@@ -55,11 +55,17 @@ LAUNCH_HOST_POST = "launch.host_post"
 # a filtered kNN request's eligibility (DETAIL; kept beside `ALL`, as
 # `mesh.bundle_build` is): everything that turns the request's filter nodes
 # into what its launch may return, whatever implements it. Today the filter
-# executor over every segment, then on the mesh road the mask's upload and
-# `valid & mask`, on the per-shard road `present & live & mask`. It opens
-# once a filtered request (and shard, on the per-shard road) and carries
-# `rows` (the mask's width), `eligible` (rows that pass), `clauses`,
-# `upload_bytes` (host -> device bytes the mask cost)
+# executor over every segment (a keyword clause's mask is made on the host
+# from its ordinals' posting lists and uploaded, the clauses composed on the
+# device), then on the mesh road the composed mask's copy to the host, its
+# upload as the launch's `[S, n_flat]` mask and `valid & mask`, on the
+# per-shard road `present & live & mask`. It opens once a filtered request
+# (and shard, on the per-shard road) and carries `rows` (the mask's width),
+# `eligible` (rows that pass), `clauses`, `postings` (posting entries the
+# keyword clauses scattered: work follows these, not the field's pairs; 0
+# without a keyword clause), `upload_bytes` (host -> device bytes of the
+# launch's mask; a keyword clause's own upload, one byte a row of its
+# segment, is not in it)
 FILTER_MASK = "filter.mask"
 
 # process

@@ -5,6 +5,8 @@ a keyword field of tags, at the shapes of the benchmark's `knn-filtered`
 law) and a tests' size. A node built the normal way answers as numpy over
 the eligible rows does, on the mesh road (one shard, the mesh program) and
 on the per-shard road (the mesh program switched off), two segments each.
+The span also says how much the filter worked (PR 36): `postings`, the sum of
+the request's tags' posting lengths, far under the field's (tag, row) pairs.
 
 The mechanism that makes a filtered request visible: the counters
 `knn.filter.requests` / `knn.filter.mask_bytes` (registered with the node,
@@ -252,6 +254,10 @@ def test_every_filtered_request_holds_one_filter_mask_span(
         assert got["eligible"] == len(_eligible(tags))
         assert got["clauses"] == len(tags)
         assert got["rows"] >= DOCS
+        # the work followed the tags' posting lists, not the field's pairs
+        assert got["postings"] == sum(
+            sum(t in bag for bag in BAGS) for t in tags)
+        assert got["postings"] * 3 < sum(len(bag) for bag in BAGS)
         # the mesh road uploads the mask it flattened on the host; the
         # per-shard road makes it where it is used
         assert got["upload_bytes"] == (got["rows"] if name == "mesh" else 0)

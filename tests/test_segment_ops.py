@@ -130,14 +130,16 @@ def test_range_filter_i64_beyond_int32(segment):
 
 def test_keyword_term_filter(segment):
     dev = to_device(segment)
-    kf = dev.keyword_fields["tag"]
-    host_kf = segment.keyword_fields["tag"]
-    q = host_kf.ord_dict["animal"]
-    mask = filters.term_mask_keyword(kf.mv_ords, kf.mv_docs, jnp.int32(q), dev.n_pad)
-    assert list(np.asarray(mask)[: segment.n_docs]) == [True, True, False, False]
+    kf = segment.keyword_fields["tag"]
+    assert kf.build_postings() and not kf.build_postings()
+    mask, postings = filters.keyword_mask_from_postings(
+        kf, (kf.ord_dict["animal"],), dev.n_pad)
+    assert mask.shape == (dev.n_pad,) and mask.dtype == bool
+    assert list(mask[: segment.n_docs]) == [True, True, False, False]
+    assert postings == 2
     # unknown term ordinal matches nothing
-    mask = filters.term_mask_keyword(kf.mv_ords, kf.mv_docs, jnp.int32(-3), dev.n_pad)
-    assert not np.asarray(mask).any()
+    mask, postings = filters.keyword_mask_from_postings(kf, (-3,), dev.n_pad)
+    assert not mask.any() and postings == 0
 
 
 def test_exact_knn_l2(segment):
