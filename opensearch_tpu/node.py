@@ -436,10 +436,22 @@ class TpuNode:
             "postings_builds": self.telemetry.metrics.counter(
                 "knn.filter.postings_builds"),
         }
+        # hybrid searches served, and what their (and any other query's)
+        # BM25 scoring launched: device launches and the posting entries
+        # of the terms they looked up (search/executor.py `_bm25`)
+        lexical = {
+            "hybrid_requests": self.telemetry.metrics.counter(
+                "search.hybrid.requests"),
+            "bm25_launches": self.telemetry.metrics.counter(
+                "search.bm25.launches"),
+            "bm25_postings": self.telemetry.metrics.counter(
+                "search.bm25.postings"),
+        }
         self.telemetry.tracer.capture_counters = lambda: {
             "knn_batch": dict(self.knn_batcher.stats),
             "knn_collect": {k: c.value for k, c in knn_collect.items()},
             "knn_filter": {k: c.value for k, c in knn_filter.items()},
+            "lexical": {k: c.value for k, c in lexical.items()},
             "device_resident_bytes": default_ledger.resident_bytes(),
             "device_resident_by_device": default_ledger.device_totals(),
             "device_backend_memory": backend_memory(),
@@ -3327,9 +3339,11 @@ class TpuNode:
         return body
 
     def _resolve_search_pipeline(
-        self, pipeline_id: str | None, index_names: list[str]
+        self, pipeline_id: str | dict | None, index_names: list[str]
     ) -> tuple[dict | None, dict | None]:
-        """Explicit search_pipeline param > index.search.default_pipeline.
+        """Explicit search_pipeline param > the body's `search_pipeline` (an
+        id, or a pipeline object: a temporary search pipeline, this
+        request's alone) > index.search.default_pipeline.
         Returns (pipeline, phase_results_config)."""
         if pipeline_id == "_none":
             return None, None
@@ -3345,7 +3359,7 @@ class TpuNode:
                     break
         if pipeline_id is None:
             return None, None
-        pl = self.search_pipelines.get(pipeline_id)
+        pl = self.search_pipelines.resolve(pipeline_id)
         return pl, self.search_pipelines.phase_results_config(pl)
 
     # -- reader contexts: scroll + point-in-time (ReaderContext registry) --

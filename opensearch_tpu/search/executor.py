@@ -85,12 +85,15 @@ def _knn_metrics():
     return tracing.active_metrics() or batcher_mod.default_batcher.metrics
 
 
-def _count_knn(name: str) -> None:
-    """One of the node's kNN counters by 1: `knn.collect.dense` / `.sparse`
-    (once a request and shard), `knn.filter.postings_builds`."""
+def count_metric(name: str, amount: int = 1) -> None:
+    """One of the node's query-phase counters: `knn.collect.dense` /
+    `.sparse` (once a request and shard), `knn.filter.postings_builds`,
+    `search.bm25.launches` / `.postings` (once a `_bm25` call; the posting
+    entries of the terms it looked up), `search.hybrid.requests` (once a
+    `hybrid` search, whatever its sub-queries and shards)."""
     metrics = _knn_metrics()
     if metrics is not None:
-        metrics.counter(name).add(1)
+        metrics.counter(name).add(amount)
 
 
 def count_knn_filter(requests: int, mask_bytes: int) -> None:
@@ -109,8 +112,25 @@ def _keyword_postings(kf):
     `knn.filter.postings_builds` counts the one build a segment and field,
     so that a build inside a measured window shows."""
     if kf.build_postings():
-        _count_knn("knn.filter.postings_builds")
+        count_metric("knn.filter.postings_builds")
     return kf
+
+
+BM25_TERM_ROWS = 4      # `_bm25` launches term rows in multiples of this
+
+_FULL_TEXT_NODES = (q.MatchQuery, q.MatchPhraseQuery, q.MatchPhrasePrefixQuery)
+
+
+def _bm25_span(query_node):
+    """The `bm25.score` detail span of one shard's query phase where the
+    query holds a full-text node; the no-op scope elsewhere, and wherever
+    the request is not detailed (the tree is only walked when it is)."""
+    scope = tracing.detail(span_names.BM25_SCORE)
+    if scope is tracing.NO_DETAIL or any(
+            isinstance(n, _FULL_TEXT_NODES)
+            for n in q.iter_query_nodes(query_node)):
+        return scope
+    return tracing.NO_DETAIL
 
 
 def filter_clauses(node) -> int:
@@ -181,6 +201,9 @@ class ShardContext:
         self.knn_dense = False
         # query_string trees are parsed once per shard, not per segment
         self._qs_cache: dict[int, Any] = {}
+        # what this request's BM25 launches on this shard looked up and
+        # gathered: the `bm25.score` span's attributes
+        self.bm25 = {"terms": 0, "postings": 0, "window": 0, "rows": 0}
 
     def rewritten_query_string(self, node) -> Any:
         """Parse a query_string/simple_query_string node's text once per
@@ -904,7 +927,7 @@ class HostNodeResult:
     def _dense(self) -> tuple[np.ndarray, np.ndarray]:
         if not self.ctx.knn_dense:
             self.ctx.knn_dense = True
-            _count_knn("knn.collect.dense")
+            count_metric("knn.collect.dense")
         scores = np.zeros(self.n_pad, np.float32)
         scores[self.docs] = self.doc_scores
         mask = np.zeros(self.n_pad, bool)
@@ -957,6 +980,22 @@ class SegmentExecutor:
                 lens.append(int(host_tf.term_offsets[tid + 1] - host_tf.term_offsets[tid]))
                 idfs.append(bm25.idf(self.ctx.text_df(field, t), doc_count))
         window = pad_window(max(lens) if lens else 1)
+        postings = sum(lens)
+        tally = self.ctx.bm25
+        tally["terms"] += len(terms)
+        tally["postings"] += postings
+        tally["window"] = max(tally["window"], window)
+        tally["rows"] += self.dev.n_pad
+        count_metric("search.bm25.launches")
+        count_metric("search.bm25.postings", postings)
+        # the launch's shapes follow the term rows: rows of length 0 (no
+        # posting, no score, no count) fill them up to a multiple of
+        # BM25_TERM_ROWS, so that queries of 3-20 terms meet five shapes to
+        # compile and not eighteen
+        pad = -len(terms) % BM25_TERM_ROWS
+        offs += [0] * pad
+        lens += [0] * pad
+        idfs += [0.0] * pad
         # per-term metadata stays HOST numpy here: these columns are the
         # only per-query host->device traffic of the BM25 path (postings
         # are HBM-resident), and the profiler counts transfer bytes from
@@ -2444,6 +2483,23 @@ def execute_query_phase(
     min_score: float | None = None,
 ) -> ShardQueryResult:
     ctx = ShardContext(snapshot, mapper_service)
+    with _bm25_span(query_node) as scored:
+        result = _query_phase(ctx, query_node, size, sort, need_masks,
+                              min_score)
+        for key, value in ctx.bm25.items():
+            scored.set_attribute(key, value)
+    return result
+
+
+def _query_phase(
+    ctx: ShardContext,
+    query_node: q.QueryNode,
+    size: int,
+    sort: list[dict] | None,
+    need_masks: bool,
+    min_score: float | None,
+) -> ShardQueryResult:
+    snapshot, mapper_service = ctx.snapshot, ctx.mapper_service
     masks: list[np.ndarray] = []
     score_arrays: list[np.ndarray] = []
     total = 0
@@ -2519,7 +2575,7 @@ def execute_query_phase(
             prof.collect_ns += time.perf_counter_ns() - t_collect
 
     if ctx._knn_cache and not ctx.knn_dense:
-        _count_knn("knn.collect.sparse")
+        count_metric("knn.collect.sparse")
     t_final = time.perf_counter_ns()
     if not sort:
         all_hits.sort(key=lambda h: (-h.score, h.segment, h.doc))

@@ -60,6 +60,19 @@ class SearchPipelineService:
             )
         return self.pipelines[pipeline_id]
 
+    def resolve(self, ref: str | dict) -> dict:
+        """The pipeline a search names: a stored one by its id, or the
+        object itself, a temporary search pipeline from the request body,
+        validated as `put` validates and kept by nobody."""
+        if isinstance(ref, dict):
+            self._validate(ref)
+            return ref
+        if not isinstance(ref, str):
+            raise IllegalArgumentException(
+                "[search_pipeline] must be a pipeline id or a pipeline object"
+            )
+        return self.get(ref)
+
     def delete(self, pipeline_id: str) -> None:
         if pipeline_id not in self.pipelines:
             raise ResourceNotFoundException(

@@ -40,6 +40,7 @@ from opensearch_tpu.search.executor import (
     _sort_key_fn,
     _sort_spec,
     _StrKey,
+    count_metric,
     execute_query_phase,
 )
 
@@ -196,7 +197,9 @@ def _search(
     mesh_premerged: list | None = None
     mesh_launch: dict | None = None
 
-    phase.enter(span_names.SEARCH_QUERY_PHASE)
+    phase.enter(span_names.SEARCH_QUERY_PHASE).set_attribute(
+        "sub_queries", len(node.queries)
+        if isinstance(node, query_dsl.HybridQuery) else 0)
     fetch_k = from_ + size
     if body.get("rescore") is not None:
         # the query phase must collect the full rescore window
@@ -246,9 +249,15 @@ def _search(
                 shard_query_ns.append(time.perf_counter_ns() - t_q)
                 shard_profilers.append(prof)
             shard_snaps.append((shard, snapshot))
-        fused = pipeline_mod.fuse_hybrid_results(
-            per_shard_subs, phase_results_config, fetch_k
-        )
+        with tracing.detail(span_names.HYBRID_FUSE) as fusing:
+            fused = pipeline_mod.fuse_hybrid_results(
+                per_shard_subs, phase_results_config, fetch_k
+            )
+            fusing.set_attribute("sub_queries", len(node.queries))
+            fusing.set_attribute("pooled", sum(
+                len(res.hits) for subs in per_shard_subs for res in subs))
+            fusing.set_attribute("shards", len(shards))
+        count_metric("search.hybrid.requests")
         per_shard_results = [
             (shard, snap, res)
             for (shard, snap), res in zip(shard_snaps, fused)

@@ -68,6 +68,23 @@ LAUNCH_HOST_POST = "launch.host_post"
 # segment, is not in it)
 FILTER_MASK = "filter.mask"
 
+# lexical scoring and hybrid fusion (DETAIL; beside `ALL` as `filter.mask`
+# is). `bm25.score` spans one query phase on one shard whose query holds a
+# full-text node (`match`, `match_phrase`, `match_phrase_prefix`): from its
+# terms' look-ups, through the BM25 launches of every segment, to the
+# shard's top-k on the host. It carries `terms` (term rows launched),
+# `postings` (the sum of those terms' posting lengths: what a scorer has to
+# read at least), `window` (the widest padded gather window, a launch works
+# over terms x window elements whatever the lists hold) and `rows` (the
+# score columns' width, n_pad summed over the segments). `hybrid.fuse`
+# spans `pipeline.fuse_hybrid_results`, the phase-results processor's
+# normalisation and combination on the host: `sub_queries`, `pooled` (hits
+# of all sub-queries and shards that went in), `shards`.
+# `search.query_phase` carries `sub_queries`: a hybrid query's count, 0 for
+# any other query
+BM25_SCORE = "bm25.score"
+HYBRID_FUSE = "hybrid.fuse"
+
 # process
 RUNTIME_GC = "runtime.gc"
 

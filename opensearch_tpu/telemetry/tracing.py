@@ -272,14 +272,15 @@ class _NoDetail:
     def __exit__(self, exc_type, exc, tb):
         return False
 
-    def enter(self, name: str) -> None:
-        pass
+    def enter(self, name: str) -> _NullSpan:
+        return _NULL_SPAN
 
     def close(self) -> None:
         pass
 
 
 _NO_DETAIL = _NoDetail()
+NO_DETAIL = _NO_DETAIL  # what `detail()` returns outside a detailed request
 
 
 class _DetailScope:
@@ -329,10 +330,10 @@ class _Phases:
         self._parent = parent
         self._scope: _DetailScope | None = None
 
-    def enter(self, name: str) -> None:
+    def enter(self, name: str) -> Span:
         self.close()
         self._scope = _DetailScope(name, self._parent)
-        self._scope.__enter__()
+        return self._scope.__enter__()
 
     def close(self) -> None:
         if self._scope is not None:

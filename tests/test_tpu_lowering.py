@@ -20,7 +20,9 @@ is the one that counts.
 
 Shapes are the ones `chip_smoke.py` launches: d = 128, k = 10; exact scan
 over n = 2^20 (phase A) and 2^18 rows; B in {1, 8, 16}; IVF-PQ defaults
-(nlist 128, m 8, ks 256) with nprobe 8 and 32.
+(nlist 128, m 8, ks 256) with nprobe 8 and 32. And the one the benchmark's
+`hybrid-bm25-knn` launches (PR 37): the exact scan at d = 768, the width
+text is embedded with, over 2^18 rows, fp32, B in {1, 8}.
 """
 
 from __future__ import annotations
@@ -48,15 +50,16 @@ def _sds(shape, dtype, sharding=None):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def knn_fused_case(n: int, b: int, precision: str, sharding=None):
+def knn_fused_case(n: int, b: int, precision: str, sharding=None,
+                   d: int = D):
     def f(vectors, norms_sq, valid, queries):
         return pallas_knn.knn_fused(
             vectors, norms_sq, valid, queries, k=K, similarity="l2_norm",
             score_precision=precision, impl="pallas", interpret=False)
 
     s = sharding
-    return f, (_sds((n, D), jnp.float32, s), _sds((n,), jnp.float32, s),
-               _sds((n,), jnp.bool_, s), _sds((b, D), jnp.float32, s))
+    return f, (_sds((n, d), jnp.float32, s), _sds((n,), jnp.float32, s),
+               _sds((n,), jnp.bool_, s), _sds((b, d), jnp.float32, s))
 
 
 def adc_case(b: int, nprobe: int, precision: str, sharding=None,
@@ -83,6 +86,9 @@ def adc_case(b: int, nprobe: int, precision: str, sharding=None,
 
 
 def all_cases(sharding=None):
+    for b in (1, 8):
+        yield (f"knn_fused[fp32] n={1 << 18} d=768 B={b}",
+               *knn_fused_case(1 << 18, b, "fp32", sharding, d=768))
     for precision in PRECISIONS:
         for b in BATCHES:
             for n in (1 << 20, 1 << 18):
