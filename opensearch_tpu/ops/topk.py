@@ -15,6 +15,8 @@ doc asc).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,9 +29,15 @@ BLOCKWISE_MIN_N = 32_768
 MAX_ITERATIVE_K = 128
 
 
+@functools.partial(jax.jit, static_argnames=("k",))
 def segment_top_k(scores: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(values [k], local_doc_ids [k]) — scores must already be -inf-masked
-    for non-matching / deleted / padding docs."""
+    for non-matching / deleted / padding docs.
+
+    ONE compiled program a (shape, k): run eagerly, `blockwise_topk` is
+    ~25 dispatches and two `fori_loop`s whose bodies are new functions
+    every call, so each call traced and lowered them again (PR 38's trace:
+    130-235 ms of host a loop, under the interpreter lock)."""
     if scores.ndim == 1:
         vals, ids = blockwise_topk(scores[None, :], k)
         return vals[0], ids[0]

@@ -253,9 +253,9 @@ def _model_mesh_fused(p: dict) -> tuple[int, int]:
 
 def _model_bm25(p: dict) -> tuple[int, int]:
     """BM25 postings scan (ops/bm25.bm25_term_scores): Q padded term
-    windows gathered + tf/norm math + scatter-add. 6 FLOPs per posting
-    slot; bytes = postings docs/tfs/doc-len gathers + scatter (16·Q·W) +
-    the dense [n_pad] score/count columns out (8·n_pad)."""
+    windows sliced + tf/norm math + scatter-add. 6 FLOPs per posting
+    slot; bytes = postings docs/tfs slices, the doc-len gather + scatter
+    (16·Q·W) + the dense [n_pad] score/count columns out (8·n_pad)."""
     q, window, n_pad = int(p["q"]), int(p["window"]), int(p["n_pad"])
     flops = 6 * q * window
     nbytes = 16 * q * window + 8 * n_pad

@@ -148,6 +148,25 @@ def test_term_rows_are_launched_in_multiples_of_four(world, monkeypatch):
         assert lengths[words:] == idfs[words:] == [0] * (n - words)
 
 
+def test_the_sixteen_questions_compile_five_programs_a_segment_at_most(world):
+    """A `_bm25` call is ONE compiled program, keyed by its term rows, its
+    window and the segment's shapes: the sixteen questions compile at most
+    five a segment, and served again they compile nothing."""
+    from opensearch_tpu.search import executor
+
+    node, _data, questions, _ref, _ids, _scores = world
+    program = executor.bm25.bm25_term_scores.__wrapped__
+    program.clear_cache()
+    for i in range(QUESTIONS):
+        _search(node, _body(questions, i))
+    compiled = program._cache_size()
+    segments = -(-DOCS // SEGMENT_DOCS)
+    assert 2 <= compiled <= 5 * segments
+    for i in range(QUESTIONS):
+        _search(node, _body(questions, i))
+    assert program._cache_size() == compiled
+
+
 def test_the_target_passage_leads_and_both_sub_queries_weigh_in(world):
     """A question is made from a passage's words and vector: that passage
     is the best of both pools (fused 1.0), and the rest of the top 10 comes

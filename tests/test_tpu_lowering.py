@@ -22,13 +22,18 @@ Shapes are the ones `chip_smoke.py` launches: d = 128, k = 10; exact scan
 over n = 2^20 (phase A) and 2^18 rows; B in {1, 8, 16}; IVF-PQ defaults
 (nlist 128, m 8, ks 256) with nprobe 8 and 32. And the one the benchmark's
 `hybrid-bm25-knn` launches (PR 37): the exact scan at d = 768, the width
-text is embedded with, over 2^18 rows, fp32, B in {1, 8}.
+text is embedded with, over 2^18 rows, fp32, B in {1, 8}. Its lexical
+half is no Pallas kernel but one XLA program (`ops/bm25.bm25_term_scores`,
+PR 38): layer 2 compiles it at the cell's shapes (2^25 posting entries,
+2^18 rows, a window of 2^18, 8 and 20 term rows) and reads the compiled
+program: no element gather over a posting column may be left in it.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -38,7 +43,7 @@ import pytest
 jnp = pytest.importorskip("jax.numpy")
 import jax
 
-from opensearch_tpu.ops import ivfpq, pallas_adc, pallas_knn
+from opensearch_tpu.ops import bm25, ivfpq, pallas_adc, pallas_knn
 
 REPO = Path(__file__).resolve().parent.parent
 D, K = 128, 10
@@ -129,6 +134,27 @@ def test_mosaic_compiles_served_kernels_for_v5e():
     assert report["failed"] == {}, json.dumps(report["failed"], indent=1)
 
 
+def bm25_case(rows: int, sharding=None, postings: int = 1 << 25,
+              n: int = 1 << 18):
+    def f(*args):
+        return bm25.bm25_term_scores(*args, n_pad=n, window=n)
+
+    s = sharding
+    return f, (_sds((postings,), jnp.int32, s), _sds((postings,), jnp.float32, s),
+               _sds((n,), jnp.float32, s), _sds((rows,), jnp.int32, s),
+               _sds((rows,), jnp.int32, s), _sds((rows,), jnp.float32, s),
+               _sds((), jnp.float32, s))
+
+
+def _posting_column_gathers(hlo: str, postings: int = 1 << 25) -> list[str]:
+    """The compiled program's gathers whose operand is a posting column
+    (HLO names an operand; its shape stands where it is defined)."""
+    shape = dict(re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]", hlo))
+    return [ln.strip()[:200] for ln in hlo.splitlines()
+            for operand in re.findall(r" gather\((%[\w.-]+),", ln)
+            if shape.get(operand) == str(postings)]
+
+
 def _compile_all_for_v5e() -> dict:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -140,13 +166,21 @@ def _compile_all_for_v5e() -> dict:
         return {"skip": f"no compile-only v5e topology: {str(e)[:200]}"}
     sharding = SingleDeviceSharding(topology.devices[0])
     failed, compiled = {}, 0
-    for name, f, args in all_cases(sharding):
+    lexical = {f"bm25_term_scores rows={rows}": bm25_case(rows, sharding)
+               for rows in (8, 20)}
+    cases = [*all_cases(sharding),
+             *((name, f, args) for name, (f, args) in lexical.items())]
+    for name, f, args in cases:
         try:
-            jax.jit(f).trace(*args).lower(
+            program = jax.jit(f).trace(*args).lower(
                 lowering_platforms=("tpu",)).compile()
             compiled += 1
         except Exception as e:  # noqa: BLE001 - reported per kernel
             failed[name] = str(e)[:600]
+            continue
+        if name in lexical and (
+                gathers := _posting_column_gathers(program.as_text())):
+            failed[name] = f"a posting column is gathered: {gathers}"
     return {"compiled": compiled, "failed": failed}
 
 
