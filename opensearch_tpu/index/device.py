@@ -59,6 +59,9 @@ class DeviceTextField:
     postings_docs: jnp.ndarray    # int32 [P_pad]
     postings_tfs: jnp.ndarray     # float32 [P_pad]
     doc_len: jnp.ndarray          # float32 [n_pad]
+    # the row width at which every rung of the BM25 launch's ladder has
+    # been compiled for this field (search/executor.py `_bm25`); 0: none
+    ladder_width: int = 0
 
 
 @dataclass

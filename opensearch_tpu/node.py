@@ -437,8 +437,9 @@ class TpuNode:
                 "knn.filter.postings_builds"),
         }
         # hybrid searches served, and what their (and any other query's)
-        # BM25 scoring launched: device launches and the posting entries
-        # of the terms they looked up (search/executor.py `_bm25`)
+        # BM25 scoring launched: device launches, the posting entries of
+        # the terms they looked up and the row entries the launches worked
+        # over (search/executor.py `_bm25`)
         lexical = {
             "hybrid_requests": self.telemetry.metrics.counter(
                 "search.hybrid.requests"),
@@ -446,6 +447,8 @@ class TpuNode:
                 "search.bm25.launches"),
             "bm25_postings": self.telemetry.metrics.counter(
                 "search.bm25.postings"),
+            "bm25_entries": self.telemetry.metrics.counter(
+                "search.bm25.entries"),
         }
         self.telemetry.tracer.capture_counters = lambda: {
             "knn_batch": dict(self.knn_batcher.stats),

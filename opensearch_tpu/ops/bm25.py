@@ -5,15 +5,20 @@ SURVEY.md §3.2: search/internal/ContextIndexSearcher.java:242 driving
 BM25Similarity) with a vectorized formulation, traced once a
 (term rows, window, n_pad, P_pad) and launched as ONE XLA program:
 
-for each query term q (padded to a static Q):
+for each term row (a query term's postings, or one chunk of them: the
+caller, search/executor.py `_bm25`, cuts a list longer than `window` into
+rows of at most `window` entries and pads the rows to a few fixed counts):
     read its postings (docs, tfs) as the contiguous slice of `window`
     entries they are in the flat CSR columns (`lax.dynamic_slice` at the
-    row's offset: no [Q, window] index array, no general gather),
+    row's offset: no [rows, window] index array, no general gather),
     compute idf * tf / (tf + k1*(1 - b + b*dl/avgdl)) on the VPU,
     scatter-add contributions into a dense [n_pad] score column.
 
-Only (offset, length, idf) per query term crosses host→device at query time;
-postings stay resident in HBM. Scoring ends in jax.lax.top_k downstream.
+A launch works over rows x window entries whatever its lists hold, so the
+caller keeps both near the lists: `window` is the longest list's padded
+length or the chunk width, whichever is less. Only (offset, length, idf) a
+row crosses host→device at query time; postings stay resident in HBM.
+Scoring ends in jax.lax.top_k downstream.
 
 Scoring math matches Lucene's BM25Similarity (idf = ln(1 + (N-df+0.5)/(df+0.5)))
 with exact doc lengths instead of Lucene's lossy SmallFloat norm encoding —
@@ -78,21 +83,24 @@ def bm25_term_scores(
     postings_docs: jnp.ndarray,   # int32 [P_pad] flat CSR postings
     postings_tfs: jnp.ndarray,    # float32 [P_pad]
     doc_len: jnp.ndarray,         # float32 [n_pad]
-    offsets: jnp.ndarray,         # int32 [Q] per-query-term start into postings
-    lengths: jnp.ndarray,         # int32 [Q] per-query-term postings count
-    idfs: jnp.ndarray,            # float32 [Q] precomputed idf weights
+    offsets: jnp.ndarray,         # int32 [Q] per-row start into postings
+    lengths: jnp.ndarray,         # int32 [Q] per-row postings count (<= window)
+    idfs: jnp.ndarray,            # float32 [Q] the row's term's idf weight
     avgdl: jnp.ndarray,           # float32 scalar (shard-level average doc len)
     n_pad: int,                   # static: padded doc-column size
-    window: int,                  # static: padded per-term postings window
+    window: int,                  # static: entries a row reads
     k1: float = K1_DEFAULT,
     b: float = B_DEFAULT,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (scores [n_pad] f32, match_counts [n_pad] i32).
 
-    match_counts[d] = number of query terms matching doc d — the bool-query
+    match_counts[d] = number of rows holding doc d: the number of query
+    terms matching it, for the rows of one term are disjoint — the bool-query
     building block (must => count == n_required, should => count >= minimum).
-    Terms whose postings exceed `window` must be split by the caller into
-    multiple (offset, length) rows; idf weight rides along unchanged.
+    A row is one term's postings or a chunk of them: the caller
+    (search/executor.py `_bm25`) cuts a list longer than `window` into rows
+    of at most `window` entries, each with the term's idf, and fills the
+    launch up with rows of length 0, which score and count nothing.
     """
     valid, docs, tfs = _term_rows(offsets, lengths, window,
                                   postings_docs, postings_tfs)

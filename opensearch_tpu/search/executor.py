@@ -88,8 +88,9 @@ def _knn_metrics():
 def count_metric(name: str, amount: int = 1) -> None:
     """One of the node's query-phase counters: `knn.collect.dense` /
     `.sparse` (once a request and shard), `knn.filter.postings_builds`,
-    `search.bm25.launches` / `.postings` (once a `_bm25` call; the posting
-    entries of the terms it looked up), `search.hybrid.requests` (once a
+    `search.bm25.launches` / `.postings` / `.entries` (once a `_bm25` call;
+    the posting entries of the terms it looked up; the row entries its
+    launches worked over), `search.hybrid.requests` (once a
     `hybrid` search, whatever its sub-queries and shards)."""
     metrics = _knn_metrics()
     if metrics is not None:
@@ -116,7 +117,64 @@ def _keyword_postings(kf):
     return kf
 
 
-BM25_TERM_ROWS = 4      # `_bm25` launches term rows in multiples of this
+# `_bm25`'s launch layout. A term's list is cut into rows of at most
+# BM25_CHUNK entries, so that a row is as long as its own list and not the
+# longest of the query's; a launch's row count is padded up to the least
+# rung that holds it (2^k and 1.5 * 2^k: a few shapes, and padding costs at
+# most half again), and rows beyond the last rung go in further launches
+BM25_CHUNK = 16384
+BM25_ROW_RUNGS = (8, 12, 16, 24, 32, 48, 64, 96, 128)
+
+
+def _chunk_rows(offsets: np.ndarray, lengths: np.ndarray, idfs: np.ndarray,
+                width: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Each term's list as rows of at most `width` entries, in order, every
+    row with its term's idf; an empty list is one row of length 0. The rows
+    of one term are disjoint, so a document still counts a term once."""
+    per = np.maximum(1, -(-lengths // width))
+    term = np.repeat(np.arange(len(lengths)), per)
+    at = (np.arange(len(term)) - np.repeat(np.cumsum(per) - per, per)) * width
+    return offsets[term] + at, np.minimum(lengths[term] - at, width), idfs[term]
+
+
+def _padded(values: np.ndarray, n: int, dtype) -> np.ndarray:
+    out = np.zeros(n, dtype)
+    out[: len(values)] = values
+    return out
+
+
+def _add_launch(total, launched):
+    """(scores, counts) of a `_bm25` call's launches so far, plus one more
+    launch's."""
+    if total is None:
+        return launched
+    return total[0] + launched[0], total[1] + launched[1]
+
+
+_ladder_lock = threading.Lock()
+
+
+def _compile_ladder(dev_tf, n_pad: int, width: int) -> None:
+    """Launch every rung once at `width`, rows of length 0, and add the
+    launches up, the first time this field is scored at that width: the
+    rare rungs and the sum of a call of several launches are compiled by
+    the first full-text search the segment serves and not by one long
+    after, and searches that arrive together wait here instead of
+    compiling."""
+    if dev_tf.ladder_width == width:
+        return
+    with _ladder_lock:
+        if dev_tf.ladder_width == width:
+            return
+        total = None
+        for rung in BM25_ROW_RUNGS:
+            rows = np.zeros(rung, np.int32)
+            total = _add_launch(total, bm25.bm25_term_scores(
+                dev_tf.postings_docs, dev_tf.postings_tfs, dev_tf.doc_len,
+                rows, rows, np.zeros(rung, np.float32), np.float32(1.0),
+                n_pad=n_pad, window=width))
+        dev_tf.ladder_width = width
+
 
 _FULL_TEXT_NODES = (q.MatchQuery, q.MatchPhraseQuery, q.MatchPhrasePrefixQuery)
 
@@ -203,7 +261,8 @@ class ShardContext:
         self._qs_cache: dict[int, Any] = {}
         # what this request's BM25 launches on this shard looked up and
         # gathered: the `bm25.score` span's attributes
-        self.bm25 = {"terms": 0, "postings": 0, "window": 0, "rows": 0}
+        self.bm25 = {"terms": 0, "postings": 0, "entries": 0, "window": 0,
+                     "rows": 0}
 
     def rewritten_query_string(self, node) -> Any:
         """Parse a query_string/simple_query_string node's text once per
@@ -979,38 +1038,46 @@ class SegmentExecutor:
                 offs.append(int(host_tf.term_offsets[tid]))
                 lens.append(int(host_tf.term_offsets[tid + 1] - host_tf.term_offsets[tid]))
                 idfs.append(bm25.idf(self.ctx.text_df(field, t), doc_count))
-        window = pad_window(max(lens) if lens else 1)
-        postings = sum(lens)
+        width = min(BM25_CHUNK, pad_window(max(lens)))
+        offs, lens, idfs = _chunk_rows(np.asarray(offs, np.int64),
+                                       np.asarray(lens, np.int64),
+                                       np.asarray(idfs, np.float32), width)
+        if width == BM25_CHUNK:
+            _compile_ladder(dev_tf, self.dev.n_pad, width)
+        most = BM25_ROW_RUNGS[-1]
+        launches = [slice(lo, lo + most) for lo in range(0, len(offs), most)]
+        rungs = [next(r for r in BM25_ROW_RUNGS if r >= len(offs[part]))
+                 for part in launches]
+        postings = int(lens.sum())
+        entries = sum(rungs) * width
         tally = self.ctx.bm25
         tally["terms"] += len(terms)
         tally["postings"] += postings
-        tally["window"] = max(tally["window"], window)
+        tally["entries"] += entries
+        tally["window"] = max(tally["window"], width)
         tally["rows"] += self.dev.n_pad
         count_metric("search.bm25.launches")
         count_metric("search.bm25.postings", postings)
-        # the launch's shapes follow the term rows: rows of length 0 (no
-        # posting, no score, no count) fill them up to a multiple of
-        # BM25_TERM_ROWS, so that queries of 3-20 terms meet five shapes to
-        # compile and not eighteen
-        pad = -len(terms) % BM25_TERM_ROWS
-        offs += [0] * pad
-        lens += [0] * pad
-        idfs += [0.0] * pad
-        # per-term metadata stays HOST numpy here: these columns are the
-        # only per-query host->device traffic of the BM25 path (postings
-        # are HBM-resident), and the profiler counts transfer bytes from
-        # host-typed kernel arguments
-        scores, counts = bm25.bm25_term_scores(
-            dev_tf.postings_docs,
-            dev_tf.postings_tfs,
-            dev_tf.doc_len,
-            np.asarray(offs, np.int32),
-            np.asarray(lens, np.int32),
-            np.asarray(idfs, np.float32),
-            np.float32(avgdl),
-            n_pad=self.dev.n_pad,
-            window=window,
-        )
+        count_metric("search.bm25.entries", entries)
+        total = None
+        for part, rung in zip(launches, rungs):
+            # rows of length 0 (no posting, no score, no count) fill the
+            # launch up to its rung. Row metadata stays HOST numpy: it is
+            # the only per-query host->device traffic of the BM25 path
+            # (postings are HBM-resident), and the profiler counts transfer
+            # bytes from host-typed kernel arguments
+            total = _add_launch(total, bm25.bm25_term_scores(
+                dev_tf.postings_docs,
+                dev_tf.postings_tfs,
+                dev_tf.doc_len,
+                _padded(offs[part], rung, np.int32),
+                _padded(lens[part], rung, np.int32),
+                _padded(idfs[part], rung, np.float32),
+                np.float32(avgdl),
+                n_pad=self.dev.n_pad,
+                window=width,
+            ))
+        scores, counts = total
         mask = counts > 0
         return NodeResult(scores=scores * boost, mask=mask, scoring=True), counts
 

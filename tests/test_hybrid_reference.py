@@ -10,8 +10,10 @@ Also here: the temporary search pipeline (`"search_pipeline": {...}` in the
 request body: resolved where an id is, validated, stored nowhere), and what
 makes the path visible: the detail spans `bm25.score` and `hybrid.fuse`,
 `search.query_phase`'s `sub_queries`, and the counters
-`search.hybrid.requests`, `search.bm25.launches`, `search.bm25.postings`
-(registered with the node, so a 0 shows).
+`search.hybrid.requests`, `search.bm25.launches`, `search.bm25.postings`,
+`search.bm25.entries` (registered with the node, so a 0 shows); and the
+launch's layout: a term's list cut into rows of at most `BM25_CHUNK`
+entries, rows padded up the ladder `BM25_ROW_RUNGS`.
 
 Every profiler session here starts and stops inside a test of this file's
 own process; nothing touches the profiler at import time."""
@@ -81,6 +83,8 @@ def world(tmp_path_factory):
     node = TpuNode(home / "node")
     # registered with the node: a 0 is shown as a 0, before any request
     assert _counters(node) == (0, 0, 0)
+    assert node.telemetry.metrics.stats()["counters"][
+        "search.bm25.entries"] == 0
     node.create_index(INDEX, CONF["index_body"])
     acked = 0
     for lo, n, body in KIND.bulk_bodies(CONF, data):
@@ -121,37 +125,66 @@ def test_the_served_fused_top_10_is_the_references(world, i):
     assert set(first) == {CONF["text_field"]}
 
 
-def test_term_rows_are_launched_in_multiples_of_four(world, monkeypatch):
-    """Questions of 3 to 20 words meet five launch shapes, not eighteen:
-    `_bm25` fills the term rows up with rows of length 0, which score
-    nothing (the sixteen answers above are served through it)."""
+def _launches(monkeypatch) -> list:
+    """Every BM25 launch from here on, as (rows, lengths, idfs, width)."""
     from opensearch_tpu.search import executor
 
-    node, _data, questions, _ref, _ids, _scores = world
-    rows = []
+    launched = []
     launch = executor.bm25.bm25_term_scores
 
     def recorded(docs, tfs, doc_len, offsets, lengths, idfs, *args, **kw):
-        rows.append((len(offsets), lengths.tolist(), idfs.tolist()))
+        launched.append((len(offsets), lengths.tolist(), idfs.tolist(),
+                         kw["window"]))
         return launch(docs, tfs, doc_len, offsets, lengths, idfs, *args, **kw)
 
     monkeypatch.setattr(executor.bm25, "bm25_term_scores", recorded)
+    return launched
+
+
+def _entries_bound(lengths: list, terms: int, width: int) -> float:
+    """What a `_bm25` call may launch: half again over its lists cut into
+    rows of `width` (a row a term at least), or the least rung."""
+    from opensearch_tpu.search import executor
+
+    return max(1.5 * (sum(lengths) + terms * width),
+               executor.BM25_ROW_RUNGS[0] * width)
+
+
+def test_term_rows_are_launched_in_multiples_of_four(world, monkeypatch):
+    """A launch's term rows are a rung of the ladder (8, 12, 16, 24, ...:
+    every one a multiple of four), so questions of 3 to 20 words meet four
+    row counts, not eighteen: `_bm25` fills the rows up with rows of length
+    0, which score nothing (the sixteen answers above are served through
+    it). Where every list fits one row, as in these segments, a term is one
+    row as wide as the question's longest list, and what the launches work
+    over stays within half again of the lists cut into rows."""
+    from opensearch_tpu.index.segment import pad_window
+    from opensearch_tpu.search import executor
+
+    node, _data, questions, _ref, _ids, _scores = world
+    counted = node.telemetry.metrics.counter("search.bm25.entries")
+    before = counted.value
+    rows = _launches(monkeypatch)
     segments = -(-DOCS // SEGMENT_DOCS)
     for i in range(QUESTIONS):
         _search(node, _body(questions, i))
     assert len(rows) == QUESTIONS * segments
-    assert {n for n, _lengths, _idfs in rows} <= {4, 8, 12, 16, 20}
-    assert len({n for n, _lengths, _idfs in rows}) >= 2
-    for at, (n, lengths, idfs) in enumerate(rows):
+    assert {n for n, *_rest in rows} <= set(executor.BM25_ROW_RUNGS)
+    assert all(n % 4 == 0 for n in executor.BM25_ROW_RUNGS)
+    assert len({n for n, *_rest in rows}) >= 2
+    for at, (n, lengths, idfs, width) in enumerate(rows):
         words = len(questions.words[at // segments])
-        assert n == -(-words // executor.BM25_TERM_ROWS) * 4
+        assert n == min(r for r in executor.BM25_ROW_RUNGS if r >= words)
         assert lengths[words:] == idfs[words:] == [0] * (n - words)
+        assert width == pad_window(max(lengths)) < executor.BM25_CHUNK
+        assert n * width <= _entries_bound(lengths, words, width)
+    assert counted.value - before == sum(n * w for n, _l, _i, w in rows)
 
 
 def test_the_sixteen_questions_compile_five_programs_a_segment_at_most(world):
-    """A `_bm25` call is ONE compiled program, keyed by its term rows, its
-    window and the segment's shapes: the sixteen questions compile at most
-    five a segment, and served again they compile nothing."""
+    """A `_bm25` call is ONE compiled program a launch, keyed by its rung,
+    its row width and the segment's shapes: the sixteen questions compile at
+    most five a segment, and served again they compile nothing."""
     from opensearch_tpu.search import executor
 
     node, _data, questions, _ref, _ids, _scores = world
@@ -165,6 +198,40 @@ def test_the_sixteen_questions_compile_five_programs_a_segment_at_most(world):
     for i in range(QUESTIONS):
         _search(node, _body(questions, i))
     assert program._cache_size() == compiled
+
+
+@pytest.mark.parametrize("chunk", [64, 16])
+def test_lists_cut_into_rows_of_a_small_chunk_answer_as_the_reference(
+        world, monkeypatch, chunk):
+    """A chunk width far under these segments' longest lists: every term
+    whose list is longer is launched as several rows of its idf (at 16,
+    more rows than a launch holds, so further launches add up), the rungs
+    of the ladder are compiled by the first search, and the served fused
+    top 10 is still the reference's."""
+    from opensearch_tpu.search import executor
+
+    node, data, questions, ref, ids, scores = world
+    monkeypatch.setattr(executor, "BM25_CHUNK", chunk)
+    _search(node, _body(questions, 0))          # compiles the ladder
+    program = executor.bm25.bm25_term_scores.__wrapped__
+    compiled = program._cache_size()
+    launches0 = _counters(node)[1]
+    rows = _launches(monkeypatch)
+    for i in range(QUESTIONS):
+        got_ids, got_scores = _hits(_search(node, _body(questions, i)))
+        assert got_ids == ids[i].tolist()
+        assert got_scores == pytest.approx(scores[i].tolist(), abs=2e-5)
+    assert program._cache_size() == compiled
+    calls = _counters(node)[1] - launches0
+    assert {w for *_rest, w in rows} == {chunk}
+    assert {n for n, *_rest in rows} <= set(executor.BM25_ROW_RUNGS)
+    assert max(max(lengths) for _n, lengths, _i, _w in rows) == chunk
+    if chunk == 16:
+        assert len(rows) > calls      # a call of more rows than a launch
+    # every posting entry of the questions' words, each in one row
+    df = np.diff(data["postings"].ptr)
+    assert sum(sum(lengths) for _n, lengths, _i, _w in rows) == sum(
+        int(df[w]) for i in range(QUESTIONS) for w in questions.words[i])
 
 
 def test_the_target_passage_leads_and_both_sub_queries_weigh_in(world):
@@ -315,9 +382,13 @@ def test_a_capture_holds_bm25_score_and_hybrid_fuse_in_every_request(
         assert scored["attributes"]["terms"] == len(words) * segments
         assert scored["attributes"]["postings"] == sum(
             int(df[w]) for w in words)
-        # a launch gathers terms x window elements whatever the lists hold
+        # a launch gathers rows x window elements whatever the lists hold
         assert scored["attributes"]["window"] >= max(
             int(df[w]) for w in words) / segments
+        assert scored["attributes"]["postings"] <= scored["attributes"][
+            "entries"] <= segments * _entries_bound(
+                [scored["attributes"]["postings"]], len(words),
+                scored["attributes"]["window"])
         assert scored["attributes"]["rows"] >= DOCS
         (fused,) = by_name[span_names.HYBRID_FUSE]
         assert fused["attributes"] == {
@@ -345,6 +416,9 @@ def test_a_capture_holds_bm25_score_and_hybrid_fuse_in_every_request(
         opened["bm25_launches"] + len(sent) * segments)
     assert closed["bm25_postings"] - opened["bm25_postings"] == sum(
         int(df[w]) for i in sent for w in questions.words[i])
+    assert closed["bm25_entries"] - opened["bm25_entries"] == sum(
+        s["attributes"]["entries"] for s in doc["spans"]
+        if s["name"] == span_names.BM25_SCORE)
 
 
 def test_the_new_spans_are_detail_spans_kept_beside_all():

@@ -131,50 +131,87 @@ def _bm25_loop(docs, tfs, doc_len, offsets, lengths, idfs, avgdl, n_pad,
     return scores, counts
 
 
+def _split(offset, ids, width):
+    """One term's list as the caller launches it: rows of at most `width`
+    entries, in order (search/executor.py `_chunk_rows`)."""
+    return [(offset + at, ids[at:at + width]) for at in range(0, len(ids), width)]
+
+
 def _case(name):
-    """(p_pad, n_pad, window, [(offset, doc ids)] a term row)."""
+    """(p_pad, n_pad, window, terms): a term is its rows [(offset, doc ids)],
+    all with the term's idf."""
     rng = np.random.default_rng(38)
     pick = lambda n_pad, n: np.sort(rng.choice(n_pad, n, replace=False)).astype(np.int32)
-    if name == "rows_of_length_0_between_real_ones":   # BM25_TERM_ROWS' padding
+    if name == "rows_of_length_0_between_real_ones":   # a rung's padding
         rows = [(3, pick(32, 5)), (0, []), (20, pick(32, 16)), (0, []),
                 (40, pick(32, 9)), (50, pick(32, 3)), (0, []), (0, [])]
-        return 64, 32, 16, rows
+        return 64, 32, 16, [[row] for row in rows]
     if name == "a_list_ends_at_the_columns_last_entry":  # offset + window > P_pad
         rows = [(64 - 10, pick(32, 10)), (5, pick(32, 16)), (0, []), (0, [])]
-        return 64, 32, 16, rows
+        return 64, 32, 16, [[row] for row in rows]
     if name == "window_equals_p_pad":
         rows = [(0, pick(8, 3)), (3, pick(8, 5)), (0, []), (0, [])]
-        return 8, 8, 8, rows
+        return 8, 8, 8, [[row] for row in rows]
     if name == "window_over_p_pad":
         rows = [(0, pick(16, 16)), (0, []), (0, []), (0, [])]
-        return 16, 16, 32, rows
+        return 16, 16, 32, [[row] for row in rows]
     if name == "two_terms_share_documents":
         shared = pick(64, 12)
         rows = [(7, shared), (100, shared[::2].copy()), (30, pick(64, 20)),
                 (0, [])]
-        return 128, 64, 32, rows
+        return 128, 64, 32, [[row] for row in rows]
+    if name == "a_list_longer_than_the_window_split_into_rows":
+        shared = pick(128, 45)
+        return 256, 128, 16, [_split(30, shared, 16),
+                              [(120, shared[::5].copy())],
+                              [(0, [])] * 4]
+    if name == "a_split_list_ends_at_the_columns_last_entry":
+        return 64, 64, 16, [_split(24, pick(64, 40), 16), [(3, pick(64, 9))],
+                            [(0, [])] * 4]
+    if name == "ladder_padding_between_a_split_terms_rows":
+        (a, b, c), ids = _split(8, pick(64, 40), 16), pick(64, 11)
+        return 128, 64, 16, [[a, (0, []), b, (0, []), (0, []), c], [(0, [])],
+                             [(90, ids), (0, []), (0, [])], [(0, [])] * 2]
     raise AssertionError(name)
 
 
 BM25_CASES = ("rows_of_length_0_between_real_ones",
               "a_list_ends_at_the_columns_last_entry", "window_equals_p_pad",
-              "window_over_p_pad", "two_terms_share_documents")
+              "window_over_p_pad", "two_terms_share_documents",
+              "a_list_longer_than_the_window_split_into_rows",
+              "a_split_list_ends_at_the_columns_last_entry",
+              "ladder_padding_between_a_split_terms_rows")
 
 
 def _launch_args(name, metadata):
-    p_pad, n_pad, window, rows = _case(name)
+    p_pad, n_pad, window, terms = _case(name)
     rng = np.random.default_rng(len(name))
+    rows = [row for term in terms for row in term]
     docs, tfs = _columns(p_pad, n_pad, [(o, ids) for o, ids in rows if len(ids)], rng)
     doc_len = rng.integers(1, 60, n_pad).astype(np.float32)
     offsets = np.asarray([o for o, _ids in rows], np.int32)
     lengths = np.asarray([len(ids) for _o, ids in rows], np.int32)
-    idfs = np.where(lengths > 0, rng.random(len(rows)) + 0.1, 0.0).astype(np.float32)
+    idfs = np.asarray([idf if len(ids) else 0.0
+                       for term, idf in zip(terms, rng.random(len(terms)) + 0.1)
+                       for _o, ids in term], np.float32)
     avgdl = np.float32(doc_len.mean())
     host = (docs, tfs, doc_len, offsets, lengths, idfs, avgdl)
     as_meta = jnp.asarray if metadata == "device" else np.asarray
     device = (jnp.asarray(docs), jnp.asarray(tfs), jnp.asarray(doc_len),
               as_meta(offsets), as_meta(lengths), as_meta(idfs), avgdl)
     return host, device, n_pad, window
+
+
+def _terms_holding(name, n_pad):
+    """counts[d] as the terms see it: how many terms' lists hold d, however
+    each list was cut into rows."""
+    _p_pad, _n_pad, _window, terms = _case(name)
+    held = np.zeros(n_pad, np.int32)
+    for term in terms:
+        ids = [d for _o, row in term for d in row]
+        assert len(set(ids)) == len(ids)
+        held[np.asarray(ids, np.int64)] += 1
+    return held
 
 
 @pytest.mark.parametrize("metadata", ["host", "device"])
@@ -186,6 +223,8 @@ def test_bm25_term_scores_is_the_loop_over_rows_and_postings(name, metadata):
     assert scores.dtype == jnp.float32 and counts.dtype == jnp.int32
     assert np.asarray(counts).tolist() == want_counts.tolist()
     assert want_counts.sum() == host[4].sum() > 0
+    # a term cut into several rows still counts once a document
+    assert want_counts.tolist() == _terms_holding(name, n_pad).tolist()
     np.testing.assert_allclose(np.asarray(scores), want_scores, rtol=1e-6, atol=0)
 
 

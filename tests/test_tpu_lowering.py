@@ -25,8 +25,9 @@ over n = 2^20 (phase A) and 2^18 rows; B in {1, 8, 16}; IVF-PQ defaults
 text is embedded with, over 2^18 rows, fp32, B in {1, 8}. Its lexical
 half is no Pallas kernel but one XLA program (`ops/bm25.bm25_term_scores`,
 PR 38): layer 2 compiles it at the cell's shapes (2^25 posting entries,
-2^18 rows, a window of 2^18, 8 and 20 term rows) and reads the compiled
-program: no element gather over a posting column may be left in it.
+2^18 rows, rows of `executor.BM25_CHUNK` entries, the ladder's least and
+greatest rungs of term rows) and reads the compiled program: no element
+gather over a posting column may be left in it.
 """
 
 from __future__ import annotations
@@ -44,6 +45,7 @@ jnp = pytest.importorskip("jax.numpy")
 import jax
 
 from opensearch_tpu.ops import bm25, ivfpq, pallas_adc, pallas_knn
+from opensearch_tpu.search import executor
 
 REPO = Path(__file__).resolve().parent.parent
 D, K = 128, 10
@@ -135,9 +137,9 @@ def test_mosaic_compiles_served_kernels_for_v5e():
 
 
 def bm25_case(rows: int, sharding=None, postings: int = 1 << 25,
-              n: int = 1 << 18):
+              n: int = 1 << 18, window: int = executor.BM25_CHUNK):
     def f(*args):
-        return bm25.bm25_term_scores(*args, n_pad=n, window=n)
+        return bm25.bm25_term_scores(*args, n_pad=n, window=window)
 
     s = sharding
     return f, (_sds((postings,), jnp.int32, s), _sds((postings,), jnp.float32, s),
@@ -167,7 +169,8 @@ def _compile_all_for_v5e() -> dict:
     sharding = SingleDeviceSharding(topology.devices[0])
     failed, compiled = {}, 0
     lexical = {f"bm25_term_scores rows={rows}": bm25_case(rows, sharding)
-               for rows in (8, 20)}
+               for rows in (executor.BM25_ROW_RUNGS[0],
+                            executor.BM25_ROW_RUNGS[-1])}
     cases = [*all_cases(sharding),
              *((name, f, args) for name, (f, args) in lexical.items())]
     for name, f, args in cases:
