@@ -11,10 +11,13 @@ Padding invariants relied on by the ops kernels:
 - doc column index in [0, n_pad); docs >= n_docs are padding (live=False)
 - postings arrays padded with zeros (never addressed: window mask guards)
 - keyword CSR padded with ord=-2, doc=0 (ord -2 matches no query ordinal)
+- a multi-vector field's rows pad to their own bucketed r_pad, padding
+  rows with parent -1 and valid False
 """
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from dataclasses import dataclass
@@ -95,6 +98,31 @@ class DeviceVectorField:
 
 
 @dataclass
+class DeviceNestedVectorField:
+    """A multi-vector field on the device: its rows, each row's document,
+    and which rows may answer (`valid`: the row's document is live), made
+    again whenever the segment's live bitmap is republished, so a deleted
+    document's rows score as dead."""
+
+    vectors: jnp.ndarray          # float32 [r_pad, dims]
+    norms_sq: jnp.ndarray         # float32 [r_pad]
+    parent: jnp.ndarray           # int32 [r_pad], padding rows -1
+    valid: jnp.ndarray            # bool [r_pad]
+    rows: int                     # rows before padding
+    dims: int
+    similarity: str
+    parent_host: np.ndarray       # int32 [rows]: what `valid` is made from
+
+
+def _nested_valid(parent: np.ndarray, live: np.ndarray,
+                  r_pad: int) -> np.ndarray:
+    """bool [r_pad]: the rows whose document is live (padding: False)."""
+    valid = np.zeros(r_pad, dtype=bool)
+    valid[: len(parent)] = live[parent]
+    return valid
+
+
+@dataclass
 class DeviceSegment:
     name: str
     n_docs: int
@@ -110,6 +138,7 @@ class DeviceSegment:
     allocations: dict | None = None
     # the chip every column above was put on (None: the default device)
     device: object | None = None
+    nested_vector_fields: dict[str, DeviceNestedVectorField] | None = None
 
     def with_live(self, live_host: np.ndarray) -> "DeviceSegment":
         """Republishes the deletes bitmap (refresh after deletes)."""
@@ -125,6 +154,18 @@ class DeviceSegment:
             old_live.free(reason="live-republished")
         allocs["_live"] = default_ledger.register(
             KIND_COLUMN, array_nbytes(live_dev), field="_live")
+        # a multi-vector field's rows answer only for live documents
+        nested = {}
+        for fname, nvf in (self.nested_vector_fields or {}).items():
+            nested[fname] = dataclasses.replace(nvf, valid=jax.device_put(
+                _nested_valid(nvf.parent_host, live, nvf.valid.shape[0]),
+                self.device))
+            old_valid = allocs.pop(f"_live:{fname}", None)
+            if old_valid is not None:
+                old_valid.free(reason="live-republished")
+            allocs[f"_live:{fname}"] = default_ledger.register(
+                KIND_COLUMN, array_nbytes(nested[fname].valid),
+                field=f"_live:{fname}")
         return DeviceSegment(
             name=self.name,
             n_docs=self.n_docs,
@@ -136,6 +177,7 @@ class DeviceSegment:
             vector_fields=self.vector_fields,
             allocations=allocs,
             device=self.device,
+            nested_vector_fields=nested,
         )
 
     def free_allocations(self, reason: str = "retired") -> None:
@@ -272,6 +314,25 @@ def to_device(seg: HostSegment, device=None) -> DeviceSegment:
         if ann is not None and getattr(ann, "allocation", None) is not None:
             allocs[f"ivfpq:{fname}"] = ann.allocation
 
+    nested_vector_fields: dict[str, DeviceNestedVectorField] = {}
+    for fname, vf in seg.nested_vector_fields.items():
+        rows = vf.vectors.shape[0]
+        r_pad = pad_size(rows)
+        vecs = _pad1(vf.vectors, r_pad)
+        nested_vector_fields[fname] = nvf = DeviceNestedVectorField(
+            vectors=put(vecs),
+            norms_sq=put((vecs.astype(np.float64) ** 2).sum(axis=1)
+                         .astype(np.float32)),
+            parent=put(_pad1(vf.parent, r_pad, fill=-1)),
+            valid=put(_nested_valid(vf.parent, live, r_pad)),
+            rows=rows,
+            dims=vf.dims,
+            similarity=vf.similarity,
+            parent_host=vf.parent,
+        )
+        track(fname, nvf.vectors, nvf.norms_sq, nvf.parent)
+        track(f"_live:{fname}", nvf.valid)
+
     live_dev = put(live)
     allocs["_live"] = default_ledger.register(
         KIND_COLUMN, array_nbytes(live_dev), field="_live")
@@ -286,4 +347,5 @@ def to_device(seg: HostSegment, device=None) -> DeviceSegment:
         vector_fields=vector_fields,
         allocations=allocs,
         device=device,
+        nested_vector_fields=nested_vector_fields,
     )

@@ -13,7 +13,8 @@ Field value encodings chosen for the TPU segment layout:
 - long/integer/short/byte/date -> int64 doc-values column
 - double/float/half_float      -> float64 doc-values column
 - boolean   -> int64 column (0/1)
-- dense_vector -> row in the segment's [n, dims] matrix
+- dense_vector -> row in the segment's [n, dims] matrix; under a `nested`
+  path one row a nested object, each row with its document
 """
 
 from __future__ import annotations
@@ -22,6 +23,8 @@ import datetime as _dt
 import math
 from dataclasses import dataclass, field as dc_field
 from typing import Any
+
+import numpy as np
 
 from opensearch_tpu.common.errors import (
     IllegalArgumentException,
@@ -178,6 +181,9 @@ class FieldMapper:
     # text fielddata (TextFieldMapper.fielddata): enables sort/agg columnar
     # access on a text field; surfaced by GET /_cat/fielddata
     fielddata: bool = False
+    # dense_vector under a `nested` path: that path (the field is then a
+    # multi-vector field, one row a nested object); None at the top level
+    nested_path: str | None = None
 
     def to_dict(self) -> dict:
         out: dict[str, Any] = {
@@ -225,6 +231,8 @@ class ParsedField:
     exact: list[str] | None = None        # keyword: untokenized values
     numeric: list[float] | None = None    # numeric/date/boolean column values
     vector: list[float] | None = None     # dense_vector row
+    # a multi-vector field's rows (float32 [dims] each), in document order
+    vectors: list | None = None
 
 
 # position gap between successive values of a multi-valued text field, so
@@ -463,9 +471,13 @@ class MapperService:
             ftype = "dense_vector"
         if ftype in ("object", "nested"):
             # object: children flatten with dotted names. nested flattens
-            # the same way — the per-object match scoping of true nested
-            # docs is NOT modeled; nested queries reject loudly instead of
-            # matching wrongly (index/mapper/ObjectMapper vs NestedDocs)
+            # the same way (a `nested` query over it runs its inner query
+            # on the dotted multi-valued columns), with one exception: a
+            # vector field under a nested path keeps every nested object's
+            # vector as a row of its own (a multi-vector field: rows plus
+            # each row's document), searched by a `nested` `knn` that
+            # scores a document by its best row; any other `nested` query
+            # over such a path is refused (search/executor.nested_knn)
             if ftype == "nested":
                 self.nested_paths.add(full)
             for child, child_conf in (conf.get("properties") or {}).items():
@@ -562,6 +574,13 @@ class MapperService:
             )
         if ftype == "dense_vector" and mapper.method is not None:
             validate_ann_method(full, mapper.method, mapper.dims)
+        if ftype == "dense_vector":
+            mapper.nested_path = self.nested_path_of(full)
+            if mapper.nested_path is not None and mapper.method is not None:
+                raise MapperParsingException(
+                    f"vector field [{full}] under nested path "
+                    f"[{mapper.nested_path}] is searched exactly: it takes "
+                    f"no [method]")
         existing = self.mappers.get(full)
         if existing is not None and existing.type != mapper.type:
             raise IllegalArgumentException(
@@ -592,6 +611,16 @@ class MapperService:
                 mapper.fields[sub] = sub_mapper
         self.mappers[full] = mapper
 
+    def nested_path_of(self, name: str) -> str | None:
+        """The innermost `nested` path that `name` lies under, or None."""
+        under = [p for p in self.nested_paths if name.startswith(f"{p}.")]
+        return max(under, key=len, default=None)
+
+    def nested_vector_fields(self, path: str) -> list[str]:
+        """The multi-vector fields of a `nested` path."""
+        return [name for name, m in self.mappers.items()
+                if m.type == "dense_vector" and m.nested_path == path]
+
     def field_mapper(self, name: str) -> FieldMapper | None:
         """Mapper for a field, following alias paths (the reference resolves
         aliases in QueryShardContext.fieldMapper). Segment columns are
@@ -615,6 +644,13 @@ class MapperService:
             for p in parts[:-1]:
                 node = node.setdefault(p, {}).setdefault("properties", {})
             node[parts[-1]] = m.to_dict()
+        # a nested path says so, or a mapping read back (recovery) would
+        # take it for an object and its vector fields for single-vector ones
+        for path in self.nested_paths:
+            node = {"properties": props}
+            for p in path.split("."):
+                node = node.setdefault("properties", {}).setdefault(p, {})
+            node["type"] = "nested"
         out: dict[str, Any] = {"properties": props}
         if self.dynamic_templates:
             out["dynamic_templates"] = self.dynamic_templates
@@ -1201,6 +1237,16 @@ class MapperService:
                 nums = pf.numeric or []
                 nums.extend(float(_parse_boolean(v)) for v in values if v is not None)
                 pf.numeric = nums
+            elif mapper.type == "dense_vector" and mapper.nested_path:
+                # a multi-vector field: each nested object's vector is a
+                # row of its own, in document order (float32 as stored)
+                row = np.asarray(values, np.float32)
+                if row.shape != (mapper.dims,):
+                    raise ValueError(
+                        f"vector length {row.size} != dims {mapper.dims}")
+                if pf.vectors is None:
+                    pf.vectors = []
+                pf.vectors.append(row)
             elif mapper.type == "dense_vector":
                 if pf.vector is not None:
                     raise ValueError("multiple vectors for one field")

@@ -12,7 +12,9 @@ InternalEngine.java:1138 addDocs → IndexWriter) re-designed for TPU HBM:
   are split into two int32 words on device (TPU JAX is 32-bit by default and
   epoch-millis don't fit float32); float-family stored as float32
 - keyword: ordinal encoding, CSR for multi-valued + first-ord column for sort
-- vectors: [n_docs, dims] float32 matrix (bf16 variant for the MXU path)
+- vectors: [n_docs, dims] float32 matrix (bf16 variant for the MXU path);
+  a multi-vector field (a vector under a `nested` path) is [rows, dims] with
+  each row's document beside it, rows in document order
 - stored fields (_source, _id): host-side only — fetch phase is host work
 
 All device arrays are padded: n_docs to a bucketed n_pad so XLA compile
@@ -204,11 +206,14 @@ class HostNumericField:
 
 @dataclass
 class HostVectorField:
-    vectors: np.ndarray              # float32 [n_docs, dims]
+    vectors: np.ndarray              # float32 [n_docs, dims] ([rows, dims])
     present: np.ndarray              # bool [n_docs]
     dims: int
     similarity: str
     method: dict | None = None       # ANN method config from the mapper
+    # a multi-vector field's row -> local document, int32 [rows] ascending
+    # (None: one row a document, row i is document i)
+    parent: np.ndarray | None = None
 
 
 @dataclass
@@ -223,6 +228,10 @@ class HostSegment:
     keyword_fields: dict[str, HostKeywordField] = dc_field(default_factory=dict)
     numeric_fields: dict[str, HostNumericField] = dc_field(default_factory=dict)
     vector_fields: dict[str, HostVectorField] = dc_field(default_factory=dict)
+    # multi-vector fields (a vector under a `nested` path), kept apart so
+    # that nothing that reads one row a document meets them
+    nested_vector_fields: dict[str, HostVectorField] = dc_field(
+        default_factory=dict)
     # live docs bitmap — mutated by deletes, republished to device on refresh
     live: np.ndarray = dc_field(default_factory=lambda: np.zeros(0, bool))
     min_seq_no: int = -1
@@ -335,6 +344,11 @@ class SegmentBuilder:
                 nf = self._build_numeric(fname, n, "int")
                 if nf is not None:
                     seg.numeric_fields[fname] = nf
+            elif mapper.type == "dense_vector" and mapper.nested_path:
+                vf = self._build_nested_vector(
+                    fname, n, mapper.dims, mapper.similarity)
+                if vf is not None:
+                    seg.nested_vector_fields[fname] = vf
             elif mapper.type == "dense_vector":
                 vf = self._build_vector(
                     fname, n, mapper.dims, mapper.similarity, mapper.method
@@ -359,7 +373,8 @@ class SegmentBuilder:
             if mapper.type != "alias" or not mapper.path:
                 continue
             for store in (seg.text_fields, seg.keyword_fields,
-                          seg.numeric_fields, seg.vector_fields):
+                          seg.numeric_fields, seg.vector_fields,
+                          seg.nested_vector_fields):
                 if mapper.path in store:
                     store[fname] = store[mapper.path]
         return seg
@@ -517,6 +532,28 @@ class SegmentBuilder:
             method=method,
         )
 
+    def _build_nested_vector(self, fname: str, n: int, dims: int,
+                             similarity: str) -> HostVectorField | None:
+        """A multi-vector field: every document's rows in document order,
+        each with its document in `parent`."""
+        present = np.zeros(n, dtype=bool)
+        rows: list[np.ndarray] = []
+        counts = np.zeros(n, dtype=np.int64)
+        for d, doc in enumerate(self.docs):
+            pf = doc.fields.get(fname)
+            if pf is None or not pf.vectors:
+                continue
+            present[d] = True
+            counts[d] = len(pf.vectors)
+            rows.extend(pf.vectors)
+        if not rows:
+            return None
+        return HostVectorField(
+            vectors=np.stack(rows).astype(np.float32, copy=False),
+            present=present, dims=dims, similarity=similarity,
+            parent=np.repeat(np.arange(n, dtype=np.int32), counts),
+        )
+
 
 # --------------------------------------------------------------------------
 # Persistence (flush/commit writes segments to disk; recovery reads them)
@@ -558,6 +595,7 @@ def segment_payload(
         "keyword_fields": {},
         "numeric_fields": {},
         "vector_fields": {},
+        "nested_vector_fields": {},
         # alias columns (shared by reference, see SegmentBuilder.build) are
         # serialized once under the canonical name; load re-links them
         "field_links": {},
@@ -623,6 +661,16 @@ def segment_payload(
         arrays[f"{key}:present"] = vf.present
         meta["vector_fields"][fname] = {
             "dims": vf.dims, "similarity": vf.similarity, "method": vf.method,
+        }
+    for fname, vf in seg.nested_vector_fields.items():
+        if _link(fname, vf):
+            continue
+        key = f"nvec:{fname}"
+        arrays[f"{key}:vectors"] = vf.vectors
+        arrays[f"{key}:present"] = vf.present
+        arrays[f"{key}:parent"] = vf.parent
+        meta["nested_vector_fields"][fname] = {
+            "dims": vf.dims, "similarity": vf.similarity,
         }
     import io as _io
 
@@ -726,10 +774,20 @@ def segment_from_payload(meta: dict, arrays, sources: list[bytes]) -> HostSegmen
             similarity=m["similarity"],
             method=m.get("method"),
         )
+    for fname, m in (meta.get("nested_vector_fields") or {}).items():
+        key = f"nvec:{fname}"
+        seg.nested_vector_fields[fname] = HostVectorField(
+            vectors=arrays[f"{key}:vectors"],
+            present=arrays[f"{key}:present"],
+            dims=m["dims"],
+            similarity=m["similarity"],
+            parent=arrays[f"{key}:parent"],
+        )
     # re-link alias columns (serialized once under the canonical name)
     for fname, target in (meta.get("field_links") or {}).items():
         for store in (seg.text_fields, seg.keyword_fields,
-                      seg.numeric_fields, seg.vector_fields):
+                      seg.numeric_fields, seg.vector_fields,
+                      seg.nested_vector_fields):
             if target in store:
                 store[fname] = store[target]
                 break

@@ -450,11 +450,20 @@ class TpuNode:
             "bm25_entries": self.telemetry.metrics.counter(
                 "search.bm25.entries"),
         }
+        # nested kNN queries served over multi-vector fields, and the rows
+        # of the segments they searched (search/executor.count_nested_knn)
+        knn_nested = {
+            "requests": self.telemetry.metrics.counter(
+                "search.knn.nested.requests"),
+            "children": self.telemetry.metrics.counter(
+                "search.knn.nested.children"),
+        }
         self.telemetry.tracer.capture_counters = lambda: {
             "knn_batch": dict(self.knn_batcher.stats),
             "knn_collect": {k: c.value for k, c in knn_collect.items()},
             "knn_filter": {k: c.value for k, c in knn_filter.items()},
             "lexical": {k: c.value for k, c in lexical.items()},
+            "knn_nested": {k: c.value for k, c in knn_nested.items()},
             "device_resident_bytes": default_ledger.resident_bytes(),
             "device_resident_by_device": default_ledger.device_totals(),
             "device_backend_memory": backend_memory(),

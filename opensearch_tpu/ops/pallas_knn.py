@@ -1,6 +1,7 @@
 """The exact-kNN scan: `knn_fused`, the one implementation of exact kNN
-scoring in the program, and `fused_impl`, the one rule that picks its
-lowering.
+scoring in the program, `knn_nested`, the same scan over a multi-vector
+field's rows whose pool holds documents (each at its best row), and
+`fused_impl`, the one rule that picks their lowering.
 
 The flagship hot loop (ContextIndexSearcher.search + TopScoreDocCollector,
 SURVEY.md §3.2 ★★). `knn_fused` pads, preps the matmul operands at the
@@ -198,17 +199,21 @@ def _knn_fused_kernel(
     v_ref,        # [tile, d] tile, same dtype as q_ref
     nsq_ref,      # [1, tile] f32
     valid_ref,    # [1, tile] f32
-    scale_ref,    # [1, 1] f32 dequant scale
-    vals_out,     # [b_tile, r] f32
-    ids_out,      # [b_tile, r] i32
-    vals_scr,     # scratch [b_tile, r] f32 — pool persists across doc blocks
-    ids_scr,      # scratch [b_tile, r] i32
-    *,
+    *refs,        # nested: parent_ref [1, tile] i32 first; then
+    #               scale_ref [1, 1] f32 dequant scale,
+    #               vals_out, ids_out [b_tile, r] f32 / i32, and the
+    #               scratch pool [b_tile, r] f32 / i32 that persists
+    #               across doc blocks
     r: int,
     similarity: str,
     score_precision: str,
     n_blocks: int,
+    nested: bool = False,
 ):
+    if nested:
+        parent_ref, scale_ref, vals_out, ids_out, vals_scr, ids_scr = refs
+    else:
+        scale_ref, vals_out, ids_out, vals_scr, ids_scr = refs
     i = pl.program_id(1)   # doc-block index — INNERMOST, iterates fastest,
     #                        so the scratch pool is per-query-tile coherent
     B = q_ref.shape[0]
@@ -231,8 +236,14 @@ def _knn_fused_kernel(
     @pl.when(improves)
     def _merge():
         # carried entries FIRST: argmax takes the first maximum, so score
-        # ties keep the earlier (lower doc id) entry — lax.top_k tie-break
-        block_ids = i * bs + jax.lax.broadcasted_iota(jnp.int32, (B, bs), 1)
+        # ties keep the earlier (lower doc id) entry — lax.top_k tie-break.
+        # Nested: a row's candidate id is its DOCUMENT (rows lie in
+        # document order, so the earlier row has the lower document)
+        if nested:
+            block_ids = jnp.broadcast_to(parent_ref[:], (B, bs))
+        else:
+            block_ids = i * bs + jax.lax.broadcasted_iota(
+                jnp.int32, (B, bs), 1)
         ext_vals = jnp.concatenate([vals_scr[:], scores], axis=1)
         ext_ids = jnp.concatenate([ids_scr[:], block_ids], axis=1)
         width = bs + r
@@ -251,7 +262,10 @@ def _knn_fused_kernel(
             sel = colr == j
             acc_v = jnp.where(sel, best, acc_v)
             acc_i = jnp.where(sel, best_id, acc_i)
-            return jnp.where(onehot, _NEG_INF, ext), acc_v, acc_i
+            # nested: the chosen document's every row leaves the running,
+            # so the pool holds distinct documents, each at its best row
+            drop = (ext_ids == best_id) if nested else onehot
+            return jnp.where(drop, _NEG_INF, ext), acc_v, acc_i
 
         _, acc_v, acc_i = jax.lax.fori_loop(
             0, r, select_one,
@@ -279,6 +293,7 @@ def pallas_knn_fused(
     q_x: jnp.ndarray,        # [B, d] prepped operand, B % b_tile == 0
     qsq: jnp.ndarray,        # [B, 1] f32 (from the ORIGINAL f32 queries)
     scale: jnp.ndarray,      # f32 scalar dequant scale
+    parent: jnp.ndarray | None = None,   # [n_pad] i32: nested rows' docs
     *,
     r: int,
     similarity: str = "l2_norm",
@@ -288,7 +303,9 @@ def pallas_knn_fused(
     """Raw pool scan: (pool_scores [B, r], pool_ids [B, r]), slots past the
     valid-doc count carry (-inf, -1). Operands come pre-prepped from
     `_prep_operands` so this and `_fused_xla_pool` see identical bits;
-    use `knn_fused` for the end-to-end contract."""
+    use `knn_fused` for the end-to-end contract. With `parent` (rows of a
+    multi-vector field, each row's document, -1 on padding rows) the pool
+    holds DOCUMENTS, each at its best row's score: `knn_nested`."""
     n, d = v_x.shape
     B = q_x.shape[0]
     assert n % FK_BLOCK == 0, f"n [{n}] must be a multiple of {FK_BLOCK}"
@@ -296,10 +313,13 @@ def pallas_knn_fused(
     assert B % b_tile == 0, f"B [{B}] must be a multiple of {b_tile}"
     tile = fused_tile(n, d, v_x.dtype.itemsize, b_tile)
     n_blocks = n // tile
+    nested = parent is not None
     kernel = functools.partial(
         _knn_fused_kernel, r=r, similarity=similarity,
-        score_precision=score_precision, n_blocks=n_blocks,
+        score_precision=score_precision, n_blocks=n_blocks, nested=nested,
     )
+    # side operands as [1, n] rows, never [n, 1] (see above)
+    side = pl.BlockSpec((1, tile), lambda j, i: (0, i))
     vals, ids = pl.pallas_call(
         kernel,
         # query tiles outer, doc blocks INNER: the per-query-tile pool in
@@ -309,9 +329,9 @@ def pallas_knn_fused(
             pl.BlockSpec((b_tile, d), lambda j, i: (j, 0)),
             pl.BlockSpec((b_tile, 1), lambda j, i: (j, 0)),
             pl.BlockSpec((tile, d), lambda j, i: (i, 0)),
-            # side operands as [1, n] rows, never [n, 1] (see above)
-            pl.BlockSpec((1, tile), lambda j, i: (0, i)),
-            pl.BlockSpec((1, tile), lambda j, i: (0, i)),
+            side,
+            side,
+            *([side] if nested else []),
             pl.BlockSpec((1, 1), lambda j, i: (0, 0)),
         ],
         out_specs=[
@@ -330,13 +350,14 @@ def pallas_knn_fused(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
-        name="pallas_knn_fused",
+        name="pallas_knn_fused_nested" if nested else "pallas_knn_fused",
     )(
         q_x,
         qsq,
         v_x,
         norms_sq.reshape(1, -1),
         valid.astype(jnp.float32).reshape(1, -1),
+        *([parent.reshape(1, -1)] if nested else []),
         scale.reshape(1, 1),
     )
     return vals, ids
@@ -375,6 +396,16 @@ def _fused_rescore(queries, vectors, norms_sq, valid, cand, *,
     return vals, ids
 
 
+def _padded_sizes(n: int, b: int) -> tuple[int, int]:
+    """(rows, queries) a scan pads to: rows to a multiple of FK_BLOCK,
+    queries to a multiple of 8 within one query tile, of FK_QTILE above."""
+    if b <= FK_QTILE:
+        b_pad = max(8, -(-b // 8) * 8)
+    else:
+        b_pad = -(-b // FK_QTILE) * FK_QTILE
+    return -(-n // FK_BLOCK) * FK_BLOCK, b_pad
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("k", "similarity", "score_precision", "impl",
@@ -400,11 +431,7 @@ def knn_fused(
     _check_precision(score_precision)
     n, d = vectors.shape
     B = queries.shape[0]
-    n_pad = -(-n // FK_BLOCK) * FK_BLOCK
-    if B <= FK_QTILE:
-        b_pad = max(8, -(-B // 8) * 8)
-    else:
-        b_pad = -(-B // FK_QTILE) * FK_QTILE
+    n_pad, b_pad = _padded_sizes(n, B)
     if n_pad != n:
         vectors = jnp.pad(vectors, ((0, n_pad - n), (0, 0)))
         norms_sq = jnp.pad(norms_sq, (0, n_pad - n))
@@ -433,6 +460,79 @@ def knn_fused(
         vals, ids = _fused_rescore(
             queries, vectors, norms_sq, valid, pi,
             k=k_eff, similarity=similarity,
+        )
+    if k_eff < k:
+        vals = jnp.pad(vals, ((0, 0), (0, k - k_eff)),
+                       constant_values=_NEG_INF)
+        ids = jnp.pad(ids, ((0, 0), (0, k - k_eff)), constant_values=-1)
+    return vals[:B], ids[:B]
+
+
+def _nested_xla_pool(vectors, norms_sq, valid, parent, queries, qsq, *,
+                     r, n_docs, similarity):
+    """XLA twin of the nested pool scan: the full [B, rows] scores, each
+    document's best row by a segment max over `parent`, lax.top_k over the
+    [B, n_docs] documents. The same scoring math as the kernel."""
+    dots = _fused_dots(queries, vectors, "fp32", jnp.float32(1.0))
+    scores = _transform_scores(dots, qsq, norms_sq[None, :], similarity)
+    scores = jnp.where(valid[None, :], scores, _NEG_INF)
+    # padding rows (parent -1) fall in one extra segment, cut off below
+    doc = jnp.where(parent >= 0, parent, n_docs)
+    best = jax.ops.segment_max(scores.T, doc, num_segments=n_docs + 1)
+    vals, ids = jax.lax.top_k(best[:n_docs].T, r)
+    ids = jnp.where(vals > _NEG_INF, ids, -1)
+    return vals, ids
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("k", "n_docs", "similarity", "impl", "interpret"),
+)
+def knn_nested(
+    vectors: jnp.ndarray,    # [n, d] f32: a multi-vector field's rows
+    norms_sq: jnp.ndarray,   # [n] f32
+    valid: jnp.ndarray,      # [n] bool: the row may answer (live document)
+    parent: jnp.ndarray,     # [n] i32: each row's document, ascending; -1 pad
+    queries: jnp.ndarray,    # [B, d] f32 (any B)
+    *,
+    k: int,
+    n_docs: int,             # documents are 0 .. n_docs - 1
+    similarity: str = "l2_norm",
+    impl: str = "pallas",
+    interpret: bool = False,
+):
+    """Exact kNN over documents that hold many vectors: the k best
+    DOCUMENTS, each scored by its best valid row (a `nested` `knn`, score
+    mode max), in one pass over the rows. The reduction to documents is the
+    scan's own pool (`pallas_knn_fused` with `parent`: a chosen document's
+    every row leaves the merge), or in the XLA twin a segment max; either
+    is exact at any number of rows a document. Returns (scores [B, k],
+    document ids [B, k]), (-inf, -1) past the documents with a valid row.
+    The scan is fp32 at HIGHEST whatever `search.knn.score_precision`
+    says: the pool holds documents, whose best rows a reduced-precision
+    rescore could not find again."""
+    n, d = vectors.shape
+    B = queries.shape[0]
+    n_pad, b_pad = _padded_sizes(n, B)
+    if n_pad != n:
+        vectors = jnp.pad(vectors, ((0, n_pad - n), (0, 0)))
+        norms_sq = jnp.pad(norms_sq, (0, n_pad - n))
+        valid = jnp.pad(valid, (0, n_pad - n))
+        parent = jnp.pad(parent, (0, n_pad - n), constant_values=-1)
+    if b_pad != B:
+        queries = jnp.pad(queries, ((0, b_pad - B), (0, 0)))
+    k_eff = min(k, n_docs, n_pad)
+    qsq = jnp.sum(queries * queries, axis=1, keepdims=True)
+    if impl == "pallas":
+        vals, ids = pallas_knn_fused(
+            vectors, norms_sq, valid, queries, qsq, jnp.float32(1.0),
+            parent, r=k_eff, similarity=similarity, score_precision="fp32",
+            interpret=interpret,
+        )
+    else:
+        vals, ids = _nested_xla_pool(
+            vectors, norms_sq, valid, parent, queries, qsq,
+            r=k_eff, n_docs=n_docs, similarity=similarity,
         )
     if k_eff < k:
         vals = jnp.pad(vals, ((0, 0), (0, k - k_eff)),

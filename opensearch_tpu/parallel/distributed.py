@@ -39,6 +39,7 @@ def build_knn_serving_step(
     kernel: str,
     score_precision: str,
     interpret: bool,
+    doc_stride: int | None = None,
 ):
     """Exact k-NN over S shards laid out on D devices (S % D == 0; each
     device owns a block of S/D shards — the two-level layout of the
@@ -60,11 +61,18 @@ def build_knn_serving_step(
     reduced-precision scan ends in the exact fp32 rescore, keeping scores
     in the serving score space; slots past a shard's valid-doc count carry
     explicit (-inf, -1) global ids. The S % D == 0 precondition is the
-    caller's (distributed_serving picks D as a divisor of S)."""
+    caller's (distributed_serving picks D as a divisor of S).
+
+    With `doc_stride` the program serves a multi-vector field (a `nested`
+    `knn`): fn takes a fifth operand, parent [S, n] (each row's document in
+    its shard's flat document space, -1 on padding rows), before the
+    queries; each local shard is scanned by ops/pallas_knn.knn_nested, so
+    the reduction to documents is done before the cut and the winners are
+    documents; global id = shard_idx * doc_stride + flat_doc."""
     # a stable scope name: a trace reduction can match the step's device ops
     # to the host's `launch.device` span by name after a refactor
     @jax.named_scope("mesh_knn_step")
-    def step(vectors, norms_sq, valid, queries):
+    def scan(vectors, norms_sq, valid, parent, queries):
         # block shapes: [S_local, n, d], [S_local, n], [S_local, n], [B, d]
         s_local, n_flat, _d = vectors.shape
         # one scan per LOCAL shard (s_local is a static block shape, so
@@ -72,12 +80,20 @@ def build_knn_serving_step(
         # program)
         per_v, per_i = [], []
         for si in range(s_local):
-            v, i = pallas_knn.knn_fused(
-                vectors[si], norms_sq[si], valid[si], queries,
-                k=k_shard, similarity=similarity,
-                score_precision=score_precision,
-                impl=kernel, interpret=interpret,
-            )
+            if parent is None:
+                v, i = pallas_knn.knn_fused(
+                    vectors[si], norms_sq[si], valid[si], queries,
+                    k=k_shard, similarity=similarity,
+                    score_precision=score_precision,
+                    impl=kernel, interpret=interpret,
+                )
+            else:
+                v, i = pallas_knn.knn_nested(
+                    vectors[si], norms_sq[si], valid[si], parent[si],
+                    queries, k=k_shard, n_docs=doc_stride,
+                    similarity=similarity, impl=kernel,
+                    interpret=interpret,
+                )
             per_v.append(v)
             per_i.append(i)
         vals = jnp.stack(per_v)                    # [S_local, B, k]
@@ -85,7 +101,8 @@ def build_knn_serving_step(
         counts = jnp.sum(jnp.isfinite(vals), axis=-1)          # [S_local, B]
 
         shard0 = jax.lax.axis_index(DATA_AXIS) * s_local
-        offsets = (shard0 + jnp.arange(s_local))[:, None, None] * n_flat
+        stride = n_flat if parent is None else doc_stride
+        offsets = (shard0 + jnp.arange(s_local))[:, None, None] * stride
         # the scan marks empty slots id -1: keep them explicit instead of
         # wrapping them into a neighbouring shard's range
         gids = jnp.where(ids >= 0, ids + offsets, -1)
@@ -108,11 +125,20 @@ def build_knn_serving_step(
             [jax.lax.bitcast_convert_type(top_vals, jnp.int32), top_ids,
              all_counts.T], axis=1)
 
+    rows = P(DATA_AXIS, None)
+    if doc_stride is None:
+        def step(vectors, norms_sq, valid, queries):
+            return scan(vectors, norms_sq, valid, None, queries)
+
+        in_specs = (P(DATA_AXIS, None, None), rows, rows, P(None, None))
+    else:
+        step = scan
+        in_specs = (P(DATA_AXIS, None, None), rows, rows, rows,
+                    P(None, None))
     mapped = shard_map(
         step,
         mesh=mesh,
-        in_specs=(P(DATA_AXIS, None, None), P(DATA_AXIS, None),
-                  P(DATA_AXIS, None), P(None, None)),
+        in_specs=in_specs,
         out_specs=P(),
         check_vma=False,
     )

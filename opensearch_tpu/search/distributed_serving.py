@@ -131,11 +131,17 @@ class MeshLaunchOutcome:
 
 
 class _IndexBundle:
-    """[S, n_flat, d] mesh-sharded slabs + host-side flat->segment maps."""
+    """[S, n_flat, d] mesh-sharded slabs + host-side flat->segment maps.
+
+    A multi-vector field's bundle (`parent` set) holds the shards' ROWS:
+    `parent` [S, n_flat] is each row's document in its shard's flat
+    document space (segment-ascending, doc-ascending; -1 on padding), and
+    the launch's ids are documents, `id_stride` apart from shard to shard
+    (the flat bundle's ids are rows, `n_flat` apart)."""
 
     def __init__(self, vectors, norms_sq, valid, n_flat: int,
                  seg_offsets: list[list[tuple[int, int, int]]],
-                 allocation=None):
+                 allocation=None, parent=None, id_stride: int = 0):
         self.vectors = vectors          # jnp [S, n_flat, d] on mesh
         self.norms_sq = norms_sq        # jnp [S, n_flat]
         self.valid = valid              # jnp [S, n_flat]
@@ -145,11 +151,14 @@ class _IndexBundle:
         # device-residency ledger handle; the ShardMeshRegistry frees it
         # on eviction/invalidation (and on a lost duplicate-build race)
         self.allocation = allocation
+        self.parent = parent            # jnp [S, n_flat] int32, or None
+        self.id_stride = id_stride or n_flat
 
     @property
     def nbytes(self) -> int:
         return sum(int(a.nbytes) for a in
-                   (self.vectors, self.norms_sq, self.valid))
+                   (self.vectors, self.norms_sq, self.valid, self.parent)
+                   if a is not None)
 
     def locate(self, shard_idx: int, flat: int) -> tuple[int, int]:
         for start, seg_idx, n_docs in self.seg_offsets[shard_idx]:
@@ -194,14 +203,15 @@ def _peaks(mesh: Mesh) -> list[tuple[int, int]] | None:
             for st in stats]
 
 
-def _can_serve(snaps: list, field: str, *,
-               filtered: bool = False) -> tuple[str, int] | None:
+def _can_serve(snaps: list, field: str, *, filtered: bool = False,
+               nested: bool = False) -> tuple[str, int] | None:
     """Returns (similarity, dims) if every shard can be served exactly,
     else None. ANN-indexed segments fall back on UNFILTERED queries: the
     host path would answer those with IVF-PQ, and this path must stay
     bit-identical to the host. With a filter, the host path itself runs an
     exact scan (executor.shard_knn_selection gates ANN on filter is None),
-    so ANN segments are eligible here too."""
+    so ANN segments are eligible here too. `nested`: the field is a
+    multi-vector field (always exact)."""
     from opensearch_tpu.ops.knn import canonical_similarity
 
     similarity = None
@@ -209,11 +219,12 @@ def _can_serve(snaps: list, field: str, *,
     any_field = False
     for snap in snaps:
         for host, dev in snap.segments:
-            vf = dev.vector_fields.get(field)
+            vf = (dev.nested_vector_fields or {}).get(field) if nested \
+                else dev.vector_fields.get(field)
             if vf is None:
                 continue
             any_field = True
-            if vf.ann is not None and not filtered:
+            if getattr(vf, "ann", None) is not None and not filtered:
                 return None
             sim = canonical_similarity(vf.similarity)
             if similarity is None:
@@ -225,21 +236,42 @@ def _can_serve(snaps: list, field: str, *,
     return similarity, dims
 
 
+def _nested_slab(host, dev, field: str, dims: int, doc_start: int):
+    """One segment's rows of a multi-vector field for a nested bundle:
+    (vectors, norms, valid, parent in the shard's flat document space)."""
+    hvf = host.nested_vector_fields.get(field)
+    if hvf is None:
+        return (np.zeros((0, dims), np.float32), np.zeros(0, np.float32),
+                np.zeros(0, bool), np.zeros(0, np.int32))
+    v = np.asarray(hvf.vectors, np.float32)
+    # dev.live, as the flat slab's: the PUBLISHED live bitmap
+    return (v, (v.astype(np.float64) ** 2).sum(axis=1).astype(np.float32),
+            np.asarray(dev.live)[hvf.parent],
+            (hvf.parent + doc_start).astype(np.int32))
+
+
 def _build_bundle(snaps: list, field: str, dims: int, mesh: Mesh,
                   index_name: str = "_unknown",
-                  generations: tuple = ()) -> _IndexBundle:
+                  generations: tuple = (),
+                  nested: bool = False) -> _IndexBundle:
     per_shard_vecs: list[np.ndarray] = []
     per_shard_norms: list[np.ndarray] = []
     per_shard_valid: list[np.ndarray] = []
+    per_shard_parent: list[np.ndarray] = []
     seg_offsets: list[list[tuple[int, int, int]]] = []
     for snap in snaps:
-        chunks_v, chunks_n, chunks_ok = [], [], []
+        chunks_v, chunks_n, chunks_ok, chunks_p = [], [], [], []
         offsets: list[tuple[int, int, int]] = []
         pos = 0
         for seg_idx, (host, dev) in enumerate(snap.segments):
             n = host.n_docs
             hvf = host.vector_fields.get(field)
-            if hvf is None:
+            if nested:
+                for out, part in zip(
+                        (chunks_v, chunks_n, chunks_ok, chunks_p),
+                        _nested_slab(host, dev, field, dims, pos)):
+                    out.append(part)
+            elif hvf is None:
                 chunks_v.append(np.zeros((n, dims), np.float32))
                 chunks_n.append(np.zeros(n, np.float32))
                 chunks_ok.append(np.zeros(n, bool))
@@ -271,12 +303,19 @@ def _build_bundle(snaps: list, field: str, dims: int, mesh: Mesh,
         per_shard_valid.append(
             np.concatenate(chunks_ok) if chunks_ok else np.zeros(0, bool)
         )
+        per_shard_parent.append(
+            np.concatenate(chunks_p) if chunks_p else np.zeros(0, np.int32)
+        )
 
     max_docs = max((v.shape[0] for v in per_shard_vecs), default=1)
     # bucket to the next power of two: keeps the compiled program stable
     # across refreshes that grow a shard slightly (query-shape cache,
     # SURVEY.md §7 hard part #3)
     n_flat = 1 << max(int(max_docs - 1).bit_length(), 3)
+    # a nested bundle's winners are documents: their ids' shard stride
+    doc_stride = 1 << max(int(max(
+        (sum(n for _s, _i, n in offs) for offs in seg_offsets),
+        default=1) - 1).bit_length(), 3)
 
     def pad(a: np.ndarray, fill=0) -> np.ndarray:
         out = np.full((n_flat, *a.shape[1:]), fill, dtype=a.dtype)
@@ -302,9 +341,13 @@ def _build_bundle(snaps: list, field: str, dims: int, mesh: Mesh,
                          P(DATA_AXIS)),
             n_flat=n_flat,
             seg_offsets=seg_offsets,
+            parent=(_place([pad(p, fill=-1) for p in per_shard_parent],
+                           mesh, P(DATA_AXIS)) if nested else None),
+            id_stride=doc_stride if nested else n_flat,
         )
-        by_device = device_bytes(bundle.vectors, bundle.norms_sq,
-                                 bundle.valid)
+        by_device = device_bytes(*(a for a in (
+            bundle.vectors, bundle.norms_sq, bundle.valid, bundle.parent)
+            if a is not None))
         after = _peaks(mesh)
         span.set_attribute("devices", len(by_device))
         span.set_attribute("shards", len(snaps))
@@ -382,11 +425,14 @@ def mesh_knn_batch(
     nodes: list,
     fetch_k: int,
     alias_filters: list | None = None,
+    nested: bool = False,
 ) -> MeshLaunchOutcome | None:
     """Execute B KnnQuery nodes (same field/k/filter) in ONE device
     dispatch. Returns a MeshLaunchOutcome (per-query per-shard results,
     device-merged row order, launch attribution), or None when this path
-    cannot reproduce the host result."""
+    cannot reproduce the host result. `nested`: the nodes are the `knn`s
+    of `nested` queries over a multi-vector field (no filter): the winners
+    are documents, each scored by its best row."""
     # the batcher's (or the service's) `launch` detail span, if this request
     # is detailed: it learns the launch's shape below
     launch_span = tracing.active_tracer().current_span()
@@ -405,7 +451,10 @@ def mesh_knn_batch(
         has_filter = first.filter is not None or (
             alias_filters is not None and any(f is not None for f in alias_filters)
         )
-        served = _can_serve(snaps, first.field, filtered=has_filter)
+        if nested and has_filter:
+            return None
+        served = _can_serve(snaps, first.field, filtered=has_filter,
+                            nested=nested)
         if served is None:
             _count("fallbacks")
             return None
@@ -433,7 +482,7 @@ def mesh_knn_batch(
                 cache_key,
                 _build_bundle(snaps, first.field, dims, mesh,
                               index_name=index_name,
-                              generations=cache_key[4]),
+                              generations=cache_key[4], nested=nested),
             )
 
         valid = bundle.valid
@@ -482,6 +531,7 @@ def mesh_knn_batch(
             # device -> host transfers this launch makes: the packed output
             launch_span.set_attribute("host_copies", 1)
             launch_span.set_attribute("filtered", int(has_filter))
+            launch_span.set_attribute("nested", int(nested))
         q_host = np.zeros((b_pad, dims), np.float32)
         for i, node in enumerate(nodes):
             q_host[i] = np.asarray(node.vector, np.float32)
@@ -497,9 +547,14 @@ def mesh_knn_batch(
         from opensearch_tpu.search.ann import default_config as ann_config
 
         impl, interpret = fused_impl(ann_config.exact_kernel, k_shard)
-        score_precision = ann_config.score_precision
+        # a nested scan is fp32 whatever the setting (ops/pallas_knn.
+        # knn_nested)
+        score_precision = "fp32" if nested else ann_config.score_precision
+        doc_stride = bundle.id_stride if nested else None
         prog_key = (n_devices, s, bundle.n_flat, dims, k_shard, k_final,
                     similarity, b_pad, impl, interpret, score_precision)
+        if nested:
+            prog_key += (doc_stride,)
         with _CACHE_LOCK:
             program = _PROGRAM_CACHE.get(prog_key)
             retraced = program is None
@@ -508,17 +563,18 @@ def mesh_knn_batch(
                     mesh, k_shard=k_shard, k_final=k_final,
                     similarity=similarity, kernel=impl,
                     score_precision=score_precision, interpret=interpret,
+                    doc_stride=doc_stride,
                 )
                 _PROGRAM_CACHE[prog_key] = program
 
         queries = jnp.asarray(q_host)
+        operands = (bundle.vectors, bundle.norms_sq, valid,
+                    *((bundle.parent,) if nested else ()), queries)
     t0 = time.perf_counter_ns()
     with tracing.detail(span_names.LAUNCH_DEVICE) as span:
         span.set_attribute("retraced", retraced)
         with mesh:
-            packed = program(
-                bundle.vectors, bundle.norms_sq, valid, queries
-            )
+            packed = program(*operands)
         # host materialization is the fence for this launch and its only
         # transfer: the host needs these rows anyway, so the copy doubles
         # as the wait (asking for it at dispatch, `copy_to_host_async`,
@@ -538,8 +594,9 @@ def mesh_knn_batch(
                              precision=score_precision,
                              r=fused_pool_width(k_shard, score_precision),
                              kernel=impl)
+        family = "mesh_knn_nested" if nested else "mesh_knn_fused"
         roofline.record_launch(
-            f"mesh_knn_fused[{score_precision}]", wall_ns, **launch_params)
+            f"{family}[{score_precision}]", wall_ns, **launch_params)
         from opensearch_tpu.telemetry.device_ledger import (
             KIND_QUERY_BATCH,
             default_ledger,
@@ -549,11 +606,11 @@ def mesh_knn_batch(
         # heat touch against the mesh bundle this launch scanned, bytes from
         # the same cost model the roofline fold used (telemetry/device_ledger)
         default_ledger.touch([getattr(bundle, "allocation", None)],
-                             family="mesh_knn_fused", params=launch_params)
+                             family=family, params=launch_params)
         if retraced:
             # program-cache miss == fresh jit entry for the mesh kernel family;
             # the first launch wall includes the compile
-            default_ledger.record_compile("mesh_knn_fused", wall_ns)
+            default_ledger.record_compile(family, wall_ns)
         _count("distributed_searches")
         if s == 1:
             _count("single_shard")
@@ -571,7 +628,7 @@ def mesh_knn_batch(
             for v, g in zip(vals[qi], gids[qi]):
                 if not np.isfinite(v):
                     continue
-                shard_idx, flat = int(g) // bundle.n_flat, int(g) % bundle.n_flat
+                shard_idx, flat = divmod(int(g), bundle.id_stride)
                 seg_idx, doc = bundle.locate(shard_idx, flat)
                 hit = ShardHit(float(np.float32(v) * boost), seg_idx, doc)
                 per_shard_hits[shard_idx].append(hit)

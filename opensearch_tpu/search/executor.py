@@ -26,7 +26,7 @@ import logging
 import re
 import threading
 import time
-from dataclasses import dataclass, field as dc_field
+from dataclasses import dataclass, field as dc_field, replace
 from functools import cached_property
 from typing import Any
 
@@ -191,6 +191,19 @@ def _bm25_span(query_node):
     return tracing.NO_DETAIL
 
 
+def _nested_span(query_node, mapper_service: MapperService):
+    """The `knn.nested` detail span of one shard's query phase where the
+    query holds a `nested` query over a multi-vector field; the no-op scope
+    elsewhere, and wherever the request is not detailed."""
+    scope = tracing.detail(span_names.KNN_NESTED)
+    if scope is tracing.NO_DETAIL or any(
+            isinstance(n, q.NestedQuery)
+            and mapper_service.nested_vector_fields(n.path)
+            for n in q.iter_query_nodes(query_node)):
+        return scope
+    return tracing.NO_DETAIL
+
+
 def filter_clauses(node) -> int:
     """Clauses of one filter node: a bool's direct children, else 1 (0 for
     no filter). The `filter.mask` span's `clauses`."""
@@ -202,11 +215,57 @@ def filter_clauses(node) -> int:
     return 1
 
 
-def _mark_launch_filtered(filtered: bool) -> None:
-    """`filtered` on the batcher's `launch` span, from inside its closure."""
+def _mark_launch(filtered: bool, nested: bool = False) -> None:
+    """`filtered` and `nested` on the batcher's `launch` span, from inside
+    its closure."""
     span = tracing.active_tracer().current_span()
     if span is not None and span.name == span_names.LAUNCH:
         span.set_attribute("filtered", int(filtered))
+        span.set_attribute("nested", int(nested))
+
+
+def nested_knn(node, mapper_service: MapperService) -> q.KnnQuery | None:
+    """The `knn` that a `nested` query runs over a multi-vector field (a
+    vector under the nested path, one row a nested object), with the nested
+    query's boost folded in: it answers the k best DOCUMENTS, each scored
+    by its best row. None where the node is no `nested` query or its path
+    holds no multi-vector field (the flattened semantics). Over a path that
+    holds one, any other inner query is refused (400), as is a score mode
+    other than `max` (or `avg`, the default: one row a document matches,
+    its best, as in the k-NN plugin) and a `knn` filter, rather than
+    answered from the flattened rows."""
+    if not isinstance(node, q.NestedQuery):
+        return None
+    fields = mapper_service.nested_vector_fields(node.path)
+    if not fields:
+        return None
+    inner = node.query
+    if not isinstance(inner, q.KnnQuery) or inner.field not in fields:
+        raise IllegalArgumentException(
+            f"[nested] query on path [{node.path}], which holds the "
+            f"multi-vector field(s) {sorted(fields)}: its query must be a "
+            f"[knn] on one of them (a document is matched by its best "
+            f"vector, never by flattened rows)")
+    if node.score_mode not in ("max", "avg"):
+        raise IllegalArgumentException(
+            f"[nested] [knn] on path [{node.path}] scores a document by its "
+            f"best vector: [score_mode] must be [max] (or [avg], which "
+            f"scores that one vector alike), got [{node.score_mode}]")
+    if inner.filter is not None:
+        raise IllegalArgumentException(
+            f"[nested] [knn] on [{inner.field}] does not take a [filter]")
+    return replace(inner, boost=inner.boost * node.boost)
+
+
+def count_nested_knn(children: int) -> None:
+    """`search.knn.nested.requests` / `.children`: nested kNN queries
+    served (once a request on the mesh road, once a request and shard on
+    the per-shard road) and the data's rows in the segments they searched,
+    whatever implements the scan."""
+    metrics = _knn_metrics()
+    if metrics is not None:
+        metrics.counter("search.knn.nested.requests").add(1)
+        metrics.counter("search.knn.nested.children").add(children)
 
 
 def _pad_query_batch(rows: list) -> np.ndarray:
@@ -263,6 +322,16 @@ class ShardContext:
         # gathered: the `bm25.score` span's attributes
         self.bm25 = {"terms": 0, "postings": 0, "entries": 0, "window": 0,
                      "rows": 0}
+        # nested query node id -> its kNN (nested_knn), made once a shard
+        self._nested: dict[int, Any] = {}
+        # what this request's nested kNN searched on this shard: the
+        # `knn.nested` span's attributes
+        self.nested = {"children": 0, "parents": 0, "launches": 0}
+
+    def nested_knn(self, node) -> q.KnnQuery | None:
+        if id(node) not in self._nested:
+            self._nested[id(node)] = nested_knn(node, self.mapper_service)
+        return self._nested[id(node)]
 
     def rewritten_query_string(self, node) -> Any:
         """Parse a query_string/simple_query_string node's text once per
@@ -296,7 +365,7 @@ class ShardContext:
                     fields.append(name)
         return fields or ["_all_absent_"]
 
-    def shard_knn_selection(self, node) -> list:
+    def shard_knn_selection(self, node, nested: bool = False) -> list:
         """Per segment, the KnnQuery's winners as two short numpy arrays
         (docs int32[w], scores f32[w]), best first, with the top-k cut
         applied across the whole shard (w <= k over all segments together);
@@ -305,16 +374,31 @@ class ShardContext:
         Every exact segment (any size, any k, filter or not) scores through
         ops/pallas_knn.knn_fused; an ANN-indexed segment under an unfiltered
         query through IVF-PQ. Either way only a [1, k_bucket] row comes back
-        to the host, and nothing n_pad wide is built from it here."""
+        to the host, and nothing n_pad wide is built from it here.
+        `nested`: the node is a `nested` query's kNN (`nested_knn`) over a
+        multi-vector field, each segment's winners are its documents, each
+        at its best row (ops/pallas_knn.knn_nested)."""
         cached = self._knn_cache.get(id(node))
         if cached is not None:
             return cached
         from opensearch_tpu.ops import knn as knn_ops
 
+        mapper = self.mapper_service.field_mapper(node.field)
+        if not nested and mapper is not None and mapper.nested_path:
+            raise IllegalArgumentException(
+                f"[knn] on [{node.field}], a multi-vector field under nested "
+                f"path [{mapper.nested_path}]: search it with a [nested] "
+                f"query on that path")
         # each segment's launch row (vals, ids), None without the field
         seg_rows: list[tuple[np.ndarray, np.ndarray] | None] = []
-        valids = self._knn_valid_masks(node)
+        valids = ([None] * len(self.snapshot.segments) if nested
+                  else self._knn_valid_masks(node))
         for (host, dev), valid in zip(self.snapshot.segments, valids):
+            if nested:
+                nvf = (dev.nested_vector_fields or {}).get(node.field)
+                seg_rows.append(None if nvf is None else
+                                self._nested_row(node, host, dev, nvf))
+                continue
             vf = dev.vector_fields.get(node.field)
             if vf is None:
                 seg_rows.append(None)
@@ -381,7 +465,7 @@ class ShardContext:
                 touch_allocs = _touch_targets(dev, node.field, ann=vf.ann)
 
                 def launch_ann(rows):
-                    _mark_launch_filtered(False)
+                    _mark_launch(False)
                     with profile.profiling(None):
                         with tracing.detail(span_names.LAUNCH_HOST_PRE):
                             q_batch = _pad_query_batch(rows)
@@ -515,7 +599,7 @@ class ShardContext:
                 touch_allocs = _touch_targets(dev, node.field)
 
                 def launch_fused(rows):
-                    _mark_launch_filtered(node.filter is not None)
+                    _mark_launch(node.filter is not None)
                     q_batch = _pad_query_batch(rows)
                     t0 = time.perf_counter_ns()
                     with profile.profiling(None):
@@ -603,6 +687,65 @@ class ShardContext:
         ]
         self._knn_cache[id(node)] = out
         return out
+
+    def _nested_row(self, node, host, dev, nvf):
+        """One segment's launch row of a nested kNN: (scores, documents),
+        best first, through the batcher (concurrent requests over the same
+        rows and reader generation share a launch)."""
+        from opensearch_tpu.ops import knn as knn_ops
+        from opensearch_tpu.ops import pallas_knn as pallas_knn_ops
+        from opensearch_tpu.search import batcher as batcher_mod
+        from opensearch_tpu.search.ann import default_config as ann_config
+        from opensearch_tpu.telemetry.device_ledger import default_ledger
+
+        k_req = max(1, min(int(node.k), host.n_docs))
+        k_bucket = 1 << (k_req - 1).bit_length()
+        sim = knn_ops.canonical_similarity(nvf.similarity)
+        impl, interpret = pallas_knn_ops.fused_impl(ann_config.exact_kernel,
+                                                    k_bucket)
+        allocs = dev.allocations or {}
+        touch_allocs = [a for a in (allocs.get(node.field),
+                                    allocs.get(f"_live:{node.field}"))
+                        if a is not None]
+
+        def launch_nested(rows):
+            _mark_launch(False, nested=True)
+            q_batch = _pad_query_batch(rows)
+            t0 = time.perf_counter_ns()
+            with profile.profiling(None):
+                b_vals, b_ids = pallas_knn_ops.knn_nested(
+                    nvf.vectors, nvf.norms_sq, nvf.valid, nvf.parent,
+                    q_batch, k=k_bucket, n_docs=dev.n_pad, similarity=sim,
+                    impl=impl, interpret=interpret,
+                )
+            # host materialization is the fence for this launch
+            b_vals = np.asarray(b_vals)
+            b_ids = np.asarray(b_ids)
+            launch_params = dict(
+                b=int(q_batch.shape[0]), n=int(nvf.vectors.shape[0]),
+                d=nvf.dims, k=k_bucket, r=k_bucket, precision="fp32")
+            roofline.record_launch("knn_nested_pallas[fp32]",
+                                   time.perf_counter_ns() - t0,
+                                   **launch_params)
+            default_ledger.touch(touch_allocs, family="knn_nested_pallas",
+                                 params=launch_params)
+            retraced = profile.signature_retraced(
+                "knn_nested_pallas", (nvf.vectors, q_batch),
+                (k_bucket, sim, impl, interpret))
+            return [(b_vals[i], b_ids[i]) for i in range(len(rows))], retraced
+
+        out = batcher_mod.dispatch(
+            ("knn_nested", id(nvf), self.snapshot.generation, k_bucket, sim,
+             impl, interpret),
+            np.asarray(node.vector, np.float32), launch_nested,
+            shards=1, rank=k_bucket, family="knn_nested_pallas",
+            tune_key=("knn_nested_pallas", id(self.mapper_service),
+                      node.field, k_bucket))
+        self.nested["children"] += nvf.rows
+        self.nested["parents"] += host.n_docs
+        self.nested["launches"] += 1
+        _count_knn_path("fused")
+        return out.value
 
     def _knn_valid_masks(self, node) -> list:
         """Per segment, the rows a KnnQuery's launch may return: `present &
@@ -1912,10 +2055,11 @@ class SegmentExecutor:
         scores = jnp.where(mask, scores, 0.0) * node.boost
         return NodeResult(scores=scores, mask=mask, scoring=any_scoring)
 
-    def _exec_KnnQuery(self, node: q.KnnQuery) -> NodeResult:
+    def _exec_KnnQuery(self, node: q.KnnQuery,
+                       nested: bool = False) -> NodeResult:
         # k applies per SHARD (top-k cut across all its segments) — the
         # ShardContext caches the shard-wide selection per query node
-        selections = self.ctx.shard_knn_selection(node)
+        selections = self.ctx.shard_knn_selection(node, nested=nested)
         seg_idx = next(
             i for i, (h, d) in enumerate(self.ctx.snapshot.segments) if d is self.dev
         )
@@ -2200,6 +2344,10 @@ class SegmentExecutor:
         return NodeResult(jnp.where(mask, scores, 0.0) * node.boost, mask, True)
 
     def _exec_NestedQuery(self, node: q.NestedQuery) -> NodeResult:
+        # over a multi-vector field: the k best documents by their best row
+        knn_node = self.ctx.nested_knn(node)
+        if knn_node is not None:
+            return self._exec_KnnQuery(knn_node, nested=True)
         # Flattened semantics: arrays of objects were indexed as multi-valued
         # dotted columns, so the inner query already addresses path.field.
         r = self.execute(node.query)
@@ -2550,11 +2698,17 @@ def execute_query_phase(
     min_score: float | None = None,
 ) -> ShardQueryResult:
     ctx = ShardContext(snapshot, mapper_service)
-    with _bm25_span(query_node) as scored:
+    with _bm25_span(query_node) as scored, \
+            _nested_span(query_node, mapper_service) as searched:
         result = _query_phase(ctx, query_node, size, sort, need_masks,
                               min_score)
         for key, value in ctx.bm25.items():
             scored.set_attribute(key, value)
+        for key, value in ctx.nested.items():
+            searched.set_attribute(key, value)
+        searched.set_attribute("road", "shard")
+    if any(knn is not None for knn in ctx._nested.values()):
+        count_nested_knn(ctx.nested["children"])
     return result
 
 

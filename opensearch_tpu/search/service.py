@@ -41,7 +41,9 @@ from opensearch_tpu.search.executor import (
     _sort_spec,
     _StrKey,
     count_metric,
+    count_nested_knn,
     execute_query_phase,
+    nested_knn,
 )
 
 DEFAULT_SIZE = 10
@@ -1115,10 +1117,24 @@ def _try_distributed_query_phase(
     (launch_id / wall_ns / retraced / shards / merged) for per-shard
     profiling. None when the host merge must run (every other query
     shape, or a non-resident shard set the mesh cannot serve — the
-    caller's per-shard loop is the fallback)."""
-    if not isinstance(node, query_dsl.KnnQuery):
+    caller's per-shard loop is the fallback).
+
+    A `nested` query whose `knn` searches a multi-vector field
+    (executor.nested_knn; an unfiltered one) takes the same road: its
+    launch reduces the rows to documents before the cut, and its hits are
+    documents. It opens the `knn.nested` span and counts
+    `search.knn.nested.*` here, once a request."""
+    if not shards:
         return None
-    if (not shards or sort or search_after is not None
+    nested = None
+    if isinstance(node, query_dsl.NestedQuery):
+        nested = nested_knn(node, shards[0].mapper_service)
+        if nested is None or any(f is not None for f in filter_nodes):
+            return None
+        node = nested
+    elif not isinstance(node, query_dsl.KnnQuery):
+        return None
+    if (sort or search_after is not None
             or aggs_body is not None or min_score is not None):
         return None
     from opensearch_tpu.search import distributed_serving
@@ -1140,7 +1156,7 @@ def _try_distributed_query_phase(
     # and only then learn the mesh cannot serve it — paying a batch wait
     # just to fall back. The per-shard loop below dispatches it through
     # the ANN batch key instead (executor.shard_knn_selection).
-    if (node.filter is None
+    if (nested is None and node.filter is None
             and not any(f is not None for f in filter_nodes)
             and any(
                 (vf := dev.vector_fields.get(node.field)) is not None
@@ -1154,10 +1170,11 @@ def _try_distributed_query_phase(
     # in the key is the snapshot-safety invariant: a refresh mid-flight is
     # a different key, so no query is ever answered from another request's
     # (older or newer) snapshot.
+    family = "distributed_knn" if nested is None else "distributed_nested_knn"
     key = None
     if node.filter is None and not any(f is not None for f in filter_nodes):
         key = (
-            "distributed_knn", shards[0].shard_id.index, node.field,
+            family, shards[0].shard_id.index, node.field,
             int(node.k), int(fetch_k),
             tuple(sh.engine.instance_id for sh in shards),
             tuple(snap.generation for snap in snaps),
@@ -1169,7 +1186,7 @@ def _try_distributed_query_phase(
     def launch(nodes_batch):
         out_b = distributed_serving.mesh_knn_batch(
             shards, snaps, list(nodes_batch), fetch_k,
-            alias_filters=filter_nodes,
+            alias_filters=filter_nodes, nested=nested is not None,
         )
         if out_b is None:  # ineligible: every member falls back
             return [None] * len(nodes_batch), False
@@ -1183,11 +1200,25 @@ def _try_distributed_query_phase(
     # a filtered query's mask is request-private: key None, so the batcher
     # launches it alone, under the same `launch` span and in the same
     # `dispatches` / `merged_queries` as every other launch
-    outcome = batcher_mod.dispatch(
-        key, node, launch, shards=len(shards),
-        # generation-free family for the wait auto-tuner
-        tune_key=("distributed_knn", shards[0].shard_id.index,
-                  node.field, int(node.k)))
+    with (tracing.detail(span_names.KNN_NESTED) if nested is not None
+          else tracing.NO_DETAIL) as searched:
+        outcome = batcher_mod.dispatch(
+            key, node, launch, shards=len(shards),
+            # generation-free family for the wait auto-tuner
+            tune_key=(family, shards[0].shard_id.index, node.field,
+                      int(node.k)))
+        if nested is not None and outcome.value is not None:
+            fields = [(host, nvf) for snap in snaps
+                      for host, dev in snap.segments
+                      if (nvf := (dev.nested_vector_fields or {})
+                          .get(node.field)) is not None]
+            children = sum(nvf.rows for _host, nvf in fields)
+            searched.set_attribute("children", children)
+            searched.set_attribute("parents",
+                                   sum(host.n_docs for host, _ in fields))
+            searched.set_attribute("road", "mesh")
+            searched.set_attribute("launches", 1)
+            count_nested_knn(children)
     if outcome.value is None:
         return None
     results, premerged, launch_info = outcome.value

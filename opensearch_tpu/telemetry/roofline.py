@@ -281,6 +281,9 @@ COST_MODELS: dict[str, Callable[[dict], tuple[int, int]]] = {
     "ivfpq_adc_pallas": _model_ivfpq_adc_pallas,
     "knn_fused_pallas": _model_knn_fused,
     "mesh_knn_fused": _model_mesh_fused,
+    # a multi-vector field's scan: the same kernel over its rows
+    "knn_nested_pallas": _model_knn_fused,
+    "mesh_knn_nested": _model_mesh_fused,
     "bm25_term_scores": _model_bm25,
     "constant_term_scores": _model_constant_terms,
 }

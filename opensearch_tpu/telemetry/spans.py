@@ -85,6 +85,16 @@ FILTER_MASK = "filter.mask"
 BM25_SCORE = "bm25.score"
 HYBRID_FUSE = "hybrid.fuse"
 
+# a `nested` `knn` over a multi-vector field (DETAIL; beside `ALL` as
+# `bm25.score` is): one shard's nested kNN query phase, on the per-shard
+# road from the first segment's launch to the shard's top-k on the host,
+# on the mesh road (search/service.py) the one launch that serves every
+# shard, from the batcher's queue to the documents it returned. It carries
+# `children` (the rows searched, unpadded), `parents` (the documents they
+# belong to), `road` (`mesh` / `shard`) and `launches`; the launch carries
+# `nested` 1 (0 on any other kNN launch)
+KNN_NESTED = "knn.nested"
+
 # process
 RUNTIME_GC = "runtime.gc"
 

@@ -377,9 +377,10 @@ def test_a_follower_names_the_leaders_launch(node, tmp_path):
     assert len(launches) == 1 and launches[0]["attributes"] == {
         "merged": 2, "reason": "size",
         # from the mesh program: the launch's shape (one shard, one
-        # device), its one device -> host transfer, and no filter
+        # device), its one device -> host transfer, no filter and no
+        # multi-vector field
         "devices": 1, "shards": 1, "b_pad": 2, "host_copies": 1,
-        "filtered": 0}
+        "filtered": 0, "nested": 0}
     waits = {s["attributes"]["reason"]: s for s in doc["spans"]
              if s["name"] == "batch.wait"}
     assert set(waits) == {"size", "follower"}

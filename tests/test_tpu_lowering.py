@@ -22,7 +22,10 @@ Shapes are the ones `chip_smoke.py` launches: d = 128, k = 10; exact scan
 over n = 2^20 (phase A) and 2^18 rows; B in {1, 8, 16}; IVF-PQ defaults
 (nlist 128, m 8, ks 256) with nprobe 8 and 32. And the one the benchmark's
 `hybrid-bm25-knn` launches (PR 37): the exact scan at d = 768, the width
-text is embedded with, over 2^18 rows, fp32, B in {1, 8}. Its lexical
+text is embedded with, over 2^18 rows, fp32, B in {1, 8}; and the one
+`knn-nested-chunks` launches: the nested scan (`knn_nested`, the same
+kernel whose pool holds documents) over 2^19 chunk rows at d = 768, B in
+{1, 8, 32}. The hybrid cell's lexical
 half is no Pallas kernel but one XLA program (`ops/bm25.bm25_term_scores`,
 PR 38): layer 2 compiles it at the cell's shapes (2^25 posting entries,
 2^18 rows, rows of `executor.BM25_CHUNK` entries, the ladder's least and
@@ -69,6 +72,18 @@ def knn_fused_case(n: int, b: int, precision: str, sharding=None,
                _sds((n,), jnp.bool_, s), _sds((b, d), jnp.float32, s))
 
 
+def knn_nested_case(n: int, b: int, sharding=None, d: int = 768):
+    def f(vectors, norms_sq, valid, parent, queries):
+        return pallas_knn.knn_nested(
+            vectors, norms_sq, valid, parent, queries, k=K, n_docs=n // 4,
+            similarity="l2_norm", impl="pallas", interpret=False)
+
+    s = sharding
+    return f, (_sds((n, d), jnp.float32, s), _sds((n,), jnp.float32, s),
+               _sds((n,), jnp.bool_, s), _sds((n,), jnp.int32, s),
+               _sds((b, d), jnp.float32, s))
+
+
 def adc_case(b: int, nprobe: int, precision: str, sharding=None,
              n: int = 1 << 18, l_pad: int = 4096):
     nlist, m, ks = ivfpq.DEFAULT_NLIST, ivfpq.DEFAULT_M, ivfpq.DEFAULT_KS
@@ -96,6 +111,9 @@ def all_cases(sharding=None):
     for b in (1, 8):
         yield (f"knn_fused[fp32] n={1 << 18} d=768 B={b}",
                *knn_fused_case(1 << 18, b, "fp32", sharding, d=768))
+    for b in (1, 8, 32):
+        yield (f"knn_nested[fp32] n={1 << 19} d=768 B={b}",
+               *knn_nested_case(1 << 19, b, sharding))
     for precision in PRECISIONS:
         for b in BATCHES:
             for n in (1 << 20, 1 << 18):
