@@ -61,10 +61,15 @@ def _pad1(a: np.ndarray, n: int, fill=0) -> np.ndarray:
 class DeviceTextField:
     postings_docs: jnp.ndarray    # int32 [P_pad]
     postings_tfs: jnp.ndarray     # float32 [P_pad]
+    # each posting's document length, doc_len[postings_docs]: the BM25
+    # launch reads it with the posting instead of gathering doc_len.
+    # Derived at upload, never persisted
+    postings_dl: jnp.ndarray      # float32 [P_pad]
     doc_len: jnp.ndarray          # float32 [n_pad]
-    # the row width at which every rung of the BM25 launch's ladder has
-    # been compiled for this field (search/executor.py `_bm25`); 0: none
-    ladder_width: int = 0
+    # the (row width, counted) variants of the BM25 launch whose every rung
+    # of the ladder has been compiled for this field (search/executor.py
+    # `_compile_ladder`)
+    ladders: set = dataclasses.field(default_factory=set)
 
 
 @dataclass
@@ -261,9 +266,11 @@ def to_device(seg: HostSegment, device=None) -> DeviceSegment:
         text_fields[fname] = dtf = DeviceTextField(
             postings_docs=put(_pad1(tf.postings_docs, p_pad)),
             postings_tfs=put(_pad1(tf.postings_tfs, p_pad)),
+            postings_dl=put(_pad1(tf.doc_len[tf.postings_docs], p_pad)),
             doc_len=put(_pad1(tf.doc_len, n_pad)),
         )
-        track(fname, dtf.postings_docs, dtf.postings_tfs, dtf.doc_len)
+        track(fname, dtf.postings_docs, dtf.postings_tfs, dtf.postings_dl,
+              dtf.doc_len)
 
     keyword_fields: dict[str, DeviceKeywordField] = {}
     for fname, kf in seg.keyword_fields.items():

@@ -437,14 +437,17 @@ class TpuNode:
                 "knn.filter.postings_builds"),
         }
         # hybrid searches served, and what their (and any other query's)
-        # BM25 scoring launched: device launches, the posting entries of
-        # the terms they looked up and the row entries the launches worked
-        # over (search/executor.py `_bm25`)
+        # BM25 scoring launched: device launches, those that scattered no
+        # match-count column, the posting entries of the terms they looked
+        # up and the row entries the launches worked over
+        # (search/executor.py `_bm25`)
         lexical = {
             "hybrid_requests": self.telemetry.metrics.counter(
                 "search.hybrid.requests"),
             "bm25_launches": self.telemetry.metrics.counter(
                 "search.bm25.launches"),
+            "bm25_uncounted_launches": self.telemetry.metrics.counter(
+                "search.bm25.uncounted_launches"),
             "bm25_postings": self.telemetry.metrics.counter(
                 "search.bm25.postings"),
             "bm25_entries": self.telemetry.metrics.counter(

@@ -88,10 +88,11 @@ def _knn_metrics():
 def count_metric(name: str, amount: int = 1) -> None:
     """One of the node's query-phase counters: `knn.collect.dense` /
     `.sparse` (once a request and shard), `knn.filter.postings_builds`,
-    `search.bm25.launches` / `.postings` / `.entries` (once a `_bm25` call;
-    the posting entries of the terms it looked up; the row entries its
-    launches worked over), `search.hybrid.requests` (once a
-    `hybrid` search, whatever its sub-queries and shards)."""
+    `search.bm25.launches` / `.uncounted_launches` / `.postings` /
+    `.entries` (once a `_bm25` call; once a call whose launches scattered
+    no match-count column; the posting entries of the terms it looked up;
+    the row entries its launches worked over), `search.hybrid.requests`
+    (once a `hybrid` search, whatever its sub-queries and shards)."""
     metrics = _knn_metrics()
     if metrics is not None:
         metrics.counter(name).add(amount)
@@ -144,36 +145,37 @@ def _padded(values: np.ndarray, n: int, dtype) -> np.ndarray:
 
 
 def _add_launch(total, launched):
-    """(scores, counts) of a `_bm25` call's launches so far, plus one more
-    launch's."""
+    """What a `_bm25` call's launches return so far (the scores, or the
+    scores and the counts), plus one more launch's."""
     if total is None:
         return launched
-    return total[0] + launched[0], total[1] + launched[1]
+    return jax.tree.map(jnp.add, total, launched)
 
 
 _ladder_lock = threading.Lock()
 
 
-def _compile_ladder(dev_tf, n_pad: int, width: int) -> None:
+def _compile_ladder(dev_tf, n_pad: int, width: int, counts: bool) -> None:
     """Launch every rung once at `width`, rows of length 0, and add the
-    launches up, the first time this field is scored at that width: the
-    rare rungs and the sum of a call of several launches are compiled by
-    the first full-text search the segment serves and not by one long
-    after, and searches that arrive together wait here instead of
-    compiling."""
-    if dev_tf.ladder_width == width:
+    launches up, the first time this field is scored at that width by the
+    variant (`counts` or not) the caller needs: the rare rungs and the sum
+    of a call of several launches are compiled by the first full-text
+    search the segment serves and not by one long after, and searches that
+    arrive together wait here instead of compiling."""
+    variant = (width, counts)
+    if variant in dev_tf.ladders:
         return
     with _ladder_lock:
-        if dev_tf.ladder_width == width:
+        if variant in dev_tf.ladders:
             return
         total = None
         for rung in BM25_ROW_RUNGS:
             rows = np.zeros(rung, np.int32)
             total = _add_launch(total, bm25.bm25_term_scores(
-                dev_tf.postings_docs, dev_tf.postings_tfs, dev_tf.doc_len,
+                dev_tf.postings_docs, dev_tf.postings_tfs, dev_tf.postings_dl,
                 rows, rows, np.zeros(rung, np.float32), np.float32(1.0),
-                n_pad=n_pad, window=width))
-        dev_tf.ladder_width = width
+                n_pad=n_pad, window=width, counts=counts))
+        dev_tf.ladders.add(variant)
 
 
 _FULL_TEXT_NODES = (q.MatchQuery, q.MatchPhraseQuery, q.MatchPhrasePrefixQuery)
@@ -1163,8 +1165,13 @@ class SegmentExecutor:
 
     # -- text scoring ------------------------------------------------------
 
-    def _bm25(self, field: str, terms: list[str], boost: float) -> tuple[NodeResult, jnp.ndarray]:
-        """Returns (result, per-doc matched-term counts)."""
+    def _bm25(self, field: str, terms: list[str], boost: float,
+              counts: bool = False) -> tuple[NodeResult, jnp.ndarray | None]:
+        """Returns (result, per-doc matched-term counts, or None where the
+        launches counted nothing). A caller that reads more than whether a
+        doc matched at all asks for `counts`; for the others the launches
+        scatter no count column, unless a term's idf is not positive (a
+        matched doc may then score 0 or below, so the mask is `counts > 0`)."""
         dev_tf = self.dev.text_fields.get(field)
         host_tf = self.host.text_fields.get(field)
         if dev_tf is None or host_tf is None or not terms:
@@ -1185,8 +1192,9 @@ class SegmentExecutor:
         offs, lens, idfs = _chunk_rows(np.asarray(offs, np.int64),
                                        np.asarray(lens, np.int64),
                                        np.asarray(idfs, np.float32), width)
+        counted = counts or bool((idfs[lens > 0] <= 0).any())
         if width == BM25_CHUNK:
-            _compile_ladder(dev_tf, self.dev.n_pad, width)
+            _compile_ladder(dev_tf, self.dev.n_pad, width, counted)
         most = BM25_ROW_RUNGS[-1]
         launches = [slice(lo, lo + most) for lo in range(0, len(offs), most)]
         rungs = [next(r for r in BM25_ROW_RUNGS if r >= len(offs[part]))
@@ -1200,6 +1208,8 @@ class SegmentExecutor:
         tally["window"] = max(tally["window"], width)
         tally["rows"] += self.dev.n_pad
         count_metric("search.bm25.launches")
+        if not counted:
+            count_metric("search.bm25.uncounted_launches")
         count_metric("search.bm25.postings", postings)
         count_metric("search.bm25.entries", entries)
         total = None
@@ -1212,17 +1222,24 @@ class SegmentExecutor:
             total = _add_launch(total, bm25.bm25_term_scores(
                 dev_tf.postings_docs,
                 dev_tf.postings_tfs,
-                dev_tf.doc_len,
+                dev_tf.postings_dl,
                 _padded(offs[part], rung, np.int32),
                 _padded(lens[part], rung, np.int32),
                 _padded(idfs[part], rung, np.float32),
                 np.float32(avgdl),
                 n_pad=self.dev.n_pad,
                 window=width,
+                counts=counted,
             ))
-        scores, counts = total
-        mask = counts > 0
-        return NodeResult(scores=scores * boost, mask=mask, scoring=True), counts
+        if counted:
+            scores, matched = total
+            mask = matched > 0
+        else:
+            # every contribution is idf * tf / (tf + norm) with idf > 0 and
+            # tf >= 1: a doc scores above 0 exactly where a row holds it
+            scores, matched = total, None
+            mask = scores > 0
+        return NodeResult(scores=scores * boost, mask=mask, scoring=True), matched
 
     # -- dispatch ----------------------------------------------------------
 
@@ -1281,7 +1298,10 @@ class SegmentExecutor:
             # zero analyzed tokens (e.g. all stopwords) matches nothing,
             # like the reference's MatchNoDocsQuery rewrite
             return _empty(self.dev)
-        result, counts = self._bm25(node.field, terms, node.boost)
+        counting = node.operator == "and" or \
+            node.minimum_should_match is not None
+        result, counts = self._bm25(node.field, terms, node.boost,
+                                    counts=counting)
         if node.operator == "and":
             result = NodeResult(
                 scores=result.scores, mask=counts >= len(terms), scoring=True
@@ -1301,7 +1321,8 @@ class SegmentExecutor:
         terms = self.ctx.mapper_service.analyze_query_text(node.field, node.query)
         if not terms:
             return _empty(self.dev)
-        result, counts = self._bm25(node.field, terms, node.boost)
+        result, counts = self._bm25(node.field, terms, node.boost,
+                                    counts=True)
         conj = (counts >= len(terms)) & self.dev.live
         host_tf = self.host.text_fields.get(node.field)
         if len(terms) <= 1 or host_tf is None or not host_tf.has_positions:
@@ -2255,7 +2276,8 @@ class SegmentExecutor:
         *body_terms, last = terms
         result = None
         if body_terms:
-            r, counts = self._bm25(node.field, body_terms, node.boost)
+            r, counts = self._bm25(node.field, body_terms, node.boost,
+                                   counts=True)
             result = NodeResult(r.scores, counts >= len(body_terms), True)
         # expand the final term as a prefix (bounded by max_expansions, like
         # MatchPhrasePrefixQuery's MultiPhrasePrefixQuery expansion)

@@ -146,7 +146,7 @@ class TestEngineResidency:
         tf = dev.text_fields["title"]
         assert rows["title"]["bytes"] == sum(
             int(a.nbytes) for a in
-            (tf.postings_docs, tf.postings_tfs, tf.doc_len))
+            (tf.postings_docs, tf.postings_tfs, tf.postings_dl, tf.doc_len))
         nf = dev.numeric_fields["n"]
         assert rows["n"]["bytes"] == sum(
             int(a.nbytes) for a in (nf.hi, nf.lo, nf.present))

@@ -11,9 +11,10 @@ request body: resolved where an id is, validated, stored nowhere), and what
 makes the path visible: the detail spans `bm25.score` and `hybrid.fuse`,
 `search.query_phase`'s `sub_queries`, and the counters
 `search.hybrid.requests`, `search.bm25.launches`, `search.bm25.postings`,
-`search.bm25.entries` (registered with the node, so a 0 shows); and the
-launch's layout: a term's list cut into rows of at most `BM25_CHUNK`
-entries, rows padded up the ladder `BM25_ROW_RUNGS`.
+`search.bm25.entries`, `search.bm25.uncounted_launches` (registered with
+the node, so a 0 shows; a hybrid request's OR match launches uncounted);
+and the launch's layout: a term's list cut into rows of at most
+`BM25_CHUNK` entries, rows padded up the ladder `BM25_ROW_RUNGS`.
 
 Every profiler session here starts and stops inside a test of this file's
 own process; nothing touches the profiler at import time."""
@@ -83,8 +84,8 @@ def world(tmp_path_factory):
     node = TpuNode(home / "node")
     # registered with the node: a 0 is shown as a 0, before any request
     assert _counters(node) == (0, 0, 0)
-    assert node.telemetry.metrics.stats()["counters"][
-        "search.bm25.entries"] == 0
+    for name in ("search.bm25.entries", "search.bm25.uncounted_launches"):
+        assert node.telemetry.metrics.stats()["counters"][name] == 0
     node.create_index(INDEX, CONF["index_body"])
     acked = 0
     for lo, n, body in KIND.bulk_bodies(CONF, data):
@@ -132,10 +133,10 @@ def _launches(monkeypatch) -> list:
     launched = []
     launch = executor.bm25.bm25_term_scores
 
-    def recorded(docs, tfs, doc_len, offsets, lengths, idfs, *args, **kw):
+    def recorded(docs, tfs, dl, offsets, lengths, idfs, *args, **kw):
         launched.append((len(offsets), lengths.tolist(), idfs.tolist(),
                          kw["window"]))
-        return launch(docs, tfs, doc_len, offsets, lengths, idfs, *args, **kw)
+        return launch(docs, tfs, dl, offsets, lengths, idfs, *args, **kw)
 
     monkeypatch.setattr(executor.bm25, "bm25_term_scores", recorded)
     return launched
@@ -414,6 +415,9 @@ def test_a_capture_holds_bm25_score_and_hybrid_fuse_in_every_request(
     assert closed["hybrid_requests"] == opened["hybrid_requests"] + len(sent)
     assert closed["bm25_launches"] == (
         opened["bm25_launches"] + len(sent) * segments)
+    # a hybrid request's match is an OR match: no launch counts its terms
+    assert closed["bm25_uncounted_launches"] - opened[
+        "bm25_uncounted_launches"] == len(sent) * segments
     assert closed["bm25_postings"] - opened["bm25_postings"] == sum(
         int(df[w]) for i in sent for w in questions.words[i])
     assert closed["bm25_entries"] - opened["bm25_entries"] == sum(

@@ -1,7 +1,9 @@
 """Core pipeline: parse docs -> build segment -> device arrays -> score ops."""
 
+import functools
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -77,7 +79,7 @@ def test_bm25_scoring_matches_formula(segment):
         lens.append(int(tf.term_offsets[tid + 1] - tf.term_offsets[tid]))
         idfs.append(bm25.idf(tf.doc_freq(t), n_docs))
     scores, counts = bm25.bm25_term_scores(
-        tfd.postings_docs, tfd.postings_tfs, tfd.doc_len,
+        tfd.postings_docs, tfd.postings_tfs, tfd.postings_dl,
         jnp.asarray(offs, jnp.int32), jnp.asarray(lens, jnp.int32),
         jnp.asarray(idfs, jnp.float32), jnp.float32(avgdl),
         n_pad=n_pad, window=8,
@@ -197,7 +199,9 @@ def _launch_args(name, metadata):
     avgdl = np.float32(doc_len.mean())
     host = (docs, tfs, doc_len, offsets, lengths, idfs, avgdl)
     as_meta = jnp.asarray if metadata == "device" else np.asarray
-    device = (jnp.asarray(docs), jnp.asarray(tfs), jnp.asarray(doc_len),
+    # the launch reads each posting's length from a posting-aligned column
+    # (index/device.py `postings_dl`); the loop reads doc_len by doc
+    device = (jnp.asarray(docs), jnp.asarray(tfs), jnp.asarray(doc_len[docs]),
               as_meta(offsets), as_meta(lengths), as_meta(idfs), avgdl)
     return host, device, n_pad, window
 
@@ -257,28 +261,28 @@ def _equations(jaxpr):
 @pytest.mark.parametrize("rows", [4, 8, 20])
 def test_a_term_row_is_read_as_a_slice_and_not_gathered(rows):
     """The traced program builds no [rows, window] index array into the
-    posting columns: each row is `window` contiguous entries, and the one
-    element gather left is `doc_len[docs]`."""
-    import jax
-
+    posting columns: each row is `window` contiguous entries of each column
+    (the doc ids, the tfs and the docs' lengths), and no element gather is
+    left, counted or not."""
     p_pad, n_pad, window = 4096, 256, 128        # p_pad != n_pad: told apart
     meta = (np.zeros(rows, np.int32), np.zeros(rows, np.int32),
             np.zeros(rows, np.float32))
-    columns = (jnp.zeros(p_pad, jnp.int32), jnp.zeros(p_pad, jnp.float32))
-    doc_len = jnp.ones(n_pad, jnp.float32)
+    columns = (jnp.zeros(p_pad, jnp.int32), jnp.zeros(p_pad, jnp.float32),
+               jnp.ones(p_pad, jnp.float32))
 
-    def gathers(fn, *args):
+    def gathers(fn, *args, **static):
         """(operand shape, index array shape, slice_sizes) of every gather."""
         eqns = _equations(jax.make_jaxpr(
-            lambda *a: fn(*a, n_pad=n_pad, window=window))(*args).jaxpr)
+            lambda *a: fn(*a, n_pad=n_pad, window=window, **static))(
+                *args).jaxpr)
         return sorted((eqn.invars[0].aval.shape, eqn.invars[1].aval.shape,
                        tuple(eqn.params["slice_sizes"]))
                       for eqn in eqns if eqn.primitive.name == "gather")
 
     a_column = ((p_pad,), (rows, 1), (window,))     # `rows` starts, whole slices
-    assert gathers(bm25.bm25_term_scores, *columns, doc_len, *meta,
-                   np.float32(3.0)) == sorted(
-        [((n_pad,), (rows, window, 1), (1,)), a_column, a_column])
+    for counts in (True, False):
+        assert gathers(bm25.bm25_term_scores, *columns, *meta,
+                       np.float32(3.0), counts=counts) == [a_column] * 3
     assert gathers(bm25.constant_term_scores, columns[0], *meta) == [a_column]
 
 
@@ -300,6 +304,94 @@ def test_a_second_launch_of_the_same_shapes_traces_nothing():
     bm25.bm25_term_scores(docs, tfs, doc_len, *wider, avgdl, n_pad=n_pad, window=window)
     bm25.bm25_term_scores(docs, tfs, doc_len, *wider, avgdl, n_pad=n_pad, window=window)
     assert program._cache_size() == compiled + 1
+
+
+# -- the posting-length column and the count column, against the program
+# that gathered doc_len[docs] and always counted ------------------------------
+
+@functools.partial(jax.jit, static_argnames=("n_pad", "window"))
+def _gathering_program(postings_docs, postings_tfs, doc_len, offsets, lengths,
+                       idfs, avgdl, n_pad, window,
+                       k1=bm25.K1_DEFAULT, b=bm25.B_DEFAULT):
+    """The launch as it was before the length travelled with the posting:
+    `doc_len[docs]` gathered from the [n_pad] column, two scatter-adds."""
+    valid, docs, tfs = bm25._term_rows(offsets, lengths, window,
+                                       postings_docs, postings_tfs)
+    docs = jnp.where(valid, docs, 0)
+    dl = doc_len[docs]
+    denom = tfs + k1 * (1.0 - b + b * dl / avgdl)
+    contrib = idfs[:, None] * tfs / jnp.maximum(denom, 1e-9)
+    contrib = jnp.where(valid, contrib, 0.0)
+    flat_docs = docs.reshape(-1)
+    scores = jnp.zeros(n_pad, jnp.float32).at[flat_docs].add(contrib.reshape(-1))
+    counts = jnp.zeros(n_pad, jnp.int32).at[flat_docs].add(
+        valid.reshape(-1).astype(jnp.int32))
+    return scores, counts
+
+
+def _corpus_launch(seed):
+    """A seeded random corpus's CSR columns and one OR match over it, laid
+    out as `_bm25` lays it out: lists of up to 40,000 documents cut into
+    rows of `BM25_CHUNK` = 16,384 entries, an absent term (a row of length
+    0), and the rows padded up the ladder with more rows of length 0."""
+    from opensearch_tpu.search.executor import (BM25_CHUNK, BM25_ROW_RUNGS,
+                                                _chunk_rows, _padded)
+
+    rng = np.random.default_rng(seed)
+    n_docs = 50_000 + int(rng.integers(0, 10_000))
+    n_pad = 1 << 16
+    dfs = [40_000, 17_000, int(rng.integers(1, 300)), 0, 16_384, 5]
+    lists = [np.sort(rng.choice(n_docs, df, replace=False)) for df in dfs]
+    offsets = np.cumsum([0] + dfs[:-1])
+    docs = np.concatenate(lists).astype(np.int32)
+    tfs = rng.integers(1, 9, len(docs)).astype(np.float32)
+    doc_len = np.zeros(n_pad, np.float32)
+    doc_len[:n_docs] = rng.integers(1, 400, n_docs)
+    p_pad = 1 << int(len(docs) - 1).bit_length()
+    docs = np.concatenate([docs, np.zeros(p_pad - len(docs), np.int32)])
+    tfs = np.concatenate([tfs, np.zeros(p_pad - len(tfs), np.float32)])
+    idfs = np.asarray([bm25.idf(df, n_docs) for df in dfs], np.float32)
+    offs, lens, row_idfs = _chunk_rows(offsets.astype(np.int64),
+                                       np.asarray(dfs, np.int64), idfs,
+                                       BM25_CHUNK)
+    rung = next(r for r in BM25_ROW_RUNGS if r >= len(offs))
+    assert rung > len(offs) and (lens == 0).any() and (lens == BM25_CHUNK).any()
+    rows = (_padded(offs, rung, np.int32), _padded(lens, rung, np.int32),
+            _padded(row_idfs, rung, np.float32), np.float32(doc_len[:n_docs].mean()))
+    return docs, tfs, doc_len, rows, n_pad, BM25_CHUNK
+
+
+@pytest.mark.parametrize("seed", [42, 2**31 + 42, 3142000042])
+@pytest.mark.parametrize("check", ["scores_bitwise", "counts", "or_mask"])
+def test_the_posting_length_column_and_the_count_column_change_no_answer(
+        check, seed):
+    """scores_bitwise: the length read from the posting column scores every
+    document bit for bit as `doc_len[docs]` did, counted or not; counts:
+    the counted launch counts as before; or_mask: for an OR match (every
+    idf > 0) a document scores above 0 exactly where a row holds it, so
+    the uncounted launch's `scores > 0` is the old `counts > 0`."""
+    docs, tfs, doc_len, rows, n_pad, window = _corpus_launch(seed)
+    static = {"n_pad": n_pad, "window": window}
+    old_scores, old_counts = _gathering_program(
+        jnp.asarray(docs), jnp.asarray(tfs), jnp.asarray(doc_len), *rows,
+        **static)
+    columns = (jnp.asarray(docs), jnp.asarray(tfs),
+               jnp.asarray(doc_len[docs]))
+    scores, counts = bm25.bm25_term_scores(*columns, *rows, **static)
+    uncounted = bm25.bm25_term_scores(*columns, *rows, counts=False, **static)
+    assert isinstance(uncounted, jax.Array)
+    old_scores, old_counts = np.asarray(old_scores), np.asarray(old_counts)
+    if check == "scores_bitwise":
+        for got in (scores, uncounted):
+            assert np.array_equal(np.asarray(got).view(np.uint32),
+                                  old_scores.view(np.uint32))
+    elif check == "counts":
+        assert np.array_equal(np.asarray(counts), old_counts)
+        assert old_counts.max() >= 4 and old_counts.sum() == rows[1].sum()
+    else:
+        assert (rows[2][rows[1] > 0] > 0).all()
+        assert np.array_equal(np.asarray(uncounted) > 0, old_counts > 0)
+        assert 0 < (old_counts > 0).sum() < n_pad
 
 
 def test_topk_tiebreak_prefers_lower_docid():

@@ -29,8 +29,11 @@ kernel whose pool holds documents) over 2^19 chunk rows at d = 768, B in
 half is no Pallas kernel but one XLA program (`ops/bm25.bm25_term_scores`,
 PR 38): layer 2 compiles it at the cell's shapes (2^25 posting entries,
 2^18 rows, rows of `executor.BM25_CHUNK` entries, the ladder's least and
-greatest rungs of term rows) and reads the compiled program: no element
-gather over a posting column may be left in it.
+greatest rungs of term rows, with and without the count column) and reads
+the compiled program: no element gather over a posting column may be left
+in it. On the CPU its lowered HLO is read too: one scatter-add uncounted,
+two counted, and no gather of an `[n_pad]` column (each posting's document
+length is read from a posting column).
 """
 
 from __future__ import annotations
@@ -155,15 +158,38 @@ def test_mosaic_compiles_served_kernels_for_v5e():
 
 
 def bm25_case(rows: int, sharding=None, postings: int = 1 << 25,
-              n: int = 1 << 18, window: int = executor.BM25_CHUNK):
+              n: int = 1 << 18, window: int = executor.BM25_CHUNK,
+              counts: bool = True):
     def f(*args):
-        return bm25.bm25_term_scores(*args, n_pad=n, window=window)
+        return bm25.bm25_term_scores(*args, n_pad=n, window=window,
+                                     counts=counts)
 
     s = sharding
     return f, (_sds((postings,), jnp.int32, s), _sds((postings,), jnp.float32, s),
-               _sds((n,), jnp.float32, s), _sds((rows,), jnp.int32, s),
+               _sds((postings,), jnp.float32, s), _sds((rows,), jnp.int32, s),
                _sds((rows,), jnp.int32, s), _sds((rows,), jnp.float32, s),
                _sds((), jnp.float32, s))
+
+
+@pytest.mark.parametrize("counts", [False, True])
+def test_the_bm25_launch_scatters_once_a_column_and_gathers_no_doc_column(
+        counts):
+    """The launch's HLO as lowered on this CPU: a scatter-add into the score
+    column, and into the count column only where the caller counts; every
+    gather is a row's slice of a `[P_pad]` posting column, none reads the
+    `[n_pad]` doc-length column."""
+    postings, n, window, rows = 4096, 256, 128, 8     # P_pad != n_pad
+    f, args = bm25_case(rows, postings=postings, n=n, window=window,
+                        counts=counts)
+    hlo = jax.jit(f).trace(*args).lower().as_text(dialect="hlo")
+    shape = dict(re.findall(r"^\s*(?:ROOT )?([\w.-]+) = \w+\[([\d,]*)\]",
+                            hlo, re.M))
+    ops = re.findall(r" = \w+\[[\d,]*\]\{[\d,]*\} (\w+)\(([\w.-]+)", hlo)
+    scatters = [operand for op, operand in ops if op == "scatter"]
+    gathers = [shape[operand] for op, operand in ops if op == "gather"]
+    assert [shape[operand] for operand in scatters] == [str(n)] * (1 + counts)
+    assert gathers == [str(postings)] * 3
+    assert hlo.count("slice_sizes={128}") == 3
 
 
 def _posting_column_gathers(hlo: str, postings: int = 1 << 25) -> list[str]:
@@ -186,9 +212,11 @@ def _compile_all_for_v5e() -> dict:
         return {"skip": f"no compile-only v5e topology: {str(e)[:200]}"}
     sharding = SingleDeviceSharding(topology.devices[0])
     failed, compiled = {}, 0
-    lexical = {f"bm25_term_scores rows={rows}": bm25_case(rows, sharding)
+    lexical = {f"bm25_term_scores rows={rows} counts={counts}":
+               bm25_case(rows, sharding, counts=counts)
                for rows in (executor.BM25_ROW_RUNGS[0],
-                            executor.BM25_ROW_RUNGS[-1])}
+                            executor.BM25_ROW_RUNGS[-1])
+               for counts in (True, False)}
     cases = [*all_cases(sharding),
              *((name, f, args) for name, (f, args) in lexical.items())]
     for name, f, args in cases:
